@@ -1117,57 +1117,6 @@ def _faults_section(rng, verbose: bool):
     return res
 
 
-def _activation_lowering_note(rng, verbose: bool):
-    """Carried perf thread: the per-layer activation select inside the
-    fused MLP is now a branchless opcode-indexed ``lax.select_n`` (one
-    clamped-index 5-way select) instead of the 4-deep ``jnp.where`` chain
-    (four chained masked merges).  Both lowerings live in ``ref.py``
-    behind ``lowering=`` — bit-exact with each other by the tier-1 suite —
-    so this micro-bench can keep reporting before/after on a
-    serving-shaped operand as the PRs evolve."""
-    import jax
-    import jax.numpy as jnp
-
-    from repro.core.taylor import scaled_constants
-    from repro.kernels.ref import _select_activation_ref
-
-    frac = 8
-    sig = tuple(int(c) for c in scaled_constants("sigmoid", 3, frac))
-    alpha_q = int(round(0.01 * (1 << frac)))
-    y = jnp.asarray(rng.integers(-2 ** 12, 2 ** 12,
-                                 (MIXED_BATCH, SERVE_WIDTH)), jnp.int32)
-    op = jnp.asarray(rng.integers(0, 5, (MIXED_BATCH, 1)), jnp.int32)
-
-    fns = {}
-    for lowering in ("where_chain", "select_n"):
-        f = jax.jit(lambda y, op, lw=lowering: _select_activation_ref(
-            y, op, frac=frac, sig_coeffs=sig, leaky_alpha_q=alpha_q,
-            lowering=lw))
-        f(y, op).block_until_ready()  # compile + warm
-        fns[lowering] = f
-
-    times = {}
-    for lowering, f in fns.items():
-        t = float("inf")
-        for _ in range(SWEEPS):
-            t = min(t, _min_time(
-                lambda: f(y, op).block_until_ready()))
-        times[lowering] = t
-
-    res = {
-        "rows": MIXED_BATCH,
-        "where_chain_us": times["where_chain"] * 1e6,
-        "select_n_us": times["select_n"] * 1e6,
-        "speedup": times["where_chain"] / times["select_n"],
-    }
-    if verbose:
-        print(f"  activation select lowering : where-chain "
-              f"{res['where_chain_us']:.0f} us -> select_n "
-              f"{res['select_n_us']:.0f} us  "
-              f"({res['speedup']:.2f}x on {MIXED_BATCH} rows)")
-    return res
-
-
 def _observability_section(rng, verbose: bool):
     """PR-8 acceptance: telemetry must be (near-)free on the hot path.
 
@@ -1591,7 +1540,6 @@ def run(verbose: bool = True, reduced: bool | None = None,
         obs_sec = _observability_section(rng, verbose)
         model_quality = _model_quality_section(rng, verbose)
         latency_slo = _latency_slo_section(rng, verbose)
-        act_note = _activation_lowering_note(rng, verbose)
     finally:
         if saved:
             globals().update(saved)
@@ -1601,8 +1549,7 @@ def run(verbose: bool = True, reduced: bool | None = None,
               "sharded": sharded, "faults": faults,
               "observability": obs_sec,
               "model_quality": model_quality,
-              "latency_slo": latency_slo,
-              "activation_lowering": act_note}
+              "latency_slo": latency_slo}
     payload = {
         "schema": 1,
         "bench": "fig1_throughput",
@@ -1622,7 +1569,6 @@ def run(verbose: bool = True, reduced: bool | None = None,
         "observability": obs_sec,
         "model_quality": model_quality,
         "latency_slo": latency_slo,
-        "activation_lowering": act_note,
     }
     if write_json:
         path = json_path or _json_path()

@@ -62,7 +62,17 @@ from .control_plane import ControlPlane, ForestTables, ModelTables
 from .packet import FEATURE_BYTES, HEADER_BYTES, emit_results, parse_packets
 from .taylor import scaled_constants
 
-__all__ = ["DataPlaneEngine"]
+__all__ = ["DataPlaneEngine", "CompileError"]
+
+_LANE_NAMES = {(True, False): "mlp", (False, True): "forest",
+               (True, True): "both"}
+
+
+class CompileError(RuntimeError):
+    """A serving program failed to lower or compile.  It is a fault of the
+    deployment, not of the device: it raises to the caller of the dispatch
+    that needed the program, and the ingress retry, bisection and
+    shard-kill handlers never see it."""
 
 
 class DataPlaneEngine:
@@ -81,12 +91,12 @@ class DataPlaneEngine:
         ``"fused"`` (stacked-table masked-GEMM kernel, default) or
         ``"gather"`` (per-packet weight gather — the seed baseline).
     backend:
-        Kernel backend for the fused path: ``"auto"`` (Pallas on TPU, jnp
-        oracle on CPU), ``"pallas"`` (force kernel, interpreted off-TPU) or
-        ``"ref"``.
+        Kernel backend for the fused path: ``"auto"`` (Pallas on TPU, the
+        gathered jnp lowering on CPU), ``"pallas"`` (force kernel,
+        interpreted off-TPU) or ``"ref"``.
     kernel_variant:
         Weight lane of the fused MLP kernel (``kernels.KERNEL_VARIANTS``):
-        ``"int16"`` (default, int32-operand dot) or ``"int8"`` — the
+        ``"int16"`` (default, weights up to 16 bits) or ``"int8"`` — the
         saturating int8 weight-lane (int8×int8→int32 dot, v5e MXU native
         rate).  The int8 lane requires the control plane to quantize weights
         at ``weight_bits <= 8``; a wider format is rejected here so the
@@ -110,7 +120,6 @@ class DataPlaneEngine:
                  dispatch: str = "fused", backend: str = "auto",
                  kernel_variant: str = "int16",
                  forest_variant: str = "auto",
-                 interpret_only: bool = False,
                  device=None):
         if dispatch not in ("fused", "gather"):
             raise ValueError(f"unknown dispatch strategy: {dispatch!r}")
@@ -167,6 +176,10 @@ class DataPlaneEngine:
                                 static_argnames=("use_mlp", "use_forest"))
         self._serve = jax.jit(self._serve_impl,
                               static_argnames=("use_mlp", "use_forest"))
+        # compiled feature-path programs, one per (rows, use_mlp,
+        # use_forest): compiled ahead of their first dispatch, so a
+        # lowering error surfaces as a CompileError before the batch runs
+        self._programs: dict = {}
 
     # -- the data plane ----------------------------------------------------
 
@@ -271,10 +284,11 @@ class DataPlaneEngine:
         model_id = self._place(jnp.asarray(model_id, jnp.int32))
         tables = self.cp.tables(device=self.device)
         use_mlp, use_forest = self._lane_flags(lanes)
-        ftables, rtables = self._forest_snapshots(use_forest)
+        args = (feats_q, model_id, tables,
+                *self._forest_snapshots(use_forest))
+        program = self._program(args, use_mlp, use_forest)
         t0 = time.perf_counter()
-        out = self._serve(feats_q, model_id, tables, ftables, rtables,
-                          use_mlp=use_mlp, use_forest=use_forest)
+        out = program(*args)
         n = int(feats_q.shape[0])
         self.stats["packets"] += n
         self.stats["bytes_in"] += n * (HEADER_BYTES
@@ -285,6 +299,42 @@ class DataPlaneEngine:
             out.block_until_ready()
             self.stats["seconds"] += time.perf_counter() - t0
         return out
+
+    def _program(self, args, use_mlp: bool, use_forest: bool):
+        key = (int(args[0].shape[0]), use_mlp, use_forest)
+        program = self._programs.get(key)
+        if program is None:
+            try:
+                program = self._serve.lower(
+                    *args, use_mlp=use_mlp, use_forest=use_forest).compile()
+            except Exception as e:  # noqa: BLE001 — any lowering failure
+                raise CompileError(
+                    f"serving program ({key[0]} rows, lanes="
+                    f"{_LANE_NAMES[key[1:]]}) failed to compile on "
+                    f"{jax.default_backend()}: {e}") from e
+            self._programs[key] = program
+        return program
+
+    def compile(self, n_rows: int, lanes: str = "both"):
+        """Compile the feature-path program for ``n_rows``-row batches on
+        the ``lanes`` hint (as the control plane resolves it now), unless
+        it already exists; returns it.  Raises :class:`CompileError`."""
+        use_mlp, use_forest = self._lane_flags(lanes)
+        program = self._programs.get((n_rows, use_mlp, use_forest))
+        if program is not None:  # the per-dispatch check stays cheap
+            return program
+        feats_q = self._place(jnp.zeros((n_rows, self.max_features),
+                                        jnp.int32))
+        model_id = self._place(jnp.zeros((n_rows,), jnp.int32))
+        return self._program(
+            (feats_q, model_id, self.cp.tables(device=self.device),
+             *self._forest_snapshots(use_forest)), use_mlp, use_forest)
+
+    def compiled_programs(self) -> dict:
+        """Every compiled feature-path program, keyed ``(rows, lanes)``
+        with lanes ``"mlp"``, ``"forest"`` or ``"both"``."""
+        return {(k[0], _LANE_NAMES[k[1:]]): v
+                for k, v in self._programs.items()}
 
     def process(self, pkts) -> jax.Array:
         """Blocking alias of :meth:`run` (the seed API)."""

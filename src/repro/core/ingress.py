@@ -81,6 +81,7 @@ from typing import Deque, Dict, List, Optional, Tuple, Union
 
 import numpy as np
 
+from .inference import CompileError
 from .packet import (FEATURE_BYTES, FLAG_REFLEX, HEADER_BYTES,
                      emit_results_np, parse_packets_np)
 from ..obs import Observability, StatsAdapter
@@ -1294,6 +1295,17 @@ class IngressPipeline:
                             s_hashes[isf], s_idx[isf], generation, df)
         self._resolve_ready_chunks()
 
+    def compile_programs(self) -> None:
+        """Compile every program this pipeline can dispatch — each rung of
+        the batch ladder × each lane combination the control plane enables
+        now — ahead of serving, so a lowering or compile error raises here
+        (:class:`~repro.core.inference.CompileError`) and no dispatch pays
+        a compile.  The forest lanes exist from the first
+        ``install_forest``: call this after it."""
+        for size in self.batch_sizes:
+            for lanes in ("mlp", "forest", "both"):
+                self.engine.compile(size, lanes)
+
     # -- hard-latency layer (PR 10) ----------------------------------------
 
     def queue_depth(self) -> int:
@@ -1549,6 +1561,8 @@ class IngressPipeline:
                 gen_before = self.cp.version
                 future = self._run_guarded(x0, mid, lanes)
                 gen_after = self.cp.version
+        except CompileError:
+            raise  # a deployment fault, not a device one: never salvaged
         except Exception as err:
             # every retry exhausted at the dispatch site: the device never
             # accepted this batch.  Salvage row-by-row with same-shape
@@ -1585,10 +1599,13 @@ class IngressPipeline:
 
     def _run_guarded(self, x0: np.ndarray, mid: np.ndarray, lanes: str):
         """One device dispatch under the fault plan and the bounded
-        retry-with-backoff policy.  The stall site fires first (an injected
-        wedge a supervising watchdog must notice — it delays, never
-        raises); a dispatch-site fault or a real engine error is retried
-        ``max_retries`` times with exponential backoff before giving up."""
+        retry-with-backoff policy.  The program is compiled first, outside
+        the retry loop: a :class:`CompileError` raises to the caller at
+        once.  Then the stall site fires (an injected wedge a supervising
+        watchdog must notice — it delays, never raises); a dispatch-site
+        fault or a runtime engine error is retried ``max_retries`` times
+        with exponential backoff before giving up."""
+        self.engine.compile(x0.shape[0], lanes)
         last = None
         for attempt in range(self.max_retries + 1):
             if attempt:
@@ -1602,6 +1619,8 @@ class IngressPipeline:
                     plan.fire("dispatch", self.shard_id, mid)
                 return self.engine.run_features(x0, mid, block=False,
                                                 lanes=lanes)
+            except CompileError:
+                raise
             except Exception as e:  # noqa: BLE001 — any device failure
                 last = e
         raise last

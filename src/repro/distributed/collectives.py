@@ -17,10 +17,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-try:  # jax >= 0.5 exposes shard_map at top level
-    from jax import shard_map
-except ImportError:  # 0.4.x
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 __all__ = ["collective_bytes", "compressed_all_reduce", "shard_map",
            "DTYPE_BYTES"]
@@ -83,18 +80,6 @@ def collective_bytes(hlo_text: str) -> Dict[str, int]:
 # ---------------------------------------------------------------------------
 
 
-def _axis_size(axis_name: str) -> int:
-    """Static mesh-axis size inside shard_map, across jax versions.
-
-    ``jax.lax.axis_size`` only exists from jax 0.5; on 0.4.x the axis
-    environment frame carries it (returned as a bare int on some releases).
-    """
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(axis_name)
-    frame = jax.core.axis_frame(axis_name)
-    return frame.size if hasattr(frame, "size") else int(frame)
-
-
 def compressed_all_reduce(x: jax.Array, axis_name: str, bits: int = 8
                           ) -> jax.Array:
     """All-reduce with int8 fixed-point codes on the wire (~4× fewer bytes
@@ -108,7 +93,7 @@ def compressed_all_reduce(x: jax.Array, axis_name: str, bits: int = 8
          codes back — ≈1 B/elem.
     Total ≈2 B/elem vs ≈8 B/elem for f32 ring all-reduce.
     """
-    n = _axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     orig_shape = x.shape
     flat = x.reshape(-1).astype(jnp.float32)
     pad = (-flat.shape[0]) % n

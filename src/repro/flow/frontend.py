@@ -12,8 +12,9 @@ previously skipped: real traffic has no feature vectors, it has packets.
         │                             addressing, idle expiry, eviction)
         ▼
     kernels.flow_update               sequential scatter-update of the
-        │                             register file + count-min sketch,
-        │                             emits post-update feature codes
+        │                             register file + count-min sketch
+        │                             (host rank-round lowering), emits
+        │                             post-update feature codes
         ▼
     FeatureSpec gather                per-packet: which flow-feature lanes
         │                             feed this Model ID's input columns
@@ -24,9 +25,9 @@ previously skipped: real traffic has no feature vectors, it has packets.
 Everything upstream of the pipeline is host-side vectorized numpy (the
 registers live next to the flow hash table), so a FeatureSpec reinstall —
 re-mapping which registers feed which model — is a pure control-plane
-swap: zero data-plane retraces by construction.  On TPU the whole stage
-can instead run as one device dispatch (``serve_raw_fused``: flow-update
-kernel → in-program spec take → compute lanes → egress encode).
+swap: zero data-plane retraces by construction.  It stays on the host on
+every platform: shard failover migrates register rows straight out of the
+table, so a wedged device never holds the only copy of flow state.
 
 Converged flows are where this design pays: a periodic/telemetry flow's
 EWMA registers reach a fixed point, its feature rows byte-repeat, and the
@@ -38,13 +39,11 @@ from raw packets.
 from __future__ import annotations
 
 import dataclasses
-import functools
 from typing import Optional, Tuple
 
 import numpy as np
 
 from ..core.ingress import _dedup_rows
-from ..core.packet import HEADER_BYTES
 from ..data.packets import RAW_KEY_BYTES, RawHeaderBatch, parse_raw_headers
 from ..kernels.ops import flow_update
 from ..kernels.ref import N_FLOW_FEATURES, flow_update_numpy
@@ -106,24 +105,16 @@ class FlowFrontend:
     params:
         :class:`FlowParams` override (default derives from the control
         plane's ``frac_bits``).
-    backend:
-        Kernel backend for the flow update: ``"auto"`` (rank-round numpy on
-        CPU, Pallas on TPU), ``"pallas"``, or ``"ref"`` (the pure-Python
-        oracle — tests only).
     """
 
     def __init__(self, pipeline, *, capacity_pow2: int = 14,
                  idle_timeout: Optional[int] = None,
-                 params: Optional[FlowParams] = None,
-                 backend: str = "auto"):
-        if backend not in ("auto", "pallas", "ref"):
-            raise ValueError(f"unknown backend: {backend!r}")
+                 params: Optional[FlowParams] = None):
         self.pipeline = pipeline
         self.cp = pipeline.cp
         self.engine = pipeline.engine
         self.params = params or FlowParams(frac=self.cp.frac_bits)
         self.width = self.engine.max_features  # wire feature-block columns
-        self.backend = backend
         self.key_words = (RAW_KEY_BYTES + 7) // 8
         self.table = FlowTable(self.key_words, capacity_pow2=capacity_pow2,
                                idle_timeout=idle_timeout)
@@ -140,7 +131,6 @@ class FlowFrontend:
         self.stats = stats
         self._arange = np.arange(0).reshape(0, 1)  # grown on demand
         self._ones = np.ones(0, np.int32)
-        self._fused_serve = None  # jitted serve_raw program (lazy)
 
     # -- feature extraction -------------------------------------------------
 
@@ -184,6 +174,7 @@ class FlowFrontend:
         p = self.params
         if self._ones.shape[0] < n:
             self._ones = np.ones(n, np.int32)
+        # registers and sketch update in place (copy=False)
         if rejected.any():
             # overflow degradation: whole flows were rejected, so the kept
             # packets' slots and within-flow ranks are still exact — run
@@ -192,31 +183,20 @@ class FlowFrontend:
             keep = np.nonzero(~rejected)[0]
             feats = np.zeros((n, N_FLOW_FEATURES), np.int32)
             if keep.size:
-                state, cms, kfeats = flow_update(
+                feats[keep] = flow_update(
                     self.table.registers, self.cms, slots[keep],
                     cells[keep], fields.ts[keep], fields.length[keep],
                     self._ones[: keep.size], frac=p.frac,
                     ewma_shift=p.ewma_shift, byte_shift=p.byte_shift,
-                    dur_shift=p.dur_shift, backend=self.backend, copy=False,
-                    rank=None if rank is None else rank[keep])
-                if state is not self.table.registers:
-                    self.table.registers[:] = np.asarray(state)
-                    self.cms[:] = np.asarray(cms)
-                feats[keep] = np.asarray(kfeats)
+                    dur_shift=p.dur_shift, copy=False,
+                    rank=None if rank is None else rank[keep])[2]
         else:
-            state, cms, feats = flow_update(
+            feats = flow_update(
                 self.table.registers, self.cms, slots, cells, fields.ts,
                 fields.length, self._ones[:n], frac=p.frac,
                 ewma_shift=p.ewma_shift, byte_shift=p.byte_shift,
-                dur_shift=p.dur_shift, backend=self.backend, copy=False,
-                rank=rank)
-            if state is not self.table.registers:  # pallas/ref return fresh
-                self.table.registers[:] = np.asarray(state)
-                self.cms[:] = np.asarray(cms)
-            feats = np.asarray(feats)
+                dur_shift=p.dur_shift, copy=False, rank=rank)[2]
         if cms_est_q is not None:
-            if not feats.flags.writeable:
-                feats = np.array(feats)
             feats[:, N_FLOW_FEATURES - 1] = cms_est_q
         return feats, fields, is_new, rejected
 
@@ -225,8 +205,7 @@ class FlowFrontend:
     def _gather(self, feats: np.ndarray, model_id: np.ndarray) -> np.ndarray:
         """Per-model FeatureSpec gather: land each packet's flow-feature
         lanes on its model's input columns (one int32 gather — ``-1``
-        columns read the appended zero lane, exactly the device program's
-        ``fused_serve.spec_take`` convention)."""
+        columns read the appended zero lane)."""
         n = feats.shape[0]
         cols, _ = self.cp.feature_spec_rows(model_id, self.width)
         feats_z = np.concatenate(
@@ -334,65 +313,6 @@ class FlowFrontend:
                 f"frontend's {self.cms.shape}")
         self.table.restore(snap["table"])
         self.cms[:] = cms
-
-    def serve_raw_fused(self, raw) -> np.ndarray:
-        """One-dispatch raw serving: the whole cold path — flow-update
-        kernel → in-program spec gather → lane dispatch → egress encode —
-        as a single jitted device program (``kernels.fused_serve.
-        serve_raw``), bypassing the ingress caches entirely.
-
-        This is the TPU deployment shape; off-TPU the kernel runs under
-        the Pallas interpreter, so the staged ``submit_raw`` path is the
-        CPU production route.  The host still resolves 5-tuples → register
-        slots (the flow hash table is the one intrinsically host-side
-        stage), and — because that table also owns eviction — the register
-        file and sketch currently round-trip host↔device per batch; making
-        them device-resident across batches (donated buffers, host-side
-        eviction mirrored by index) is the remaining step for the real-TPU
-        run (ROADMAP).  Returns the egress wire rows in batch order,
-        bit-exact with ``submit_raw``'s results for the same arrivals.
-        """
-        import jax
-        from ..kernels.fused_serve import serve_raw
-
-        fields = parse_raw_headers(raw)
-        n = fields.model_id.shape[0]
-        if n == 0:
-            return np.zeros((0, HEADER_BYTES + 4 * self.width), np.uint8)
-        self.stats["flow_raw_packets_total"] += n
-        self.stats["flow_raw_batches_total"] += 1
-        words, hashes = FlowTable.pack_keys(fields.key_bytes, self.key_words)
-        # no rank wanted: the in-kernel walk is batch-ordered, unlike the
-        # host rank-round lowering extract() feeds
-        slots, _ = self.table.lookup_or_insert(words, hashes, fields.ts)
-        if np.any(slots < 0):
-            # the fused bench surface has no per-packet error channel —
-            # keep the overflow loud here rather than serving zero rows
-            raise ValueError(
-                "flow table overflow in serve_raw_fused: "
-                f"{int((slots < 0).sum())} packets' flows rejected — size "
-                "the table above the trace's flow count for the fused path")
-        cells = self.params.cms_cells(hashes)
-        cols, _ = self.cp.feature_spec_rows(fields.model_id, self.width)
-        eng = self.engine
-        if self._fused_serve is None:
-            self._fused_serve = jax.jit(
-                functools.partial(serve_raw, cfg=eng.lane_cfg._replace(
-                    backend="pallas" if eng.backend == "auto"
-                    else eng.backend)),
-                static_argnames=("use_mlp", "use_forest", "ewma_shift",
-                                 "byte_shift", "dur_shift"))
-        use_mlp, use_forest = eng._lane_flags("both")
-        p = self.params
-        state, cms, rows = self._fused_serve(
-            self.table.registers, self.cms, slots, cells, fields.ts,
-            fields.length, np.ones(n, np.int32), cols, fields.model_id,
-            eng.cp.tables(), *eng._forest_snapshots(use_forest),
-            use_mlp=use_mlp, use_forest=use_forest, ewma_shift=p.ewma_shift,
-            byte_shift=p.byte_shift, dur_shift=p.dur_shift)
-        self.table.registers[:] = np.asarray(state)
-        self.cms[:] = np.asarray(cms)
-        return np.asarray(rows)
 
     def flow_table_hit_rate(self) -> float:
         return self.table.hit_rate()

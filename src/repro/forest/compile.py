@@ -212,6 +212,10 @@ def train_tree(X: np.ndarray, y: np.ndarray, *, task: str = "classify",
     n_sub = d if feature_frac is None else max(1, int(round(d * feature_frac)))
 
     feature, threshold, left, right, value = [], [], [], [], []
+    # right children reserved by a split but not yet built (each is built
+    # after its sibling's whole subtree): nodes built + reserved stays
+    # within max_nodes, so no split can overrun the budget
+    pending = 0
 
     def new_node() -> int:
         feature.append(0)
@@ -222,12 +226,13 @@ def train_tree(X: np.ndarray, y: np.ndarray, *, task: str = "classify",
         return len(feature) - 1
 
     def build(idx: np.ndarray, depth: int) -> int:
+        nonlocal pending
         node = new_node()
         ysub = y[idx]
         value[node] = _leaf_value(ysub, task)
         pure = np.all(ysub == ysub[0]) if ysub.size else True
         if depth >= max_depth or idx.size < 2 * min_leaf or pure \
-                or len(feature) + 2 > max_nodes:
+                or len(feature) + pending + 2 > max_nodes:
             return node
         feats = (np.arange(d) if n_sub == d
                  else np.sort(rng.choice(d, n_sub, replace=False)))
@@ -241,7 +246,9 @@ def train_tree(X: np.ndarray, y: np.ndarray, *, task: str = "classify",
         _, j, th = best
         go_left = X[idx, j] <= th
         feature[node], threshold[node] = j, th
+        pending += 1
         left[node] = build(idx[go_left], depth + 1)
+        pending -= 1
         right[node] = build(idx[~go_left], depth + 1)
         return node
 

@@ -2,7 +2,7 @@
 SmartNIC computes in register externs before the ML stage).
 
 The flow engine (``repro.flow``) resolves each raw packet's 5-tuple to a
-flow-table slot on the host; this kernel then performs, for a fixed-shape
+flow-table slot on the host; this stage then performs, for a fixed-shape
 batch of parsed headers, the whole **stateful** update in one pass:
 
     for each packet p (batch order):
@@ -15,166 +15,40 @@ batch of parsed headers, the whole **stateful** update in one pass:
 Batch order matters: two packets of one flow in the same batch chain their
 EWMAs, exactly like back-to-back packets through a hardware register ALU.
 That makes the update a *sequential scatter* — the one stage of this repo's
-data plane that is not embarrassingly batch-parallel — and drives the two
-realizations below:
+data plane that is not embarrassingly batch-parallel.  It runs on the host
+on every platform: the register file lives next to the flow hash table,
+which owns eviction, and shard failover migrates register rows straight
+from it.
 
-  * :func:`flow_update_pallas` — the TPU kernel: the whole register file and
-    sketch live in VMEM scratch-free (paper-scale tables are ≤ 1 MiB), and a
-    ``fori_loop`` walks the batch with dynamic-slice row gathers/scatters.
-    The per-packet working set is one (1, R) row — VPU lanes, no MXU.
-  * :func:`flow_update_gather` — the production CPU lowering: packets are
-    ranked within their flow (stable batch order), and rank-``r`` packets
-    across *distinct* flows update in one vectorized numpy round — the
-    sequential chain only costs rounds = max packets-per-flow-per-batch,
-    not B.  The count-min lane needs no rounds at all: increments commute,
-    so each packet's post-update estimate has the closed form
-    ``min(prior + rank_in_cell + 1, FLOW_CODE_MAX)``.
+:func:`flow_update_gather` is the lowering: packets are ranked within their
+flow (stable batch order), and rank-``r`` packets across *distinct* flows
+update in one vectorized numpy round — the sequential chain only costs
+rounds = max packets-per-flow-per-batch, not B.  The count-min lane needs
+no rounds at all: increments commute, so each packet's post-update estimate
+has the closed form ``min(prior + rank_in_cell + 1, FLOW_CODE_MAX)``.
 
-Both are bit-exact against the pure-Python per-packet oracle
+It is bit-exact against the pure-Python per-packet oracle
 ``ref.flow_update_numpy`` (asserted by hypothesis property tests) — same
 contract discipline as the MLP and forest kernels.
 """
 
 from __future__ import annotations
 
-import functools
-
-import jax
-import jax.numpy as jnp
 import numpy as np
-from jax.experimental import pallas as pl
 
 from .ref import (FLOW_CODE_MAX, N_FLOW_FEATURES, N_FLOW_REGISTERS,
                   REG_BYTE_COUNT, REG_EWMA_IAT, REG_EWMA_LEN, REG_FIRST_TS,
                   REG_LAST_TS, REG_MAX_LEN, REG_MIN_LEN, REG_PKT_COUNT,
-                  rounding_rshift, rounding_rshift_np, sat_shl_np)
+                  rounding_rshift_np, sat_shl_np)
 
-__all__ = ["flow_update_pallas", "flow_update_gather", "rank_from_order",
-           "cms_estimate_update"]
-
-
-# ---------------------------------------------------------------------------
-# Pallas kernel
-# ---------------------------------------------------------------------------
-
-
-def _sat_shl(v: jax.Array, shift: int) -> jax.Array:
-    """jnp twin of ``ref.sat_shl_np`` (saturating shift onto the code grid)."""
-    v = jnp.minimum(jnp.maximum(v, 0), jnp.int32(FLOW_CODE_MAX >> shift))
-    return v << shift
-
-
-def _kernel(state_ref, cms_ref, slot_ref, cell_ref, ts_ref, len_ref,
-            live_ref, o_state, o_cms, o_feat, *, frac: int, ewma_shift: int,
-            byte_shift: int, dur_shift: int):
-    n = slot_ref.shape[0]
-    depth = cms_ref.shape[0]
-    code_max = jnp.int32(FLOW_CODE_MAX)
-    # state/sketch update in place on the outputs; features start dead
-    o_state[...] = state_ref[...]
-    o_cms[...] = cms_ref[...]
-    o_feat[...] = jnp.zeros(o_feat.shape, jnp.int32)
-
-    def body(p, _):
-        live = pl.load(live_ref, (pl.ds(p, 1), slice(None)))[0, 0] > 0
-        slot = pl.load(slot_ref, (pl.ds(p, 1), slice(None)))[0, 0]
-        t = pl.load(ts_ref, (pl.ds(p, 1), slice(None)))[0, 0]
-        ln = jnp.maximum(
-            pl.load(len_ref, (pl.ds(p, 1), slice(None)))[0, 0], 0)
-        row = pl.load(o_state, (pl.ds(slot, 1), slice(None)))  # (1, R)
-        cnt = row[0, REG_PKT_COUNT]
-        fresh = cnt == 0
-        len_q = _sat_shl(ln, frac)
-        iat_q = _sat_shl(jnp.maximum(t - row[0, REG_LAST_TS], 0), frac)
-        blend_iat = row[0, REG_EWMA_IAT] + rounding_rshift(
-            iat_q - row[0, REG_EWMA_IAT], ewma_shift)
-        iat_e = jnp.where(fresh, 0, jnp.where(cnt == 1, iat_q, blend_iat))
-        blend_len = row[0, REG_EWMA_LEN] + rounding_rshift(
-            len_q - row[0, REG_EWMA_LEN], ewma_shift)
-        len_e = jnp.where(fresh, len_q, blend_len)
-        mn = jnp.where(fresh, ln, jnp.minimum(row[0, REG_MIN_LEN], ln))
-        mx = jnp.where(fresh, ln, jnp.maximum(row[0, REG_MAX_LEN], ln))
-        byte = jnp.where(fresh, jnp.minimum(ln, code_max),
-                         jnp.minimum(row[0, REG_BYTE_COUNT] + ln, code_max))
-        cnt2 = jnp.where(fresh, 1, jnp.minimum(cnt + 1, code_max))
-        first = jnp.where(fresh, t, row[0, REG_FIRST_TS])
-        new_row = jnp.stack([cnt2, byte, t, first, iat_e, len_e, mn, mx]
-                            ).astype(jnp.int32).reshape(1, N_FLOW_REGISTERS)
-        # dead rows store their old row back — a no-op write, no branch
-        pl.store(o_state, (pl.ds(slot, 1), slice(None)),
-                 jnp.where(live, new_row, row))
-        inc = jnp.where(live, jnp.int32(1), jnp.int32(0))
-        est = code_max
-        for d in range(depth):  # static: sketch depth is a config constant
-            c = pl.load(cell_ref, (pl.ds(p, 1), pl.ds(d, 1)))[0, 0]
-            cur = pl.load(o_cms, (pl.ds(d, 1), pl.ds(c, 1)))
-            cur = jnp.minimum(cur + inc, code_max)
-            pl.store(o_cms, (pl.ds(d, 1), pl.ds(c, 1)), cur)
-            est = jnp.minimum(est, cur[0, 0])
-        feat = jnp.stack([
-            _sat_shl(cnt2, frac),
-            _sat_shl(byte >> byte_shift, frac),
-            iat_e, len_e,
-            _sat_shl(mn, frac), _sat_shl(mx, frac),
-            _sat_shl(jnp.maximum(t - first, 0) >> dur_shift, frac),
-            _sat_shl(est, frac),
-        ]).astype(jnp.int32).reshape(1, N_FLOW_FEATURES)
-        pl.store(o_feat, (pl.ds(p, 1), slice(None)),
-                 jnp.where(live, feat, jnp.zeros_like(feat)))
-        return 0
-
-    jax.lax.fori_loop(0, n, body, 0)
-
-
-@functools.partial(jax.jit, static_argnames=("frac", "ewma_shift",
-                                             "byte_shift", "dur_shift",
-                                             "interpret"))
-def flow_update_pallas(state: jax.Array, cms: jax.Array, slots: jax.Array,
-                       cells: jax.Array, ts: jax.Array, length: jax.Array,
-                       live: jax.Array, *, frac: int, ewma_shift: int,
-                       byte_shift: int, dur_shift: int,
-                       interpret: bool = False):
-    """Sequential scatter-update of the flow register file on device.
-
-    state (S, R) int32 · cms (D, Wc) int32 · slots/ts/length/live (B,) int32
-    (slots pre-resolved and in ``[0, S)``) · cells (B, D) int32 in
-    ``[0, Wc)``.  Returns ``(new_state, new_cms, features)`` — see
-    ``ref.flow_update_numpy`` for the exact per-packet semantics.
-
-    One grid step owns the whole batch: the update is order-dependent, so
-    there is nothing to tile over — the register file (≤ 1 MiB at paper
-    scale: 2^15 slots × 8 regs × 4 B) and sketch stay resident in VMEM for
-    the whole walk.
-    """
-    col = lambda a: jnp.asarray(a, jnp.int32).reshape(-1, 1)
-    n = np.shape(slots)[-1] if np.ndim(slots) > 1 else np.shape(slots)[0]
-    if n == 0:  # static: nothing to walk, state passes through
-        return (jnp.asarray(state, jnp.int32), jnp.asarray(cms, jnp.int32),
-                jnp.zeros((0, N_FLOW_FEATURES), jnp.int32))
-    return pl.pallas_call(
-        functools.partial(_kernel, frac=frac, ewma_shift=ewma_shift,
-                          byte_shift=byte_shift, dur_shift=dur_shift),
-        out_shape=(
-            jax.ShapeDtypeStruct(state.shape, jnp.int32),
-            jax.ShapeDtypeStruct(cms.shape, jnp.int32),
-            jax.ShapeDtypeStruct((n, N_FLOW_FEATURES), jnp.int32),
-        ),
-        interpret=interpret,
-    )(jnp.asarray(state, jnp.int32), jnp.asarray(cms, jnp.int32),
-      col(slots), jnp.asarray(cells, jnp.int32).reshape(n, -1),
-      col(ts), col(length), col(live))
-
-
-# ---------------------------------------------------------------------------
-# Vectorized CPU lowering (rank rounds)
-# ---------------------------------------------------------------------------
+__all__ = ["flow_update_gather", "rank_from_order", "cms_estimate_update"]
 
 
 def rank_from_order(order: np.ndarray, newg: np.ndarray) -> np.ndarray:
     """Per-group occurrence rank (original order) from a stable sort's
     ``order`` permutation and its group-start mask ``newg`` — THE rank
     definition, shared with ``core.ingress._dedup_rows(want_rank=True)``
-    so the flow table's dedup by-product and the kernel's own fallback can
+    so the flow table's dedup by-product and this module's own fallback can
     never drift apart."""
     n = order.shape[0]
     ar = np.arange(n)
@@ -241,7 +115,7 @@ def flow_update_gather(state: np.ndarray, cms: np.ndarray, slots: np.ndarray,
                        live: np.ndarray, *, frac: int, ewma_shift: int,
                        byte_shift: int, dur_shift: int, copy: bool = True,
                        rank: "np.ndarray | None" = None):
-    """Bit-identical CPU realization: rank-round vectorized scatter.
+    """Bit-identical host realization: rank-round vectorized scatter.
 
     Packets are ranked within their flow (stable batch order); round ``r``
     updates every flow's rank-``r`` packet at once — all distinct slots, so
@@ -254,7 +128,7 @@ def flow_update_gather(state: np.ndarray, cms: np.ndarray, slots: np.ndarray,
     the flow table's register file is megabytes, and re-copying it per batch
     would dwarf the update itself).
 
-    All arithmetic is int32 (like the Pallas kernel): exact as long as the
+    All arithmetic is int32: exact as long as the
     inputs respect the wire's field ranges — ``ts`` non-negative int32 and
     every register/length within ``[0, FLOW_CODE_MAX]`` (lengths are
     clamped on entry; the update itself can then never leave the range —

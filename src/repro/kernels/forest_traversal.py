@@ -11,9 +11,10 @@ without gathering per-packet node tensors from HBM.
 
 Formulation (per batch tile, all tables resident in VMEM):
 
-  1. one-hot forest select, once per tree: ``tbl[p] = onehot_f[p] · nodes[t]``
-     — a (bb, F) × (F, 5·N) MXU dot that hands every packet its own tree's
-     node table, field-major (feat | thresh | left | right | leaf columns);
+  1. forest select, once per tree: ``tbl[p] = nodes[t, slot[p]]`` — an
+     F-row select chain (``ref.slot_select``, VPU; the matrix unit has no
+     exact int32 dot) that hands every packet its own tree's node table,
+     field-major (feat | thresh | left | right | leaf columns);
   2. level-bounded pointer chase, unrolled to ``max_depth``: the current
      node's fields are iota-compare row reductions over the gathered table
      (VPU), the split feature value is the same reduction over the packet's
@@ -44,7 +45,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from .ref import FOREST_CLASSIFY
+from .ref import FOREST_CLASSIFY, slot_select
 
 __all__ = ["forest_traverse_pallas", "forest_range_pallas", "FB",
            "FOREST_VARIANTS"]
@@ -74,32 +75,22 @@ def _kernel(x_ref, slot_ref, nodes_ref, on_ref, mode_ref, o_ref, *,
     x = x_ref[...]        # (bb, W) int32 feature codes
     slot = slot_ref[...]  # (bb, 1) int32, pre-clamped to [0, F)
     bb, width = x.shape
-    n_forests = mode_ref.shape[0]
 
-    f_iota = jax.lax.broadcasted_iota(jnp.int32, (bb, n_forests), 1)
-    onehot_f = (slot == f_iota).astype(jnp.int32)  # (bb, F)
-    mode_p = jax.lax.dot_general(onehot_f, mode_ref[...],
-                                 (((1,), (0,)), ((), ())),
-                                 preferred_element_type=jnp.int32)  # (bb, 1)
+    mode_p = slot_select(slot, mode_ref[...])  # (bb, 1)
     n_iota = jax.lax.broadcasted_iota(jnp.int32, (bb, n_nodes), 1)
     w_iota = jax.lax.broadcasted_iota(jnp.int32, (bb, width), 1)
     one_q = jnp.int32(1 << frac)
 
-    acc = jnp.zeros((bb, width), jnp.int32)
-    for t in range(n_trees):  # static: max_trees is a synthesis-time bound
-        # forest dispatch fused into one dot: every packet receives its own
-        # forest's node table for tree t, field-major columns
-        tbl = jax.lax.dot_general(onehot_f, nodes_ref[t],
-                                  (((1,), (0,)), ((), ())),
-                                  preferred_element_type=jnp.int32)
+    def tree(t, acc):  # one tree per step: the unrolled body stays small
+        # forest dispatch: every packet receives its own forest's node
+        # table for tree t, field-major columns
+        tbl = slot_select(slot, nodes_ref[t])
         feat_t = tbl[:, 0 * n_nodes: 1 * n_nodes]
         th_t = tbl[:, 1 * n_nodes: 2 * n_nodes]
         left_t = tbl[:, 2 * n_nodes: 3 * n_nodes]
         right_t = tbl[:, 3 * n_nodes: 4 * n_nodes]
         leaf_t = tbl[:, 4 * n_nodes: 5 * n_nodes]
-        on = jax.lax.dot_general(onehot_f, on_ref[t],
-                                 (((1,), (0,)), ((), ())),
-                                 preferred_element_type=jnp.int32) > 0
+        on = slot_select(slot, on_ref[t]) > 0
         cur = jnp.zeros((bb, 1), jnp.int32)
         for _ in range(max_depth):  # static: the P4 stage-count bound
             sel = (n_iota == cur).astype(jnp.int32)  # (bb, N)
@@ -115,9 +106,10 @@ def _kernel(x_ref, slot_ref, nodes_ref, on_ref, mode_ref, o_ref, *,
         vote_cls = jnp.where(w_iota == leaf, one_q, 0)
         vote_reg = jnp.where(w_iota == 0, leaf, 0)
         contrib = jnp.where(mode_p == FOREST_CLASSIFY, vote_cls, vote_reg)
-        acc = acc + jnp.where(on, contrib, 0)
+        return acc + jnp.where(on, contrib, 0)
 
-    o_ref[...] = acc
+    o_ref[...] = jax.lax.fori_loop(0, n_trees, tree,
+                                   jnp.zeros((bb, width), jnp.int32))
 
 
 @functools.partial(jax.jit, static_argnames=("max_depth", "frac", "bb",
@@ -168,7 +160,7 @@ def forest_traverse_pallas(x_q: jax.Array, slot: jax.Array,
 
 def _range_kernel(x_ref, slot_ref, rng_ref, on_ref, mode_ref, o_ref, *,
                   n_trees: int, n_entries: int, n_leaves: int, frac: int):
-    """Range-table traversal: per tree, one one-hot dot hands every packet
+    """Range-table traversal: per tree, one slot select hands every packet
     its own forest's range rows (feat | thresh | mask | payload, field-major
     columns), then the whole tree evaluates as ``n_entries`` parallel
     compares + a leaf-mask AND-reduce — no pointer chase, no per-step
@@ -176,29 +168,19 @@ def _range_kernel(x_ref, slot_ref, rng_ref, on_ref, mode_ref, o_ref, *,
     x = x_ref[...]        # (bb, W) int32 feature codes
     slot = slot_ref[...]  # (bb, 1) int32, pre-clamped to [0, F)
     bb, width = x.shape
-    n_forests = mode_ref.shape[0]
 
-    f_iota = jax.lax.broadcasted_iota(jnp.int32, (bb, n_forests), 1)
-    onehot_f = (slot == f_iota).astype(jnp.int32)  # (bb, F)
-    mode_p = jax.lax.dot_general(onehot_f, mode_ref[...],
-                                 (((1,), (0,)), ((), ())),
-                                 preferred_element_type=jnp.int32)  # (bb, 1)
+    mode_p = slot_select(slot, mode_ref[...])  # (bb, 1)
     w_iota = jax.lax.broadcasted_iota(jnp.int32, (bb, width), 1)
     one_q = jnp.int32(1 << frac)
     all_ones = jnp.uint32(0xFFFFFFFF)
 
-    acc = jnp.zeros((bb, width), jnp.int32)
-    for t in range(n_trees):  # static: max_trees is a synthesis-time bound
-        tbl = jax.lax.dot_general(onehot_f, rng_ref[t],
-                                  (((1,), (0,)), ((), ())),
-                                  preferred_element_type=jnp.int32)
+    def tree(t, acc):  # one tree per step: the unrolled body stays small
+        tbl = slot_select(slot, rng_ref[t])
         feat_t = tbl[:, 0 * n_entries: 1 * n_entries]
         th_t = tbl[:, 1 * n_entries: 2 * n_entries]
         mask_t = tbl[:, 2 * n_entries: 3 * n_entries].astype(jnp.uint32)
         pay_t = tbl[:, 3 * n_entries: 3 * n_entries + n_leaves]
-        on = jax.lax.dot_general(onehot_f, on_ref[t],
-                                 (((1,), (0,)), ((), ())),
-                                 preferred_element_type=jnp.int32) > 0
+        on = slot_select(slot, on_ref[t]) > 0
         word = jnp.full((bb, 1), 0xFFFFFFFF, jnp.uint32)
         for i in range(n_entries):  # static: all entries, no serial chain
             fe = feat_t[:, i: i + 1]
@@ -217,9 +199,10 @@ def _range_kernel(x_ref, slot_ref, rng_ref, on_ref, mode_ref, o_ref, *,
         vote_cls = jnp.where(w_iota == leaf, one_q, 0)
         vote_reg = jnp.where(w_iota == 0, leaf, 0)
         contrib = jnp.where(mode_p == FOREST_CLASSIFY, vote_cls, vote_reg)
-        acc = acc + jnp.where(on, contrib, 0)
+        return acc + jnp.where(on, contrib, 0)
 
-    o_ref[...] = acc
+    o_ref[...] = jax.lax.fori_loop(0, n_trees, tree,
+                                   jnp.zeros((bb, width), jnp.int32))
 
 
 @functools.partial(jax.jit, static_argnames=("n_entries", "n_leaves", "frac",

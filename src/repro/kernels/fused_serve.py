@@ -1,40 +1,19 @@
-"""Device-resident fused serving program — the whole cold-path compute stage
-as **one dispatch**.
+"""The serving compute stage as **one device dispatch** per staged batch.
 
-Before this module the cold serving path paid, per batch: a host wire
-encode, a device byte-parse, the compute lanes, a device byte-deparse and a
-host readback — plus, for raw flow traffic, the flow-update kernel and the
-feature-spec gather as *separate* stages with their own materializations.
-Steady-state traffic short-circuits all of that through the ingress caches,
-but cold/unique traffic (the adversarial case for anomaly detection) ran
-every stage every batch.
+:func:`serve_lanes` is the lane-dispatch core shared by **every** serving
+surface: Model-ID resolution through both id maps, the fused MLP kernel
+(``kernels.fixedpoint_mlp``), the tree-ensemble lane
+(``kernels.forest_traversal`` — pointer-chase or range-table variant) and
+per-model output masking, over already-parsed int32 feature codes.
+``core.inference.DataPlaneEngine`` jits it directly for the feature path
+(``run_features``) and composes it with the byte codec for the legacy wire
+path — one definition, so the two surfaces cannot drift.  Raw traffic
+enters here too: the flow update and the feature-spec gather run on the
+host (``repro.flow``), and the wire byte layout is paid once, at egress.
 
-This module fuses the serving compute into single jitted programs built
-from the existing kernels:
-
-  * :func:`serve_lanes` — the lane-dispatch core shared by **every** serving
-    surface: Model-ID resolution through both id maps, the fused MLP kernel
-    (``kernels.fixedpoint_mlp``), the tree-ensemble lane
-    (``kernels.forest_traversal`` — pointer-chase or range-table variant)
-    and per-model output masking, over already-parsed int32 feature codes.
-    ``core.inference.DataPlaneEngine`` jits it directly for the feature
-    path (``run_features``) and composes it with the byte codec for the
-    legacy wire path — one definition, so the two surfaces cannot drift.
-  * :func:`spec_take` — the feature-spec gather as an in-program int32
-    take: each packet's flow-feature lanes land on its model's input
-    columns inside the compiled program (``-1`` columns read an appended
-    zero lane, exactly the host gather's convention).
-  * :func:`serve_raw` — flow-update → spec-take → lane dispatch → wire
-    encode in one program: the raw-packet cold path as a single device
-    dispatch, with the wire byte layout paid **once at egress only**.  The
-    flow-update stage is the Pallas kernel, so this is the TPU deployment
-    shape; on CPU the serving stack keeps the flow update in the host
-    rank-round lowering (measured faster there) and enters at
-    :func:`serve_lanes` instead — same bit-exact semantics either way.
-
-Everything here is trace-time composition: the functions are pure jnp/
-Pallas-kernel call graphs with static lane/variant switches, jitted by
-their callers (the engine owns the jit cache and the trace counter).
+Everything here is trace-time composition: pure jnp/Pallas-kernel call
+graphs with static lane/variant switches, jitted by their callers (the
+engine owns the compiled programs and the trace counter).
 """
 
 from __future__ import annotations
@@ -44,10 +23,9 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
-from ..core.packet import emit_results, ParsedBatch
-from .ops import flow_update, forest_traverse, fused_mlp
+from .ops import forest_traverse, fused_mlp
 
-__all__ = ["LaneConfig", "serve_lanes", "spec_take", "serve_raw"]
+__all__ = ["LaneConfig", "serve_lanes"]
 
 
 class LaneConfig(NamedTuple):
@@ -121,56 +99,3 @@ def serve_lanes(x0: jax.Array, model_id: jax.Array, tables, ftables, rtables,
         outputs = jnp.where(fvalid[:, None], fout, outputs)
 
     return outputs[:, : cfg.max_features]
-
-
-def spec_take(feats: jax.Array, cols: jax.Array) -> jax.Array:
-    """Feature-spec gather as an in-program int32 take.
-
-    feats (B, NF) int32 flow-feature codes · cols (B, W) int32 per-packet
-    input-column map (``-1`` = unused column) → (B, W) int32 model inputs.
-    The appended zero lane realizes the ``-1`` convention with one gather
-    and no masking pass — identical semantics to the host-side gather in
-    ``flow.frontend`` (asserted bit-exact by the tier-1 suite).
-    """
-    n = feats.shape[0]
-    feats_z = jnp.concatenate(
-        [feats.astype(jnp.int32), jnp.zeros((n, 1), jnp.int32)], axis=1)
-    safe = jnp.where(cols >= 0, cols, feats_z.shape[1] - 1)
-    return jnp.take_along_axis(feats_z, safe.astype(jnp.int32), axis=1)
-
-
-def serve_raw(state: jax.Array, cms: jax.Array, slots: jax.Array,
-              cells: jax.Array, ts: jax.Array, length: jax.Array,
-              live: jax.Array, cols: jax.Array, model_id: jax.Array,
-              tables, ftables, rtables, cfg: LaneConfig, *,
-              use_mlp: bool, use_forest: bool,
-              ewma_shift: int, byte_shift: int, dur_shift: int):
-    """The fused raw-packet serving program: one dispatch from parsed raw
-    headers (flow slots pre-resolved by the host flow table — the hash
-    table is the one intrinsically host-side stage) to egress wire rows.
-
-        flow_update (Pallas kernel: registers + count-min sketch)
-          → spec_take (in-program int32 gather)
-          → serve_lanes (fused MLP / forest kernels)
-          → emit_results (wire encode, once, at egress only)
-
-    Returns ``(new_state, new_cms, egress_rows)``: the caller owns the
-    register file across batches (same contract as ``ops.flow_update``).
-    Bit-exact against the staged host path — same kernels, same order.
-    """
-    new_state, new_cms, feats = flow_update(
-        state, cms, slots, cells, ts, length, live, frac=cfg.frac,
-        ewma_shift=ewma_shift, byte_shift=byte_shift, dur_shift=dur_shift,
-        backend="pallas" if cfg.backend == "auto" else cfg.backend)
-    x0 = spec_take(feats, cols)
-    outputs = serve_lanes(x0, model_id, tables, ftables, rtables, cfg,
-                          use_mlp=use_mlp, use_forest=use_forest)
-    n = outputs.shape[0]
-    parsed = ParsedBatch(
-        model_id=model_id.astype(jnp.int32),
-        feature_cnt=jnp.zeros((n,), jnp.int32),
-        output_cnt=jnp.zeros((n,), jnp.int32),
-        scale=jnp.full((n,), cfg.frac, jnp.int32),
-        flags=jnp.zeros((n,), jnp.int32),
-        features_q=x0)
-    return new_state, new_cms, emit_results(parsed, outputs, cfg.frac)

@@ -1,13 +1,12 @@
 """jit'd public wrappers around the Pallas kernels with platform dispatch.
 
-On TPU the Pallas kernels lower natively; on CPU (this container, and any
-test environment) they run through the Pallas interpreter or fall back to the
-pure-jnp oracle (`ref.py`) — selected by ``backend``:
+Each lane has one lowering per platform, chosen by ``backend="auto"``: the
+Pallas kernel on TPU, the gathered jnp lowering in ``ref.py`` on CPU (which
+XLA:CPU vectorizes).  Nothing falls back at run time: a kernel that does not
+lower raises.  Two explicit choices exist for cross-checks:
 
-  * ``"auto"``      — Pallas on TPU, oracle on CPU (production default; the
-                      dry-run lowers the oracle path so CPU-XLA compiles it)
   * ``"pallas"``    — force the kernel (interpret=True off-TPU)
-  * ``"ref"``       — force the oracle
+  * ``"ref"``       — force the masked jnp oracle
 
 Wrappers own the padding to block multiples so callers see arbitrary shapes.
 """
@@ -24,7 +23,7 @@ import numpy as np
 from . import ref
 from .fixedpoint_matmul import BK, BM, BN, fixedpoint_matmul_pallas
 from .fixedpoint_mlp import BB, KERNEL_VARIANTS, fixedpoint_mlp_pallas
-from .flow_update import flow_update_gather, flow_update_pallas
+from .flow_update import flow_update_gather
 from .forest_traversal import (FB, FOREST_VARIANTS, forest_range_pallas,
                                forest_traverse_pallas)
 from .taylor_activation import BC, BR, taylor_activation_pallas
@@ -81,7 +80,8 @@ def fused_mlp(x_q: jax.Array, slot: jax.Array, w: jax.Array, b: jax.Array,
     masked GEMM is row-independent).
 
     ``variant`` selects the weight lane (``kernels.KERNEL_VARIANTS``):
-    ``"int16"`` is the PR-1 int32-operand dot; ``"int8"`` saturates feature
+    ``"int16"`` takes weights of up to 16 bits (bf16 digit-plane dots in the
+    kernel); ``"int8"`` saturates feature
     codes into the int8 lane per layer and narrows both dot operands to int8
     (v5e MXU native rate).  Weight codes must already fit int8 — install
     models through a ``ControlPlane(weight_bits=8)``; the engine rejects an
@@ -112,15 +112,17 @@ def fused_mlp(x_q: jax.Array, slot: jax.Array, w: jax.Array, b: jax.Array,
     # per-generation ControlPlane snapshot is the known TPU optimization
     # (ROADMAP: multi-backend fused kernel) — needs a layer-major ModelTables
     # variant and a device to measure on.
-    wl = jnp.transpose(w, (1, 0, 2, 3)).astype(jnp.int32).reshape(
+    # (the weight table keeps its storage dtype: the "int16" kernel sizes
+    # its digit split by it)
+    wl = jnp.transpose(w, (1, 0, 2, 3)).reshape(
         n_layers, n_models * width, width)
     bl = jnp.transpose(b, (1, 0, 2)).astype(jnp.int32)
     al = jnp.transpose(act, (1, 0)).astype(jnp.int32)[:, :, None]
     onl = jnp.transpose(layer_on, (1, 0)).astype(jnp.int32)[:, :, None]
     slot2 = slot.astype(jnp.int32)[:, None]
-    if not use_pallas:  # backend == "ref": the literal kernel oracle
-        return ref.fused_mlp_ref(x_q, slot2, wl, bl, al, onl, frac=frac,
-                                 sig_coeffs=coeffs,
+    if not use_pallas:  # backend == "ref": the masked-GEMM oracle
+        return ref.fused_mlp_ref(x_q, slot2, wl.astype(jnp.int32), bl, al,
+                                 onl, frac=frac, sig_coeffs=coeffs,
                                  leaky_alpha_q=leaky_alpha_q,
                                  lane_bits=lane_bits)
     if variant == "int8":
@@ -154,8 +156,8 @@ def forest_traverse(x_q: jax.Array, slot: jax.Array, nodes: jax.Array,
       lane c = ``1 << frac`` per tree voting class c).
 
     The kernel wants tree-major field-major operands — ``nodes_t`` as
-    ``(T, F, 5·N)`` so the per-packet forest select becomes one dot per tree
-    — and a batch padded to the tile size.  Padded rows run slot 0 and are
+    ``(T, F, 5·N)`` so the per-packet forest select is one row select per
+    tree — and a batch padded to the tile size.  Padded rows run slot 0 and are
     sliced off (the masked traversal is row-independent).  Backend dispatch
     mirrors ``fused_mlp``: Pallas on TPU (interpreted when forced off-TPU),
     the gathered batched lowering on CPU, the masked jnp oracle for
@@ -200,7 +202,7 @@ def forest_traverse(x_q: jax.Array, slot: jax.Array, nodes: jax.Array,
         on_t = jnp.transpose(tree_on, (1, 0)).astype(jnp.int32)[:, :, None]
         mode2 = mode.astype(jnp.int32)[:, None]
         slot2 = slot.astype(jnp.int32)[:, None]
-        if not use_pallas:  # backend == "ref": the literal kernel oracle
+        if not use_pallas:  # backend == "ref": the masked jnp oracle
             return ref.forest_range_ref(x_q, slot2, rng_t, on_t, mode2,
                                         n_entries=ni, n_leaves=nl, frac=frac)
         xp = _pad_to(x_q, (FB, 1))
@@ -247,29 +249,23 @@ def flow_update(state, cms, slots, cells, ts, length, live, *, frac: int,
     engine) owns the register file and feeds each batch the previous
     batch's output state.
 
-    Backend dispatch mirrors the other wrappers — with one host-side twist:
-    the production CPU path (``"auto"`` off-TPU) is **numpy**, not jnp,
-    because the flow engine is a host-side ingress stage (the register file
-    lives next to the flow hash table) and the rank-round lowering there
-    beats any jit'd sequential scan by orders of magnitude.  ``copy=False``
-    lets that path update the register file in place — the serving hot
-    path.  ``rank`` optionally carries each packet's within-flow
-    occurrence order (the flow table computes it as a dedup by-product) so
-    the CPU lowering skips re-ranking; the other backends ignore it (the
-    kernel and oracle walk in batch order anyway).  ``"pallas"`` runs the
-    kernel (interpreted off-TPU) and ``"ref"`` the pure-Python oracle;
-    both always return fresh arrays.
+    The stage runs on the host on every platform: ``"auto"`` is the numpy
+    rank-round lowering, because the register file lives next to the flow
+    hash table (which owns eviction and failover migration) and the
+    rank-round walk beats any sequential device scan.  ``copy=False`` lets
+    it update the register file in place — the serving hot path.  ``rank``
+    optionally carries each packet's within-flow occurrence order (the flow
+    table computes it as a dedup by-product) so the lowering skips
+    re-ranking.  ``"ref"`` runs the pure-Python oracle and returns fresh
+    arrays.
     """
-    if backend not in ("auto", "pallas", "ref"):
-        raise ValueError(f"unknown backend: {backend!r}")
     kw = dict(frac=frac, ewma_shift=ewma_shift, byte_shift=byte_shift,
               dur_shift=dur_shift)
     if backend == "ref":
         return ref.flow_update_numpy(state, cms, slots, cells, ts, length,
                                      live, **kw)
-    if backend == "pallas" or on_tpu():
-        return flow_update_pallas(state, cms, slots, cells, ts, length,
-                                  live, interpret=not on_tpu(), **kw)
+    if backend != "auto":
+        raise ValueError(f"unknown backend: {backend!r}")
     return flow_update_gather(np.asarray(state), np.asarray(cms), slots,
                               cells, ts, length, live, copy=copy, rank=rank,
                               **kw)

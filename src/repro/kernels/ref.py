@@ -9,6 +9,7 @@ import numpy as np
 
 __all__ = ["fixedpoint_matmul_ref", "taylor_activation_ref", "fused_mlp_ref",
            "fused_mlp_gather_ref", "rounding_rshift", "lane_clamp",
+           "slot_select",
            "wkv_scan_ref", "forest_traverse_numpy", "forest_traverse_ref",
            "forest_traverse_gather_ref", "forest_range_ref",
            "forest_range_gather_ref", "FOREST_REGRESS", "FOREST_CLASSIFY",
@@ -80,26 +81,32 @@ def fixedpoint_matmul_ref(x_codes: jax.Array, w_codes: jax.Array,
     return out
 
 
+def slot_select(slot: jax.Array, table: jax.Array) -> jax.Array:
+    """Per-packet table row ``table[slot]`` as a chain of two-arm selects
+    over the table's ``K`` rows — the slot dispatch of the Pallas kernels.
+
+    slot (B, 1) int32 in ``[0, K)`` · table (K, C) → (B, C).  Exact for
+    any integer table (no products are formed), and it lowers to plain VPU
+    selects: the TPU's matrix unit has no int32×int32 dot to do a one-hot
+    contraction with."""
+    out = jnp.zeros((slot.shape[0], table.shape[1]), table.dtype)
+    for k in range(table.shape[0]):  # static: K is a synthesis-time bound
+        out = jnp.where(slot == k, table[k:k + 1, :], out)
+    return out
+
+
 def _select_activation_ref(y: jax.Array, opcode: jax.Array, *, frac: int,
-                           sig_coeffs, leaky_alpha_q: int,
-                           lowering: str = "select_n") -> jax.Array:
+                           sig_coeffs, leaky_alpha_q: int) -> jax.Array:
     """Opcode-gated integer activation (opcodes as in core.control_plane:
     1=relu, 2=taylor-sigmoid, 3=leaky-relu, 4=hard-sigmoid; anything else
     is the identity).
 
     All five arms are computed unconditionally (they are cheap VPU
     elementwise chains; per-packet opcodes make real branching impossible
-    anyway) and one selection picks each lane's arm.  ``lowering`` chooses
-    the selection form — shared by the Pallas kernel and both jnp oracles,
-    so the choice can never split the bit-exactness contract:
-
-      * ``"select_n"`` (default) — one branchless opcode-indexed
-        ``jax.lax.select_n`` over the five arms: the opcode is clamped to
-        the valid range (invalid → case 0 = identity, same semantics as
-        the chain) and a single N-way select replaces four dependent
-        2-way selects.
-      * ``"where_chain"`` — the original four-deep ``jnp.where`` chain,
-        kept for the before/after comparison in the bench.
+    anyway) and a chain of two-arm selects picks each lane's arm.  The one
+    definition is shared by the Pallas kernel and both jnp oracles, so the
+    selection can never split the bit-exactness contract (Mosaic lowers
+    only two-arm selects).
     """
     relu = jnp.maximum(y, 0)
     leaky = jnp.where(y > 0, y,
@@ -111,10 +118,6 @@ def _select_activation_ref(y: jax.Array, opcode: jax.Array, *, frac: int,
     half = jnp.int32(1 << (frac - 1))
     one = jnp.int32(1 << frac)
     hsig = jnp.clip(half + rounding_rshift(y, 2), 0, one)
-    if lowering == "select_n":
-        idx = jnp.where((opcode >= 1) & (opcode <= 4), opcode, 0)
-        idx = jnp.broadcast_to(idx, y.shape)
-        return jax.lax.select_n(idx, y, relu, sig, leaky, hsig)
     out = y
     out = jnp.where(opcode == 1, relu, out)
     out = jnp.where(opcode == 2, sig, out)
@@ -127,8 +130,9 @@ def fused_mlp_ref(x_q: jax.Array, slot: jax.Array, w: jax.Array, b: jax.Array,
                   act: jax.Array, layer_on: jax.Array, *, frac: int,
                   sig_coeffs, leaky_alpha_q: int,
                   lane_bits: int | None = None) -> jax.Array:
-    """Oracle for the fused multi-model MLP kernel — identical masked-GEMM
-    formulation in plain jnp.  This is the *cross-check* path
+    """Oracle for the fused multi-model MLP kernel — the same masked-GEMM
+    formulation as one int32 dot per layer in plain jnp (the kernel splits
+    it into exact narrow dots).  This is the *cross-check* path
     (``backend="ref"``): the production CPU lowering is
     :func:`fused_mlp_gather_ref` below (XLA:CPU scalarizes wide s32 GEMMs,
     so the gathered batched-matvec form wins there; ``ops.fused_mlp``
@@ -264,7 +268,7 @@ def forest_traverse_ref(x_q: jax.Array, slot: jax.Array, nodes_t: jax.Array,
                         tree_on_t: jax.Array, mode: jax.Array, *,
                         max_depth: int, frac: int) -> jax.Array:
     """Masked (one-hot) jnp oracle for the Pallas traversal kernel — the
-    literal kernel formulation, operand for operand.
+    kernel's formulation in plain int32 jnp.
 
     Kernel layout (see ``ops.forest_traverse`` for the prep):
       x_q (B, W) int32 · slot (B, 1) int32 in [0, F) ·
@@ -272,10 +276,10 @@ def forest_traverse_ref(x_q: jax.Array, slot: jax.Array, nodes_t: jax.Array,
       (``nodes_t[t, f, field·N + n]``) · tree_on_t (T, F, 1) int32 ·
       mode (F, 1) int32.  Returns (B, W) int32.
 
-    The per-packet forest select is one (B, F) one-hot dot per tree
-    (gathering that tree's whole node table for every packet); the per-step
-    node/feature selects are iota-compare row reductions — exactly what the
-    kernel runs on the VPU.
+    The per-packet forest select is one (B, F) one-hot int32 dot per tree
+    (gathering that tree's whole node table for every packet — the kernel
+    picks the same rows with :func:`slot_select`); the per-step node/feature
+    selects are iota-compare row reductions, as in the kernel.
     """
     n_batch, width = x_q.shape
     n_trees, n_forests, ncols = nodes_t.shape
@@ -432,10 +436,10 @@ def forest_range_gather_ref(x_q: jax.Array, slot: jax.Array,
 def forest_range_ref(x_q: jax.Array, slot: jax.Array, rng_t: jax.Array,
                      tree_on_t: jax.Array, mode: jax.Array, *,
                      n_entries: int, n_leaves: int, frac: int) -> jax.Array:
-    """Masked (one-hot) jnp oracle for the Pallas range kernel — the literal
-    kernel formulation, operand for operand (the ``backend="ref"`` path of
-    ``variant="range"``, exactly like :func:`forest_traverse_ref` for the
-    chase kernel).
+    """Masked (one-hot) jnp oracle for the Pallas range kernel — the
+    kernel's formulation in plain int32 jnp, its forest select a one-hot
+    dot (the ``backend="ref"`` path of ``variant="range"``, exactly like
+    :func:`forest_traverse_ref` for the chase kernel).
 
     Kernel layout (see ``ops.forest_traverse`` for the prep): rng_t
     ``(T, F, 3·NI + L)`` int32, tree-major with field-major columns

@@ -13,19 +13,10 @@ __all__ = ["make_mesh", "make_production_mesh", "shard_devices", "HW"]
 
 
 def make_mesh(shape, axes):
-    """Version-portable ``jax.make_mesh``.
-
-    ``axis_types=(AxisType.Auto, …)`` only exists from jax 0.5; on 0.4.x the
-    keyword (and ``jax.sharding.AxisType`` itself) is absent and plain meshes
-    are implicitly Auto.  Every mesh in this repo is fully-Auto, so the two
-    spellings are semantically identical.
-    """
-    try:
-        axis_type = jax.sharding.AxisType.Auto
-        return jax.make_mesh(shape, axes,
-                             axis_types=(axis_type,) * len(axes))
-    except (AttributeError, TypeError):
-        return jax.make_mesh(shape, axes)
+    """``jax.make_mesh`` with every axis explicitly ``Auto`` (the sharding
+    mode every mesh in this repo uses)."""
+    return jax.make_mesh(shape, axes, axis_types=(
+        jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def shard_devices(n_shards: int):

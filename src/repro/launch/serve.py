@@ -171,6 +171,12 @@ class PacketServer:
         families."""
         return self.control_plane.install_forest(model_id, forest)
 
+    def warm(self) -> None:
+        """Compile every serving program the pipeline will dispatch (see
+        :meth:`IngressPipeline.compile_programs`): a kernel that does not
+        lower raises here, before any traffic is taken."""
+        self.ingress.compile_programs()
+
     def remove(self, model_id: int) -> None:
         """Uninstall a model and drop its cached egress rows."""
         self.control_plane.remove(model_id)
@@ -498,7 +504,8 @@ def main(argv=None) -> int:
 
     The point is operational: CI's smoke bench runs this with
     ``--metrics-json`` to archive a metrics artifact per build, and
-    ``--prometheus`` prints the text-exposition form for eyeballing."""
+    ``--prometheus`` prints the text-exposition form for eyeballing.
+    Exits 1 when any packet resolved to an error slot."""
     import argparse
     import json
 
@@ -530,7 +537,9 @@ def main(argv=None) -> int:
     args = p.parse_args(argv)
 
     from ..data.packets import raw_trace
+    from .compile_cache import enable as enable_compile_cache
 
+    enable_compile_cache()
     width = 16
     kw: Dict[str, Any] = dict(
         max_models=4, max_width=width, ingress_batch=256, max_inflight=2,
@@ -576,7 +585,7 @@ def main(argv=None) -> int:
           f"{n_err} error slots"
           + (f"; metrics -> {args.metrics_json}"
              if args.metrics_json else ""))
-    return 0
+    return 1 if n_err else 0
 
 
 if __name__ == "__main__":

@@ -77,7 +77,7 @@ from typing import Dict, List, Optional, Tuple, Union
 import numpy as np
 
 from ..core.control_plane import ControlPlane
-from ..core.inference import DataPlaneEngine
+from ..core.inference import CompileError, DataPlaneEngine
 from ..core.ingress import IngressPipeline, PacketError, hash_words
 from ..data.packets import (RAW_KEY_BYTES, RawHeaderBatch,
                             parse_raw_headers, validate_raw_rows)
@@ -368,6 +368,13 @@ class ShardedPacketServer:
         with self._lock:
             self.control_plane.remove_reflex(model_id)
 
+    def warm(self) -> None:
+        """Compile every shard's serving programs on its own device (see
+        :meth:`PacketServer.warm`)."""
+        with self._lock:
+            for sh in self.shards:
+                sh.pipeline.compile_programs()
+
     def remove(self, model_id: int) -> None:
         with self._lock:
             self.control_plane.remove(model_id)
@@ -520,6 +527,8 @@ class ShardedPacketServer:
                         self.shards[s].flow.submit_raw(
                             rows[sel], fields=fields_s,
                             cms_est_q=est_q[sel])
+                    except CompileError:
+                        raise  # a deployment fault: no shard is to blame
                     except Exception as e:  # shard wedged at submit
                         self.fault_stats["fabric_submit_failures_total"] += 1
                         self._window_degraded = True
@@ -593,6 +602,8 @@ class ShardedPacketServer:
                                  (deadline - time.perf_counter()) * 1e6)
                 try:
                     per.append(deque(sh.pipeline.drain(budget)))
+                except CompileError:
+                    raise
                 except Exception as e:  # a wedged shard cannot hang drain
                     self._window_degraded = True
                     per.append(deque())

@@ -65,6 +65,27 @@ class TestFusedKernel:
         np.testing.assert_array_equal(outs["auto"], outs["ref"])
         np.testing.assert_array_equal(gathered, outs["ref"])
 
+    def test_pallas_exact_over_full_int32_range(self):
+        """Codes over the whole int32 range against weight codes at the
+        int16 rails: the kernel's digit-plane dots must reproduce the
+        oracle's wrapping int32 dot bit for bit."""
+        rng = np.random.default_rng(5)
+        width, n_models, batch = 8, 4, 64
+        cp = ControlPlane(max_models=n_models, max_layers=2,
+                          max_width=width, frac_bits=FRAC)
+        _install_zoo(cp, rng, n_models, width, scale=200.0)
+        t = cp.tables()
+        assert np.abs(np.asarray(t.w)).max() == 2 ** 15 - 1  # rails hit
+        x = jnp.asarray(rng.integers(-2 ** 31, 2 ** 31, (batch, width),
+                                     dtype=np.int64).astype(np.int32))
+        slot = jnp.asarray(rng.integers(0, n_models, batch), jnp.int32)
+        kw = dict(frac=FRAC, sig_coeffs=scaled_constants("sigmoid", 3, FRAC),
+                  leaky_alpha_q=3)
+        a, b = (np.asarray(fused_mlp(x, slot, t.w, t.b, t.act, t.layer_on,
+                                     backend=bk, **kw))
+                for bk in ("pallas", "ref"))
+        np.testing.assert_array_equal(a, b)
+
     def test_pallas_padding_path(self):
         """Batch sizes that are not tile multiples round-trip unharmed."""
         rng = np.random.default_rng(0)

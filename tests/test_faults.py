@@ -242,6 +242,37 @@ class TestGracefulPipeline:
         ref.submit_raw(raw)
         _assert_bitexact(srv.drain_packets(), ref.drain_packets())
 
+    @pytest.mark.parametrize("n_shards", [0, 2])
+    def test_compile_error_raises_not_retried(self, n_shards):
+        """A serving program that does not lower is a deployment fault: it
+        raises CompileError to the caller — no retry, no bisection probe,
+        no dispatch failure, no strike against a shard."""
+        import jax
+
+        from repro.core.inference import CompileError
+
+        def refuse(*a, **kw):
+            raise NotImplementedError("kernel refused by the compiler")
+
+        srv = _fabric(n_shards) if n_shards else _plain()
+        pipes = ([sh.pipeline for sh in srv.shards] if n_shards
+                 else [srv.ingress])
+        for p in pipes:
+            p.engine._serve = jax.jit(
+                refuse, static_argnames=("use_mlp", "use_forest"))
+        with pytest.raises(CompileError, match="refused by the compiler"):
+            srv.submit_raw(_trace(300, 5))
+            srv.drain_packets()
+        for p in pipes:
+            for k in ("ingress_dispatch_retries_total",
+                      "ingress_dispatch_failures_total",
+                      "ingress_probe_batches_total"):
+                assert p.stats[k] == 0, k
+        if n_shards:
+            faults = srv.stats()["faults"]
+            assert faults["fabric_watchdog_strikes_total"] == 0
+            assert faults["fabric_deaths_total"] == 0
+
 
 class TestCrashSafeInstalls:
     def _forest(self):

@@ -144,6 +144,18 @@ class TestTrainer:
         assert t.depth() <= 3
         assert t.n_nodes <= 11
 
+    @pytest.mark.parametrize("max_nodes", [7, 16, 31, 64])
+    def test_node_budget_is_hard_on_deep_trees(self, max_nodes):
+        """Noisy labels grow every branch to full depth, so the budget binds
+        while right siblings are still pending: the tree must stay within
+        it (a control plane with the same max_nodes accepts it)."""
+        rng = np.random.default_rng(max_nodes)
+        X = rng.normal(size=(2000, WIDTH))
+        y = rng.integers(0, 2, 2000)
+        t = train_tree(X, y, task="classify", max_depth=8,
+                       max_nodes=max_nodes, min_leaf=1)
+        assert t.n_nodes <= max_nodes
+
     def test_import_path_round_trips(self):
         """from_arrays on a trained tree's own arrays predicts identically."""
         rng = np.random.default_rng(3)

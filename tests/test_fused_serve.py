@@ -5,9 +5,6 @@
     makes the feature-domain pipeline byte-exact with the wire path
   * the feature path (``DataPlaneEngine.run_features`` over
     ``kernels.fused_serve.serve_lanes``) equals the wire program end to end
-  * the one-dispatch raw program (``fused_serve.serve_raw``: flow-update
-    kernel → in-program spec take → lanes → egress encode) reproduces the
-    staged ``submit_raw`` path bit for bit
   * the cold-traffic admission gate: unique traffic stops paying cache
     insert sweeps, reappearing duplication re-opens admission — with
     correctness invariant either way
@@ -25,7 +22,7 @@ from repro.core import packet as pk
 from repro.core.control_plane import ControlPlane
 from repro.core.inference import DataPlaneEngine
 from repro.core.ingress import IngressPipeline
-from repro.data.packets import anomaly_dataset, raw_trace
+from repro.data.packets import anomaly_dataset
 from repro.forest import train_forest
 from repro.launch.serve import PacketServer
 
@@ -148,33 +145,6 @@ class TestFeaturePath:
         want = np.asarray(srv.engine.process(np.concatenate(chunks)))
         np.testing.assert_array_equal(
             np.stack(got), want[:, : srv.ingress.out_bytes])
-
-
-class TestServeRawFused:
-    def test_one_dispatch_program_matches_staged_path(self):
-        """serve_raw (flow-update kernel → in-program spec take → lanes →
-        egress encode, one jit) equals submit_raw + drain on identical
-        arrivals — the fused program is a deployment shape, not a semantics
-        change."""
-        rng = np.random.default_rng(3)
-        srv_a = _mixed_server(np.random.default_rng(42))
-        srv_b = _mixed_server(np.random.default_rng(42))
-        for srv in (srv_a, srv_b):
-            srv.install_feature_spec(1, (2, 3, 4, 5))
-            srv.install_feature_spec(3, (0, 7, 1))
-        raw = raw_trace(rng, 400, n_flows=16, model_ids=(1, 3),
-                        pattern="mixed")
-        srv_a.submit_raw(raw)
-        want = np.stack(srv_a.drain_packets())
-        got = srv_b.flow.serve_raw_fused(raw)
-        np.testing.assert_array_equal(got[:, : want.shape[1]], want)
-        # flow state advanced identically: a second batch still agrees
-        raw2 = raw_trace(np.random.default_rng(4), 200, n_flows=16,
-                         model_ids=(1, 3), pattern="periodic")
-        srv_a.submit_raw(raw2)
-        want2 = np.stack(srv_a.drain_packets())
-        got2 = srv_b.flow.serve_raw_fused(raw2)
-        np.testing.assert_array_equal(got2[:, : want2.shape[1]], want2)
 
 
 class TestAdmissionGate:
