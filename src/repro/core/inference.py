@@ -44,12 +44,11 @@ old buffers; the next batch picks up the new generation).
 
 ``run(pkts, block=False)`` dispatches without waiting for the device —
 callers (``launch.serve.PacketServer``) overlap host-side packet encode with
-device compute and reconcile timing at drain.
+device compute.
 """
 
 from __future__ import annotations
 
-import time
 from typing import Sequence
 
 import jax
@@ -58,6 +57,7 @@ import jax.numpy as jnp
 from ..kernels.fused_serve import LaneConfig, serve_lanes
 from ..kernels.forest_traversal import FOREST_VARIANTS
 from ..kernels.ops import on_tpu
+from ..obs import no_span
 from .control_plane import ControlPlane, ForestTables, ModelTables
 from .packet import FEATURE_BYTES, HEADER_BYTES, emit_results, parse_packets
 from .taylor import scaled_constants
@@ -171,7 +171,10 @@ class DataPlaneEngine:
             forest_variant=forest_variant)
         self.out_features = min(max_features, int(control_plane.max_width))
         self.trace_count = 0
-        self.stats = {"packets": 0, "bytes_in": 0, "bytes_out": 0, "seconds": 0.0}
+        self.stats = {"packets": 0, "bytes_in": 0, "bytes_out": 0}
+        # layer span of a program compile: the owning ingress pipeline
+        # binds its own (``Observability.span`` under its shard)
+        self.span = no_span
         self._process = jax.jit(self._process_impl,
                                 static_argnames=("use_mlp", "use_forest"))
         self._serve = jax.jit(self._serve_impl,
@@ -241,7 +244,7 @@ class DataPlaneEngine:
         returned array is a device future, so callers can pipeline host-side
         encode/decode of neighbouring batches against device compute (see
         ``PacketServer.submit_async``).  Packet/byte counters update
-        immediately; wall-clock is accounted by the blocking caller.
+        immediately.
 
         ``lanes`` is the lane-pure dispatch hint: ``"both"`` (default —
         correct for any batch), ``"mlp"`` or ``"forest"`` skip the other
@@ -255,7 +258,6 @@ class DataPlaneEngine:
         tables = self.cp.tables(device=self.device)  # current generation
         use_mlp, use_forest = self._lane_flags(lanes)
         ftables, rtables = self._forest_snapshots(use_forest)
-        t0 = time.perf_counter()
         out = self._process(pkts, tables, ftables, rtables, use_mlp=use_mlp,
                             use_forest=use_forest)
         self.stats["packets"] += int(pkts.shape[0])
@@ -263,7 +265,6 @@ class DataPlaneEngine:
         self.stats["bytes_out"] += int(out.size)
         if block:
             out.block_until_ready()
-            self.stats["seconds"] += time.perf_counter() - t0
         return out
 
     def run_features(self, feats_q, model_id, *, block: bool = True,
@@ -275,8 +276,8 @@ class DataPlaneEngine:
 
         feats_q (B, W) int32 codes at the engine's ``frac`` · model_id (B,)
         int32 → device future of (B, out_features) int32 output codes.
-        Byte counters credit the equivalent wire row sizes, so
-        ``throughput_gbps`` stays comparable across the two surfaces.
+        Byte counters credit the equivalent wire row sizes, so they stay
+        comparable across the two surfaces.
         """
         if lanes not in ("both", "mlp", "forest"):
             raise ValueError(f"unknown lanes hint: {lanes!r}")
@@ -287,7 +288,6 @@ class DataPlaneEngine:
         args = (feats_q, model_id, tables,
                 *self._forest_snapshots(use_forest))
         program = self._program(args, use_mlp, use_forest)
-        t0 = time.perf_counter()
         out = program(*args)
         n = int(feats_q.shape[0])
         self.stats["packets"] += n
@@ -297,21 +297,22 @@ class DataPlaneEngine:
                                         + FEATURE_BYTES * self.out_features)
         if block:
             out.block_until_ready()
-            self.stats["seconds"] += time.perf_counter() - t0
         return out
 
     def _program(self, args, use_mlp: bool, use_forest: bool):
         key = (int(args[0].shape[0]), use_mlp, use_forest)
         program = self._programs.get(key)
         if program is None:
-            try:
-                program = self._serve.lower(
-                    *args, use_mlp=use_mlp, use_forest=use_forest).compile()
-            except Exception as e:  # noqa: BLE001 — any lowering failure
-                raise CompileError(
-                    f"serving program ({key[0]} rows, lanes="
-                    f"{_LANE_NAMES[key[1:]]}) failed to compile on "
-                    f"{jax.default_backend()}: {e}") from e
+            with self.span("engine.compile"):
+                try:
+                    program = self._serve.lower(
+                        *args, use_mlp=use_mlp,
+                        use_forest=use_forest).compile()
+                except Exception as e:  # noqa: BLE001 — any lowering failure
+                    raise CompileError(
+                        f"serving program ({key[0]} rows, lanes="
+                        f"{_LANE_NAMES[key[1:]]}) failed to compile on "
+                        f"{jax.default_backend()}: {e}") from e
             self._programs[key] = program
         return program
 
@@ -365,32 +366,19 @@ class DataPlaneEngine:
                 self.run_features(x0, mid, block=True, lanes=lane)
         self.stats = before
 
-    def add_seconds(self, dt: float) -> None:
-        """Credit wall-clock spent by an external async drain loop."""
-        self.stats["seconds"] += dt
-
     def credit_packets(self, n: int) -> None:
         """Adjust the served-packet counter on behalf of the ingress
         pipeline: positive for packets it served without a device dispatch
         (cache hits, coalesced duplicates), negative for dead padding rows
-        inside a dispatched batch — so ``packets_per_second()`` reflects
-        packets actually served, not device rows."""
+        inside a dispatched batch — so the counter reflects packets
+        actually served, not device rows."""
         self.stats["packets"] += int(n)
 
     def credit_bytes(self, n_in: int, n_out: int) -> None:
         """Byte-counter analogue of :meth:`credit_packets` — the pipeline
         uses a negative credit to cancel a dispatch it discarded (the
-        lane-race redispatch), so throughput_gbps never double-counts the
+        lane-race redispatch), so the byte counters never double-count the
         dropped batch's wire bytes."""
         self.stats["bytes_in"] += int(n_in)
         self.stats["bytes_out"] += int(n_out)
 
-    def throughput_gbps(self) -> float:
-        s = self.stats
-        if s["seconds"] == 0:
-            return 0.0
-        return (s["bytes_in"] + s["bytes_out"]) * 8 / s["seconds"] / 1e9
-
-    def packets_per_second(self) -> float:
-        s = self.stats
-        return s["packets"] / s["seconds"] if s["seconds"] else 0.0

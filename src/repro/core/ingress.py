@@ -75,6 +75,7 @@ Packet-level flow::
 from __future__ import annotations
 
 import dataclasses
+import functools
 import time
 from collections import deque
 from typing import Deque, Dict, List, Optional, Tuple, Union
@@ -84,7 +85,7 @@ import numpy as np
 from .inference import CompileError
 from .packet import (FEATURE_BYTES, FLAG_REFLEX, HEADER_BYTES,
                      emit_results_np, parse_packets_np)
-from ..obs import Observability, StatsAdapter
+from ..obs import Observability, StatsAdapter, no_span
 
 __all__ = ["PacketError", "BatchError", "ResultCache", "IngressPipeline",
            "pack_rows", "STATUS_PENDING", "STATUS_READY", "STATUS_ERROR",
@@ -267,6 +268,9 @@ class ResultCache:
         self.flushes = 0
         self.compactions = 0
         self.stale_inserts_dropped = 0
+        # layer span of maintenance (flush, compaction): the owning
+        # pipeline binds its own
+        self.span = no_span
 
     # -- internals --------------------------------------------------------
 
@@ -291,28 +295,31 @@ class ResultCache:
     # -- public API -------------------------------------------------------
 
     def clear(self) -> None:
-        self._state[:] = 0
-        self._count = 0
-        self._tombstones = 0
-        self.flushes += 1
+        with self.span("cache.compact"):
+            self._state[:] = 0
+            self._count = 0
+            self._tombstones = 0
+            self.flushes += 1
 
     def _compact(self) -> None:
         """Rebuild the table in place, dropping every tombstone (live
         entries re-hash onto clean probe chains).  Best-effort like the
         rest of the cache: a re-inserted entry that exhausts its probe
         budget is dropped, never corrupted."""
-        live = self._state == 1
-        keys = self._keys[live].copy()
-        vals = self._vals[live].copy()
-        mids = self._model[live].copy()
-        self._state[:] = 0
-        self._count = 0
-        self._tombstones = 0
-        self.compactions += 1
-        if keys.shape[0]:
-            ins0 = self.insertions  # re-admissions are not new insertions
-            self.insert(keys, vals, mids, self._gen)
-            self.insertions = ins0
+        with self.span("cache.compact"):
+            live = self._state == 1
+            keys = self._keys[live].copy()
+            vals = self._vals[live].copy()
+            mids = self._model[live].copy()
+            self._state[:] = 0
+            self._count = 0
+            self._tombstones = 0
+            self.compactions += 1
+            if keys.shape[0]:
+                # re-admissions are not new insertions
+                ins0 = self.insertions
+                self.insert(keys, vals, mids, self._gen)
+                self.insertions = ins0
 
     @property
     def tombstones(self) -> int:
@@ -808,6 +815,14 @@ class IngressPipeline:
         # standalone pipeline gets a private one.
         self.obs = obs if obs is not None else Observability(clock=clock)
         self.tracer = self.obs.make_tracer(shard=self.shard_id, clock=clock)
+        # layer spans under this shard's label; the engine, the result
+        # cache and the pending window time their own layers through it
+        self.obs.layer_spans.register(self.shard_id)
+        self.span = functools.partial(self.obs.span, shard=self.shard_id)
+        engine.span = self.span
+        for c in (self.cache, self._pending):
+            if c is not None:
+                c.span = self.span
         # model-quality plane (PR 9): the feature/prediction taps read
         # ``self.obs.drift`` per batch (one attribute check when off); an
         # attached ShadowScorer samples staged rows into its replay lane
@@ -838,11 +853,6 @@ class IngressPipeline:
         _c("ingress_reflex_served_total")
         _c("ingress_shed_total")
         _c("ingress_drain_timeouts_total")
-        # dispatch→retire wall cost per device batch — the deadline
-        # scheduler's safety margin is the EWMA of these samples
-        self._h_dispatch = reg.histogram(
-            "ingress_dispatch_seconds",
-            "device batch dispatch→retire wall seconds", shard=sid)
         lanes_sub = StatsAdapter()
         for lane in ("mlp", "forest", "both"):
             lanes_sub.bind(lane, reg.counter("ingress_lane_batches_total",
@@ -945,13 +955,14 @@ class IngressPipeline:
         full.  With ``flush_after`` set, an over-age partial staging batch
         is dispatched (padded) before this call returns.
         """
-        try:
-            first, n = self._submit(pkts)
-            self._observe_rate(n)
-            return first, n
-        finally:
-            self._maybe_flush_aged()
-            self._maybe_close_deadline()
+        with self.span("ingress.ingest"):
+            try:
+                first, n = self._submit(pkts)
+                self._observe_rate(n)
+                return first, n
+            finally:
+                self._maybe_flush_aged()
+                self._maybe_close_deadline()
 
     def poll(self) -> bool:
         """Latency-SLO tick for callers with idle arrival gaps: dispatch
@@ -1050,42 +1061,43 @@ class IngressPipeline:
         at their submission-order positions — ``error_reason`` is one
         string or a per-row sequence — and never touch the cache, the
         pending window, or a device batch."""
-        try:
-            x0 = np.ascontiguousarray(x0, np.int32)
-            n = x0.shape[0]
-            first = self._n_tickets
-            tickets = self._alloc_tickets(n)
-            if n == 0:
-                return first, 0
-            self.stats["ingress_packets_total"] += n
-            mid = np.ascontiguousarray(model_id, np.int32).reshape(n)
-            fl = (np.zeros(n, np.int32) if flags is None
-                  else np.ascontiguousarray(flags, np.int32).reshape(n))
-            tickets_g = tickets
-            if error_mask is not None:
-                em = np.asarray(error_mask, bool).reshape(n)
-                if em.any():
-                    reasons = (error_reason if isinstance(error_reason, str)
-                               else np.asarray(error_reason, object)[em])
-                    self._mark_errors(tickets[em], reasons)
-                    good = np.nonzero(~em)[0]
-                    if good.size == 0:
-                        return first, n
-                    x0, mid, fl = x0[good], mid[good], fl[good]
-                    tickets_g = tickets[good]
-            if x0.shape[1] < self.width:
-                x0 = np.concatenate(
-                    [x0, np.zeros((x0.shape[0], self.width - x0.shape[1]),
-                                  np.int32)],
-                    axis=1)
-            from .packet import encode_packets_np
-            rows = encode_packets_np(mid, self.engine.frac, x0, flags=fl)
-            self._ingest(rows, tickets_g, parsed=(mid, fl, x0))
-            self._observe_rate(n)
-            return first, n
-        finally:
-            self._maybe_flush_aged()
-            self._maybe_close_deadline()
+        with self.span("ingress.ingest"):
+            try:
+                x0 = np.ascontiguousarray(x0, np.int32)
+                n = x0.shape[0]
+                first = self._n_tickets
+                tickets = self._alloc_tickets(n)
+                if n == 0:
+                    return first, 0
+                self.stats["ingress_packets_total"] += n
+                mid = np.ascontiguousarray(model_id, np.int32).reshape(n)
+                fl = (np.zeros(n, np.int32) if flags is None
+                      else np.ascontiguousarray(flags, np.int32).reshape(n))
+                tickets_g = tickets
+                if error_mask is not None:
+                    em = np.asarray(error_mask, bool).reshape(n)
+                    if em.any():
+                        reasons = (error_reason
+                                   if isinstance(error_reason, str)
+                                   else np.asarray(error_reason, object)[em])
+                        self._mark_errors(tickets[em], reasons)
+                        good = np.nonzero(~em)[0]
+                        if good.size == 0:
+                            return first, n
+                        x0, mid, fl = x0[good], mid[good], fl[good]
+                        tickets_g = tickets[good]
+                if x0.shape[1] < self.width:
+                    pad = np.zeros((x0.shape[0], self.width - x0.shape[1]),
+                                   np.int32)
+                    x0 = np.concatenate([x0, pad], axis=1)
+                from .packet import encode_packets_np
+                rows = encode_packets_np(mid, self.engine.frac, x0, flags=fl)
+                self._ingest(rows, tickets_g, parsed=(mid, fl, x0))
+                self._observe_rate(n)
+                return first, n
+            finally:
+                self._maybe_flush_aged()
+                self._maybe_close_deadline()
 
     def _ingest(self, rows: np.ndarray, tickets: np.ndarray,
                 parsed=None) -> None:
@@ -1157,8 +1169,9 @@ class IngressPipeline:
         if n_fresh:
             fsel = miss_sel[uniq_idx[fresh]]
             if parsed is None:
-                fresh_mid, _, fresh_flags, fresh_x0 = parse_packets_np(
-                    rows[fsel], self.width)
+                with self.span("ingress.parse"):
+                    fresh_mid, _, fresh_flags, fresh_x0 = parse_packets_np(
+                        rows[fsel], self.width)
             else:
                 mid, flags, x0 = parsed
                 fresh_x0 = x0[fsel]
@@ -1491,29 +1504,30 @@ class IngressPipeline:
         device size.  ``deadlines`` (absolute clock seconds per row, inf
         when the row's model has no SLO) folds into the open batch's
         earliest deadline, which the deadline-aware closer watches."""
-        pos = 0
-        total = x0.shape[0]
-        while pos < total:
-            o = self._open.get(family)
-            if o is None:
-                o = self._open_batch(family, generation)
-            space = o.size - o.fill
-            take = min(space, total - pos)
-            lo, hi = o.fill, o.fill + take
-            self._stg_x0[o.buf][lo:hi] = x0[pos: pos + take]
-            self._stg_mid[o.buf][lo:hi] = mid[pos: pos + take]
-            self._stg_flags[o.buf][lo:hi] = flags[pos: pos + take]
-            self._staging_words[o.buf][lo:hi] = words[pos: pos + take]
-            self._staging_hashes[o.buf][lo:hi] = hashes[pos: pos + take]
-            o.miss_idx[lo:hi] = miss_idx[pos: pos + take]
-            if deadlines is not None:
-                dmin = float(deadlines[pos: pos + take].min())
-                if dmin < o.deadline:
-                    o.deadline = dmin
-            o.fill += take
-            pos += take
-            if o.fill == o.size:
-                self._dispatch(family)
+        with self.span("ingress.stage"):
+            pos = 0
+            total = x0.shape[0]
+            while pos < total:
+                o = self._open.get(family)
+                if o is None:
+                    o = self._open_batch(family, generation)
+                space = o.size - o.fill
+                take = min(space, total - pos)
+                lo, hi = o.fill, o.fill + take
+                self._stg_x0[o.buf][lo:hi] = x0[pos: pos + take]
+                self._stg_mid[o.buf][lo:hi] = mid[pos: pos + take]
+                self._stg_flags[o.buf][lo:hi] = flags[pos: pos + take]
+                self._staging_words[o.buf][lo:hi] = words[pos: pos + take]
+                self._staging_hashes[o.buf][lo:hi] = hashes[pos: pos + take]
+                o.miss_idx[lo:hi] = miss_idx[pos: pos + take]
+                if deadlines is not None:
+                    dmin = float(deadlines[pos: pos + take].min())
+                    if dmin < o.deadline:
+                        o.deadline = dmin
+                o.fill += take
+                pos += take
+                if o.fill == o.size:
+                    self._dispatch(family)
 
     def _dispatch(self, family: Optional[str] = None) -> None:
         if family is None:  # flush path: every open batch goes out
@@ -1525,6 +1539,12 @@ class IngressPipeline:
             return
         while len(self._inflight) >= self.max_inflight:
             self._retire_oldest()
+        with self.span("ingress.dispatch"):
+            self._launch(o)
+
+    def _launch(self, o: _OpenBatch) -> None:
+        """Put one closed staging batch on the device (padding, the
+        guarded run, the in-flight record)."""
         size = o.size
         x0 = self._stg_x0[o.buf][:size]
         mid = self._stg_mid[o.buf][:size]
@@ -1747,7 +1767,8 @@ class IngressPipeline:
             if rem > 0:       # injected slow device: the batch is not done
                 time.sleep(rem)
         try:
-            out = np.asarray(rec.future)  # blocks until the batch is done
+            with self.span("ingress.device_wait"):
+                out = np.asarray(rec.future)  # blocks until the batch is done
         except Exception as err:  # noqa: BLE001 — device died mid-batch
             # run_features credited this batch when it dispatched; cancel
             # so the salvage pass accounts it exactly once
@@ -1765,13 +1786,12 @@ class IngressPipeline:
         # an EWMA seeded from the first retired batch, so the scheduler's
         # notion of "how long a trip costs" tracks the device it has
         dt = self._clock() - rec.t_dispatch
-        self._h_dispatch.observe(dt)
         self.dispatch_cost_ewma = (
             dt if self.dispatch_cost_ewma == 0.0
             else (1.0 - self._COST_ALPHA) * self.dispatch_cost_ewma
             + self._COST_ALPHA * dt)
         if self.tracer is not None:
-            self.tracer.on_device_done(rec.miss_idx)
+            self.tracer.on_result_ready(rec.miss_idx)
         # model-quality prediction tap: per-model egress-code distribution
         # over the batch's real rows (int32 output codes, pre-encode)
         drift = self.obs.drift
@@ -1779,36 +1799,39 @@ class IngressPipeline:
             drift.observe_predictions(
                 self._stg_mid[rec.buf_idx][: rec.count],
                 out[: rec.count, : self.out_feats])
-        # the one egress encode of the serving path (host twin of the
-        # device deparser, byte-identical): int32 output codes → wire rows
-        rows = emit_results_np(self._stg_mid[rec.buf_idx][: rec.count],
-                               self._stg_flags[rec.buf_idx][: rec.count],
-                               out[: rec.count, : self.out_feats],
-                               self.engine.frac)
-        plan = self.fault_plan
-        if plan is not None:
-            rows = plan.corrupt_egress(rows, self.shard_id)
-        # egress verification (the wire CRC stand-in): every emitted row
-        # must echo the Model ID it was staged with — emit_results_np
-        # writes the id itself, so a mismatch means the row bytes were
-        # damaged after encode and must not reach the caller or the cache
-        echo = (rows[:, 0].astype(np.int32) << 8) | rows[:, 1]
-        bad = echo != self._stg_mid[rec.buf_idx][: rec.count]
-        idx = rec.miss_idx
-        hi = int(idx.max()) + 1 if idx.size else 0
-        self._miss_out.ensure(hi)
-        self._miss_out.a[idx] = rows
-        self._miss_out.n = max(self._miss_out.n, hi)
-        self._ensure_retired(self._n_miss)
-        self._miss_retired[idx] = True
-        if bad.any():
-            self._miss_failed[idx[bad]] = 2
-            self.stats["ingress_corrupted_rows_total"] += int(bad.sum())
-        # family batches retire out of global-index order; chunks resolve
-        # against the fully-retired prefix
-        rem = self._miss_retired[self._miss_done: self._n_miss]
-        self._miss_done = (self._n_miss if rem.all()
-                           else self._miss_done + int(np.argmin(rem)))
+        with self.span("egress.encode"):
+            # the one egress encode of the serving path (host twin of the
+            # device deparser, byte-identical): int32 output codes → wire
+            # rows
+            rows = emit_results_np(self._stg_mid[rec.buf_idx][: rec.count],
+                                   self._stg_flags[rec.buf_idx][: rec.count],
+                                   out[: rec.count, : self.out_feats],
+                                   self.engine.frac)
+            plan = self.fault_plan
+            if plan is not None:
+                rows = plan.corrupt_egress(rows, self.shard_id)
+            # egress verification (the wire CRC stand-in): every emitted
+            # row must echo the Model ID it was staged with —
+            # emit_results_np writes the id itself, so a mismatch means the
+            # row bytes were damaged after encode and must not reach the
+            # caller or the cache
+            echo = (rows[:, 0].astype(np.int32) << 8) | rows[:, 1]
+            bad = echo != self._stg_mid[rec.buf_idx][: rec.count]
+            idx = rec.miss_idx
+            hi = int(idx.max()) + 1 if idx.size else 0
+            self._miss_out.ensure(hi)
+            self._miss_out.a[idx] = rows
+            self._miss_out.n = max(self._miss_out.n, hi)
+            self._ensure_retired(self._n_miss)
+            self._miss_retired[idx] = True
+            if bad.any():
+                self._miss_failed[idx[bad]] = 2
+                self.stats["ingress_corrupted_rows_total"] += int(bad.sum())
+            # family batches retire out of global-index order; chunks
+            # resolve against the fully-retired prefix
+            rem = self._miss_retired[self._miss_done: self._n_miss]
+            self._miss_done = (self._n_miss if rem.all()
+                               else self._miss_done + int(np.argmin(rem)))
         if self.cache is not None and rec.generation is not None \
                 and not bad.any():
             # gate open: admit the whole batch; gate closed: admit a stride
@@ -1821,8 +1844,9 @@ class IngressPipeline:
             words = self._staging_words[rec.buf_idx][sl]
             hashes = self._staging_hashes[rec.buf_idx][sl]
             mids = self._stg_mid[rec.buf_idx][sl].astype(np.int64)
-            self.cache.insert(words, rows[sl], mids, rec.generation, hashes,
-                              assume_unique=True)
+            with self.span("egress.cache_insert"):
+                self.cache.insert(words, rows[sl], mids, rec.generation,
+                                  hashes, assume_unique=True)
         self._free_bufs.append(rec.buf_idx)
         self._resolve_ready_chunks()
 
@@ -1836,24 +1860,29 @@ class IngressPipeline:
         (chunks attaching only to already-retired rows resolve straight from
         submit — no further device traffic involved).  Miss rows that
         retired as failures resolve their tickets to PacketError slots."""
-        while self._chunks and self._chunks[0].hi <= self._miss_done:
-            ch = self._chunks.popleft()
-            if self.tracer is not None:
-                self.tracer.on_retire(ch.tickets)
-            fail = self._miss_failed[ch.miss_idx]
-            if fail.any():
-                bad = fail > 0
-                codes = fail[bad]
-                self._mark_errors(
-                    ch.tickets[bad],
-                    [self._FAIL_REASONS[int(c)] for c in codes])
-                good = ~bad
-                self._results.a[ch.tickets[good]] = \
-                    self._miss_out.a[ch.miss_idx[good]]
-                self._status[ch.tickets[good]] = STATUS_READY
-            else:
-                self._results.a[ch.tickets] = self._miss_out.a[ch.miss_idx]
-                self._status[ch.tickets] = STATUS_READY
+        if not (self._chunks and self._chunks[0].hi <= self._miss_done):
+            return
+        with self.span("egress.resolve"):
+            while self._chunks and self._chunks[0].hi <= self._miss_done:
+                self._resolve_chunk(self._chunks.popleft())
+
+    def _resolve_chunk(self, ch: _ChunkRecord) -> None:
+        if self.tracer is not None:
+            self.tracer.on_retire(ch.tickets)
+        fail = self._miss_failed[ch.miss_idx]
+        if fail.any():
+            bad = fail > 0
+            codes = fail[bad]
+            self._mark_errors(
+                ch.tickets[bad],
+                [self._FAIL_REASONS[int(c)] for c in codes])
+            good = ~bad
+            self._results.a[ch.tickets[good]] = \
+                self._miss_out.a[ch.miss_idx[good]]
+            self._status[ch.tickets[good]] = STATUS_READY
+        else:
+            self._results.a[ch.tickets] = self._miss_out.a[ch.miss_idx]
+            self._status[ch.tickets] = STATUS_READY
 
     def flush(self, timeout_us: Optional[float] = None) -> None:
         """Dispatch the partial staging batch (padded to the fixed shape) and
@@ -1918,13 +1947,14 @@ class IngressPipeline:
         ``PacketError(DRAIN_TIMEOUT)`` slots in their submission
         positions."""
         self.flush(timeout_us)
-        status, rows = self.results_array()
-        if not self._errors:  # common case: one vectorized unpack
-            out: List[Union[np.ndarray, PacketError]] = list(rows)
-        else:
-            out = [self._errors[t] if status[t] == STATUS_ERROR else rows[t]
-                   for t in range(self._n_tickets)]
-        self.reset_tickets()
+        with self.span("egress.resolve"):
+            status, rows = self.results_array()
+            if not self._errors:  # common case: one vectorized unpack
+                out: List[Union[np.ndarray, PacketError]] = list(rows)
+            else:
+                out = [self._errors[t] if status[t] == STATUS_ERROR
+                       else rows[t] for t in range(self._n_tickets)]
+            self.reset_tickets()
         return out
 
     def reset_tickets(self) -> None:

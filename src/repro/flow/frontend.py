@@ -118,6 +118,9 @@ class FlowFrontend:
         self.key_words = (RAW_KEY_BYTES + 7) // 8
         self.table = FlowTable(self.key_words, capacity_pow2=capacity_pow2,
                                idle_timeout=idle_timeout)
+        # layer spans under the pipeline's shard label
+        self.span = pipeline.span
+        self.table.span = self.span
         self.cms = np.zeros(
             (self.params.cms_depth, 1 << self.params.cms_width_pow2),
             np.int32)
@@ -158,17 +161,33 @@ class FlowFrontend:
         sketch becomes scratch and the global per-packet estimates ride in
         through this override.
         """
-        if fields is None:
-            fields = parse_raw_headers(raw)
-        n = fields.model_id.shape[0]
-        if n == 0:
-            return (np.zeros((0, N_FLOW_FEATURES), np.int32), fields,
-                    np.zeros(0, bool), np.zeros(0, bool))
+        span = self.span
+        with span("flow.parse"):
+            if fields is None:
+                fields = parse_raw_headers(raw)
+            n = fields.model_id.shape[0]
+            if n == 0:
+                return (np.zeros((0, N_FLOW_FEATURES), np.int32), fields,
+                        np.zeros(0, bool), np.zeros(0, bool))
+            words, hashes = FlowTable.pack_keys(fields.key_bytes,
+                                                self.key_words)
         self.stats["flow_raw_packets_total"] += n
         self.stats["flow_raw_batches_total"] += 1
-        words, hashes = FlowTable.pack_keys(fields.key_bytes, self.key_words)
-        slots, is_new, rank = self.table.lookup_or_insert(
-            words, hashes, fields.ts, want_rank=True)
+        with span("flow.lookup"):
+            slots, is_new, rank = self.table.lookup_or_insert(
+                words, hashes, fields.ts, want_rank=True)
+        with span("flow.update"):
+            feats = self._update(fields, hashes, slots, rank)
+        if cms_est_q is not None:
+            feats[:, N_FLOW_FEATURES - 1] = cms_est_q
+        return feats, fields, is_new, slots < 0
+
+    def _update(self, fields: RawHeaderBatch, hashes: np.ndarray,
+                slots: np.ndarray, rank: Optional[np.ndarray]) -> np.ndarray:
+        """Sketch cells, then the register and sketch update in place;
+        returns the post-update feature codes (zero rows where the table
+        rejected the flow)."""
+        n = slots.shape[0]
         rejected = slots < 0
         cells = self.params.cms_cells(hashes)
         p = self.params
@@ -196,9 +215,7 @@ class FlowFrontend:
                 fields.length, self._ones[:n], frac=p.frac,
                 ewma_shift=p.ewma_shift, byte_shift=p.byte_shift,
                 dur_shift=p.dur_shift, copy=False, rank=rank)[2]
-        if cms_est_q is not None:
-            feats[:, N_FLOW_FEATURES - 1] = cms_est_q
-        return feats, fields, is_new, rejected
+        return feats
 
     # -- serving -------------------------------------------------------------
 
@@ -206,13 +223,14 @@ class FlowFrontend:
         """Per-model FeatureSpec gather: land each packet's flow-feature
         lanes on its model's input columns (one int32 gather — ``-1``
         columns read the appended zero lane)."""
-        n = feats.shape[0]
-        cols, _ = self.cp.feature_spec_rows(model_id, self.width)
-        feats_z = np.concatenate(
-            [feats, np.zeros((n, 1), np.int32)], axis=1)
-        if self._arange.shape[0] < n:
-            self._arange = np.arange(n).reshape(n, 1)
-        return np.ascontiguousarray(feats_z[self._arange[:n], cols])
+        with self.span("flow.gather"):
+            n = feats.shape[0]
+            cols, _ = self.cp.feature_spec_rows(model_id, self.width)
+            feats_z = np.concatenate(
+                [feats, np.zeros((n, 1), np.int32)], axis=1)
+            if self._arange.shape[0] < n:
+                self._arange = np.arange(n).reshape(n, 1)
+            return np.ascontiguousarray(feats_z[self._arange[:n], cols])
 
     def submit_raw(self, raw, *, fields: Optional[RawHeaderBatch] = None,
                    cms_est_q: Optional[np.ndarray] = None,

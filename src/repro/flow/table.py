@@ -32,6 +32,7 @@ import numpy as np
 
 from ..core.ingress import _dedup_rows, hash_words, pack_rows
 from ..kernels.ref import N_FLOW_REGISTERS, REG_LAST_TS, REG_PKT_COUNT
+from ..obs import no_span
 
 __all__ = ["FlowTable"]
 
@@ -94,6 +95,9 @@ class FlowTable:
                           "flow_adopted_total"):
             stats.bind(canonical, Counter())
         self.stats = stats
+        # layer span of maintenance (expiry, compaction, eviction): the
+        # owning flow frontend binds its pipeline's
+        self.span = no_span
 
     # -- introspection -----------------------------------------------------
 
@@ -145,13 +149,14 @@ class FlowTable:
         """Wholesale eviction — the register-file reset.  Every live flow's
         state is discarded (counted as evictions); the next packet of any
         flow starts it fresh."""
-        self.stats["flow_evictions_total"] += self._count
-        self.stats["flow_flushes_total"] += 1
-        self._slot_state[:] = 0
-        self.registers[:] = 0
-        self._count = 0
-        self._tombstones = 0
-        self.generation += 1
+        with self.span("flow.compact"):
+            self.stats["flow_evictions_total"] += self._count
+            self.stats["flow_flushes_total"] += 1
+            self._slot_state[:] = 0
+            self.registers[:] = 0
+            self._count = 0
+            self._tombstones = 0
+            self.generation += 1
 
     def _insert_new(self, words: np.ndarray, hashes: np.ndarray,
                     regs: Optional[np.ndarray] = None) -> np.ndarray:
@@ -199,17 +204,18 @@ class FlowTable:
     def _compact(self) -> None:
         """Rebuild in place: live flows re-hash onto tombstone-free chains,
         registers move with their keys."""
-        live = np.nonzero(self._slot_state == 1)[0]
-        keys = self._keys[live].copy()
-        regs = self.registers[live].copy()
-        self._slot_state[:] = 0
-        self.registers[:] = 0
-        self._count = 0
-        self._tombstones = 0
-        self.stats["flow_compactions_total"] += 1
-        self.generation += 1
-        if keys.shape[0]:
-            self._insert_new(keys, hash_words(keys), regs)
+        with self.span("flow.compact"):
+            live = np.nonzero(self._slot_state == 1)[0]
+            keys = self._keys[live].copy()
+            regs = self.registers[live].copy()
+            self._slot_state[:] = 0
+            self.registers[:] = 0
+            self._count = 0
+            self._tombstones = 0
+            self.stats["flow_compactions_total"] += 1
+            self.generation += 1
+            if keys.shape[0]:
+                self._insert_new(keys, hash_words(keys), regs)
 
     def expire(self, now: int) -> int:
         """Tombstone every flow idle for more than ``idle_timeout`` ticks
@@ -217,19 +223,20 @@ class FlowTable:
         number expired; no-op without a timeout."""
         if self.idle_timeout is None:
             return 0
-        idle = ((self._slot_state == 1)
-                & (self.registers[:, REG_LAST_TS]
-                   < np.int64(now) - self.idle_timeout))
-        n = int(idle.sum())
-        if n:
-            self._slot_state[idle] = 2
-            self.registers[idle] = 0
-            self._count -= n
-            self._tombstones += n
-            self.stats["flow_expiries_total"] += n
-            if self._tombstones > self._cap * self._tombstone_limit:
-                self._compact()
-        return n
+        with self.span("flow.compact"):
+            idle = ((self._slot_state == 1)
+                    & (self.registers[:, REG_LAST_TS]
+                       < np.int64(now) - self.idle_timeout))
+            n = int(idle.sum())
+            if n:
+                self._slot_state[idle] = 2
+                self.registers[idle] = 0
+                self._count -= n
+                self._tombstones += n
+                self.stats["flow_expiries_total"] += n
+                if self._tombstones > self._cap * self._tombstone_limit:
+                    self._compact()
+            return n
 
     # -- the one public resolution op --------------------------------------
 

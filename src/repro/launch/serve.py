@@ -148,7 +148,6 @@ class PacketServer:
         self.max_inflight = max_inflight
         self.strict_model_ids = strict_model_ids
         self._inflight: deque = deque()
-        self._window_t0: Optional[float] = None
         # flow engine (stage 0): created on first submit_raw() so pure
         # feature-vector deployments never allocate the register file
         self._flow_capacity_pow2 = flow_capacity_pow2
@@ -183,14 +182,7 @@ class PacketServer:
         self.ingress.on_model_removed(model_id)
 
     def process(self, packets):
-        """Synchronous single-batch path (blocks until egress is ready).
-
-        Closes any open async window first — a blocking call inside the
-        window would otherwise credit its wall-clock to the engine twice
-        (once here, once when ``drain()`` credits the whole window).
-        """
-        if self._window_t0 is not None:
-            self.drain()
+        """Synchronous single-batch path (blocks until egress is ready)."""
         return self.engine.process(packets)
 
     # -- raw-packet ingress (stateful flow engine, stage 0) ----------------
@@ -258,12 +250,12 @@ class PacketServer:
         submission-order positions (:func:`repro.data.packets.
         validate_raw_rows`); the well-formed rows in the same batch serve
         normally."""
-        if self._window_t0 is None:
-            self._window_t0 = time.perf_counter()
         from ..data.packets import validate_raw_rows
-        known = (self.control_plane.installed_ids()
-                 if self.strict_model_ids else None)
-        rows, bad, reasons = validate_raw_rows(raw, known_model_ids=known)
+        with self.obs.span("flow.parse", self.ingress.shard_id):
+            known = (self.control_plane.installed_ids()
+                     if self.strict_model_ids else None)
+            rows, bad, reasons = validate_raw_rows(raw,
+                                                   known_model_ids=known)
         t0 = time.perf_counter() if self._submit_h is not None else 0.0
         try:
             if bad is None:
@@ -280,8 +272,6 @@ class PacketServer:
         """Feed one ragged per-connection chunk into the ingress pipeline.
         Returns ``(first_ticket, n_packets)``; results arrive in submission
         order via :meth:`drain_packets`."""
-        if self._window_t0 is None:
-            self._window_t0 = time.perf_counter()
         if self._submit_h is None:
             return self.ingress.submit(packets)
         t0 = time.perf_counter()
@@ -298,17 +288,11 @@ class PacketServer:
         ``PacketError(DRAIN_TIMEOUT)`` instead of blocking on a wedged
         device."""
         out = self.ingress.drain(timeout_us)
-        self._close_window()
         if self.obs.health is not None:
             # step alert rules once per drain window (drift rules also
             # step on the monitor's own window cadence)
             self.obs.health.evaluate()
         return out
-
-    def _close_window(self) -> None:
-        if self._window_t0 is not None:
-            self.engine.add_seconds(time.perf_counter() - self._window_t0)
-            self._window_t0 = None
 
     # -- async serving loop (legacy batch-level API) -----------------------
 
@@ -356,8 +340,6 @@ class PacketServer:
         rejections the oldest slots are pruned, so a caller that never
         drains cannot grow the window without bound.
         """
-        if self._window_t0 is None:
-            self._window_t0 = time.perf_counter()
         try:
             arr = self._validate_batch(packets)
         except (ValueError, TypeError) as e:
@@ -404,24 +386,20 @@ class PacketServer:
                 return
 
     def drain(self) -> List[Union[jax.Array, BatchError]]:
-        """Block until every in-flight batch has retired; credit the whole
-        submit→drain window's wall-clock to the engine's throughput stats.
-        Returns the entries still in flight **in submission order** — device
-        batches interleaved with the :class:`BatchError` slots of rejected
-        batches (every ``submit_async`` call already handed its own
-        future/error to the caller)."""
+        """Block until every in-flight batch has retired.  Returns the
+        entries still in flight **in submission order** — device batches
+        interleaved with the :class:`BatchError` slots of rejected batches
+        (every ``submit_async`` call already handed its own future/error
+        to the caller)."""
         outs = list(self._inflight)
         self._inflight.clear()
         for o in outs:
             if not isinstance(o, BatchError):
                 o.block_until_ready()
-        self._close_window()
         return outs
 
     def stats(self) -> Dict[str, float]:
-        out = {"packets_per_s": self.engine.packets_per_second(),
-               "throughput_gbps": self.engine.throughput_gbps(),
-               "recompiles": self.engine.trace_count,
+        out = {"recompiles": self.engine.trace_count,
                "table_generation": self.control_plane.version,
                "cache_hit_rate": self.ingress.cache_hit_rate(),
                "cache_entries": (len(self.ingress.cache)

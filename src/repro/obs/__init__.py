@@ -1,4 +1,5 @@
-"""Fabric-wide observability: metrics registry + event log + tracer.
+"""Fabric-wide observability: metrics registry + event log + layer spans +
+tracer.
 
 One :class:`Observability` object is created per server
 (``PacketServer`` / ``ShardedPacketServer``) and threaded through every
@@ -7,7 +8,9 @@ registry under per-shard labels, the control plane and fault supervisor
 emit into the shared event log, and (when ``trace_every > 0``) each shard
 pipeline gets its own :class:`~repro.obs.trace.PacketTracer` (tickets and
 staging-row indices are per-pipeline namespaces, so tracers cannot be
-shared across shards).
+shared across shards).  Layer spans (``obs.span(name, shard)``) time the
+serving path's layers into ``<layer>_seconds_total`` counters of the same
+registry, and into the profiler's trace while one runs.
 
 Everything is host-side numpy/Python — instrumentation can never retrace a
 jit program.
@@ -18,6 +21,8 @@ jit program.
     obs.snapshot()             # plain dict: metrics + recent events
     obs.to_prometheus_text()   # exposition format
     obs.spans()                # traced packet lifecycles, all shards
+    with obs.span("flow.lookup", shard=0):  # self time → counter
+        ...
 """
 
 from __future__ import annotations
@@ -29,7 +34,8 @@ from .events import EVENT_KINDS, Event, EventLog
 from .health import AlertRule, HealthMonitor
 from .metrics import (Counter, Gauge, Histogram, MetricsRegistry,
                       StatsAdapter)
-from .trace import TRACE_STAGES, PacketTracer
+from .trace import (LAYER_SPANS, TRACE_STAGES, LayerSpans, PacketTracer,
+                    no_span)
 
 __all__ = [
     "Observability",
@@ -41,8 +47,11 @@ __all__ = [
     "EventLog",
     "Event",
     "EVENT_KINDS",
+    "LAYER_SPANS",
+    "LayerSpans",
     "PacketTracer",
     "TRACE_STAGES",
+    "no_span",
     "DriftMonitor",
     "ShadowScorer",
     "drift_scores",
@@ -59,6 +68,7 @@ class Observability:
         self.clock = clock
         self.trace_every = int(trace_every)
         self.registry = MetricsRegistry()
+        self.layer_spans = LayerSpans(self.registry, clock)
         self.events = EventLog(capacity=event_capacity, clock=clock)
         self.tracers: List[PacketTracer] = []
         # model-quality plane (PR 9): off until enable_drift() — the
@@ -82,6 +92,13 @@ class Observability:
                 categorical_lanes=categorical_lanes, cat_cap=cat_cap,
                 health=self.health)
         return self.drift
+
+    def span(self, name: str, shard: int = 0):
+        """Context manager timing one layer of the serving path: its self
+        time (less nested spans) goes to the counter
+        ``<name, dots as underscores>_seconds_total{shard=…}``, and while a
+        profiler session runs it is a ``repro.<name>`` host annotation."""
+        return self.layer_spans.span(name, shard)
 
     def make_tracer(self, shard: int = 0, clock=None) -> Optional[PacketTracer]:
         """Per-pipeline tracer (or ``None`` when tracing is off)."""

@@ -1,5 +1,22 @@
-"""Sampled packet-lifecycle tracer: submit → stage → dispatch → device-done
-→ retire spans on the monotonic clock.
+"""Host-side tracing: layer spans on the serving path, and the sampled
+packet-lifecycle tracer.
+
+**Layer spans** (:class:`LayerSpans`, reached as
+``Observability.span(name, shard)``) time the serving path's layers —
+flow parse, lookup and update, ingest, staging, dispatch, the wait on the
+device, egress — on every call:
+
+* each span adds its **self time** (its duration minus the spans nested
+  inside it) to the registry counter named after it: ``flow.lookup`` →
+  ``flow_lookup_seconds_total{shard=…}``.  Sibling counters therefore add
+  up without double counting.  Two clock reads and one add per span;
+* while a profiler session runs (``jax.profiler.start_trace``), each span
+  is also a ``repro.<name>`` ``TraceAnnotation``: a host event in the
+  same ``.xplane.pb`` as the device's ops, on the profiler's clock.
+
+**Packet-lifecycle tracer** (:class:`PacketTracer`): submit → stage →
+dispatch → result-ready → retire stamps of sampled packets on the
+monotonic clock.
 
 Sampling is **deterministic 1-in-N by ticket id** (``ticket % every == 0``),
 so two runs over the same traffic trace the same packets — the property
@@ -12,10 +29,17 @@ same host event, so they share a timestamp).
 A closed span decomposes end-to-end latency into the four segments the SLO
 scheduler needs:
 
-    queue_s    submit → stage      (waiting to enter an open batch)
-    batch_s    stage → dispatch    (waiting for the batch to close)
-    device_s   dispatch → device_done   (device compute + transfer)
-    drain_s    device_done → retire     (egress decode + result hand-off)
+    queue_s     submit → stage      (waiting to enter an open batch)
+    batch_s     stage → dispatch    (waiting for the batch to close)
+    inflight_s  dispatch → result_ready  (in flight until the host retires
+                                          the batch: device compute and
+                                          transfer, plus however long the
+                                          host took to come back for it)
+    drain_s     result_ready → retire    (egress encode + result hand-off)
+
+``result_ready`` is stamped when the host's read of the batch's output
+returns at retire, not when the device finished: ``inflight_s`` is an
+upper bound on device time, never a measurement of it.
 
 Cache-hit / coalesced packets short-circuit the device: their spans carry
 only submit/retire and are flagged ``short_circuit``.
@@ -26,17 +50,112 @@ same fake clock as the pipeline's to make spans deterministic in tests.
 
 from __future__ import annotations
 
+import contextlib
+import threading
 import time
 from collections import deque
 from typing import Dict, List, Optional
 
 import numpy as np
 
-__all__ = ["PacketTracer", "TRACE_STAGES"]
+__all__ = ["LAYER_SPANS", "LayerSpans", "PacketTracer", "TRACE_STAGES",
+           "no_span"]
 
-TRACE_STAGES = ("submit", "stage", "dispatch", "device_done", "retire")
+# The serving path's layer spans, in path order (README "Observability"
+# says where each one sits).  A pipeline registers every counter up front,
+# so an operator reads a layer that never ran as 0, not as missing.
+LAYER_SPANS = (
+    "flow.parse", "flow.lookup", "flow.compact", "flow.update",
+    "flow.gather", "ingress.ingest", "ingress.parse", "ingress.stage",
+    "ingress.dispatch", "engine.compile", "ingress.device_wait",
+    "egress.encode", "egress.cache_insert", "cache.compact",
+    "egress.resolve")
 
-_SUBMIT, _STAGE, _DISPATCH, _DEVICE, _RETIRE = range(5)
+TRACE_STAGES = ("submit", "stage", "dispatch", "result_ready", "retire")
+
+_SUBMIT, _STAGE, _DISPATCH, _READY, _RETIRE = range(5)
+
+_NULL_SPAN = contextlib.nullcontext()
+
+
+def no_span(name: str, shard: int = 0):
+    """The span of a component no server owns (a standalone table, cache
+    or engine): times nothing."""
+    return _NULL_SPAN
+
+
+class _Span:
+    """One open layer span (see :class:`LayerSpans`)."""
+
+    __slots__ = ("_owner", "_name", "_cell", "_t0", "_child", "_ann")
+
+    def __init__(self, owner: "LayerSpans", name: str, cell) -> None:
+        self._owner = owner
+        self._name = name
+        self._cell = cell
+
+    def __enter__(self) -> "_Span":
+        o = self._owner
+        local = o._local
+        stack = getattr(local, "stack", None)
+        if stack is None:
+            stack = local.stack = []
+        if o._annotation.is_enabled():
+            self._ann = o._annotation("repro." + self._name)
+            self._ann.__enter__()
+        else:
+            self._ann = None
+        self._child = 0.0
+        stack.append(self)
+        self._t0 = o._clock()
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        o = self._owner
+        dur = o._clock() - self._t0
+        self._cell.value += dur - self._child
+        stack = o._local.stack
+        stack.pop()
+        if stack:
+            stack[-1]._child += dur
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+        return False
+
+
+class LayerSpans:
+    """Layer spans over one registry: ``span(name, shard)`` is a context
+    manager that adds its self time to ``<name with . → _>_seconds_total``
+    under ``shard=`` and, while a profiler session runs, annotates the
+    profiler's host timeline as ``repro.<name>``.  Nesting is tracked per
+    thread, so a span's self time excludes exactly the spans its own
+    thread opened inside it."""
+
+    def __init__(self, registry, clock=None) -> None:
+        from jax.profiler import TraceAnnotation
+        self._registry = registry
+        self._clock = clock if clock is not None else time.perf_counter
+        self._annotation = TraceAnnotation
+        self._local = threading.local()
+        self._cells: Dict[tuple, object] = {}
+
+    def _cell(self, name: str, shard: int):
+        cell = self._cells.get((name, shard))
+        if cell is None:
+            cell = self._registry.counter(
+                name.replace(".", "_") + "_seconds_total",
+                f"host seconds in the {name} span, less nested spans",
+                shard=shard)
+            self._cells[(name, shard)] = cell
+        return cell
+
+    def register(self, shard: int, names=LAYER_SPANS) -> None:
+        """Create the counters of ``names`` under ``shard`` at 0."""
+        for name in names:
+            self._cell(name, shard)
+
+    def span(self, name: str, shard: int = 0) -> _Span:
+        return _Span(self, name, self._cell(name, shard))
 
 
 class PacketTracer:
@@ -57,7 +176,7 @@ class PacketTracer:
         # retire), the run demotes to per-ticket _open entries.
         self._runs: Dict[tuple, float] = {}
         # ticket -> t_submit (float) until staged, then
-        # [t_submit, t_stage, t_dispatch, t_device, t_retire]
+        # [t_submit, t_stage, t_dispatch, t_ready, t_retire]
         self._open: Dict[int, object] = {}
         # miss row index -> traced ticket riding that device row
         self._miss: Dict[int, int] = {}
@@ -160,10 +279,10 @@ class PacketTracer:
     def on_dispatch(self, miss_idx: np.ndarray) -> None:
         self._stamp_miss(miss_idx, _DISPATCH)
 
-    def on_device_done(self, miss_idx: np.ndarray) -> None:
-        # device_done is the last per-row hook; pop the row mapping so a
+    def on_result_ready(self, miss_idx: np.ndarray) -> None:
+        # result_ready is the last per-row hook; pop the row mapping so a
         # reused staging row index can never stamp a stale span.
-        self._stamp_miss(miss_idx, _DEVICE, pop=True)
+        self._stamp_miss(miss_idx, _READY, pop=True)
 
     def on_retire(self, tickets: np.ndarray) -> None:
         hit = self._sampled(tickets)
@@ -210,7 +329,7 @@ class PacketTracer:
             return {"ticket": int(ticket), "shard": shard,
                     "submit": sub, "retire": ret,
                     "total_s": ret - sub, "short_circuit": True}
-        sub, stage, disp, dev, ret = span
+        sub, stage, disp, ready, ret = span
         rec = {"ticket": int(ticket), "shard": shard,
                "submit": sub, "retire": ret,
                "total_s": ret - sub,
@@ -221,10 +340,10 @@ class PacketTracer:
             if disp is not None:
                 rec["dispatch"] = disp
                 rec["batch_s"] = disp - stage
-                if dev is not None:
-                    rec["device_done"] = dev
-                    rec["device_s"] = dev - disp
-                    rec["drain_s"] = ret - dev
+                if ready is not None:
+                    rec["result_ready"] = ready
+                    rec["inflight_s"] = ready - disp
+                    rec["drain_s"] = ret - ready
         return rec
 
     # -- reads -----------------------------------------------------------

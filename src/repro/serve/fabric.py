@@ -268,7 +268,6 @@ class ShardedPacketServer:
         self._order: deque = deque()   # _Submit records, submission order
         self._n_slots = 0              # global tickets this drain window
         self._rr = 0                   # round-robin cursor (stateless path)
-        self._window_t0: Optional[float] = None
         # -- supervision state --------------------------------------------
         self.watchdog_timeout = watchdog_timeout
         self.max_consecutive_failures = max_consecutive_failures
@@ -489,8 +488,6 @@ class ShardedPacketServer:
         scatter each packet to its flow's home shard (relative order
         preserved).  Returns global ``(first_ticket, n_packets)``."""
         with self._lock:
-            if self._window_t0 is None:
-                self._window_t0 = time.perf_counter()
             raw_arr, bad, reasons = validate_raw_rows(raw)
             n = raw_arr.shape[0]
             first = self._n_slots
@@ -561,8 +558,6 @@ class ShardedPacketServer:
         round-robin across shards.  Returns global ``(first_ticket,
         n_packets)``."""
         with self._lock:
-            if self._window_t0 is None:
-                self._window_t0 = time.perf_counter()
             arr = np.asarray(packets)
             n = arr.shape[0] if arr.ndim == 2 else 0
             for _ in range(self.n_shards):  # next *alive* shard
@@ -640,29 +635,16 @@ class ShardedPacketServer:
             self._window_degraded = False
             self._order.clear()
             self._n_slots = 0
-            self._close_window()
             if self.obs.health is not None:
                 # step alert rules once per drain window (drift rules also
                 # step on the monitor's own window cadence)
                 self.obs.health.evaluate()
             return out
 
-    def _close_window(self) -> None:
-        if self._window_t0 is not None:
-            dt = time.perf_counter() - self._window_t0
-            # every shard shares the window's wall-clock, so the aggregate
-            # rate (sum of per-shard rates) is total packets / wall time —
-            # the honest number for a host that serializes shard work
-            for sh in self.shards:
-                sh.engine.add_seconds(dt)
-            self._window_t0 = None
-
     def process(self, packets):
         """Synchronous single-batch path (first alive shard — API parity
         with the single-engine server; no flow state involved)."""
         with self._lock:
-            if self._window_t0 is not None:
-                self.drain_packets()
             return self.shards[self.alive_shards[0]].engine.process(packets)
 
     # -- observability -----------------------------------------------------
@@ -678,8 +660,6 @@ class ShardedPacketServer:
         for sh in self.shards:
             d = {"shard": sh.shard_id,
                  "alive": bool(self._alive[sh.shard_id]),
-                 "packets_per_s": sh.engine.packets_per_second(),
-                 "throughput_gbps": sh.engine.throughput_gbps(),
                  "recompiles": sh.engine.trace_count,
                  "cache_hit_rate": sh.pipeline.cache_hit_rate(),
                  "packets": sh.pipeline.stats["ingress_packets_total"]}
@@ -688,9 +668,6 @@ class ShardedPacketServer:
             per_shard.append(d)
         return {
             "n_shards": self.n_shards,
-            "packets_per_s": sum(d["packets_per_s"] for d in per_shard),
-            "throughput_gbps": sum(d["throughput_gbps"]
-                                   for d in per_shard),
             "recompiles": sum(d["recompiles"] for d in per_shard),
             "table_generation": self.control_plane.version,
             "flows": sum(d.get("flows", 0) for d in per_shard),

@@ -1,12 +1,16 @@
 """Observability-layer tests (PR 8: metrics registry, latency histograms,
-packet-lifecycle tracing, structured event log).
+packet-lifecycle tracing, structured event log; layer spans).
 
   * histogram percentile readout is within one log-bucket ratio of
     ``np.percentile(..., method="inverted_cdf")`` on arbitrary positive
     samples (hypothesis), and exact on degenerate/overflow inputs
   * packet-lifecycle tracing samples deterministically (1-in-N by ticket
-    id), decomposes end-to-end latency into queue/batch/device/drain, and
-    never causes a retrace
+    id), decomposes end-to-end latency into queue/batch/inflight/drain,
+    and never causes a retrace
+  * layer spans add self time (less nested spans) to per-shard
+    ``<layer>_seconds_total`` counters, time exactly the layers a surface
+    runs (raw: the flow engine; wire: the wire parse), and annotate the
+    profiler's trace only while a profiler session runs
   * the event log is ordered, bounded, and reconstructs the full
     kill-1-of-4 failover drill post-hoc: installs → watchdog strikes →
     fault firings → shard kill → flow migrations, in sequence order
@@ -33,8 +37,8 @@ from repro.core import packet as pk
 from repro.core.ingress import PacketError
 from repro.data.packets import raw_trace
 from repro.launch.serve import PacketServer
-from repro.obs import (EventLog, Histogram, MetricsRegistry, Observability,
-                       PacketTracer, StatsAdapter)
+from repro.obs import (LAYER_SPANS, EventLog, Histogram, MetricsRegistry,
+                       Observability, PacketTracer, StatsAdapter)
 from repro.serve import FaultPlan, FaultSpec, ShardedPacketServer
 
 FRAC = 8
@@ -162,7 +166,7 @@ class TestTracer:
             assert s["total_s"] >= 0.0
             assert s["total_s"] == pytest.approx(s["retire"] - s["submit"])
             if not s["short_circuit"]:
-                parts = (s["queue_s"] + s["batch_s"] + s["device_s"]
+                parts = (s["queue_s"] + s["batch_s"] + s["inflight_s"]
                          + s["drain_s"])
                 assert parts == pytest.approx(s["total_s"], abs=1e-9)
         assert all(t.open_spans == 0 for t in srv.obs.tracers)
@@ -178,13 +182,157 @@ class TestTracer:
         tr.on_submit(np.arange(4))
         tr.on_stage(np.asarray([0, 2]), np.asarray([0, 1]))
         tr.on_dispatch(np.asarray([0, 1]))
-        tr.on_device_done(np.asarray([0, 1]))
+        tr.on_result_ready(np.asarray([0, 1]))
         tr.on_retire(np.arange(4))
         spans = tr.spans()
         assert [s["ticket"] for s in spans] == [0, 2]
         assert all(s["queue_s"] == 1.0 and s["batch_s"] == 1.0
-                   and s["device_s"] == 1.0 and s["drain_s"] == 1.0
-                   for s in spans)
+                   and s["inflight_s"] == 1.0 and s["drain_s"] == 1.0
+                   and s["result_ready"] == 3.0 for s in spans)
+        assert "device_s" not in spans[0]
+
+
+class _Ticks:
+    """Fake clock: each read returns the current time."""
+
+    def __init__(self):
+        self.t = 0.0
+
+    def __call__(self):
+        return self.t
+
+
+def _seconds(obs, name, shard=0):
+    snap = obs.registry.snapshot()
+    return snap[name.replace(".", "_") + "_seconds_total"][f'shard="{shard}"']
+
+
+class TestLayerSpans:
+    def test_nested_spans_count_self_time(self):
+        clk = _Ticks()
+        obs = Observability(clock=clk)
+        with obs.span("ingress.ingest"):
+            clk.t += 1.0
+            with obs.span("ingress.stage"):
+                clk.t += 2.0
+                with obs.span("ingress.dispatch"):
+                    clk.t += 4.0
+                clk.t += 8.0
+            clk.t += 16.0
+            with obs.span("ingress.stage"):
+                clk.t += 32.0
+        assert _seconds(obs, "ingress.ingest") == 17.0
+        assert _seconds(obs, "ingress.stage") == 2.0 + 8.0 + 32.0
+        assert _seconds(obs, "ingress.dispatch") == 4.0
+        # siblings add up to the outermost span's duration
+        assert sum(_seconds(obs, n) for n in (
+            "ingress.ingest", "ingress.stage", "ingress.dispatch")) == 63.0
+
+    def test_an_exception_closes_the_span(self):
+        clk = _Ticks()
+        obs = Observability(clock=clk)
+        with pytest.raises(KeyError):
+            with obs.span("egress.resolve"):
+                clk.t += 1.0
+                with obs.span("egress.encode"):
+                    clk.t += 2.0
+                    raise KeyError("x")
+        with obs.span("egress.encode"):
+            clk.t += 4.0
+        assert _seconds(obs, "egress.resolve") == 1.0
+        assert _seconds(obs, "egress.encode") == 6.0
+
+    def test_counter_names_and_shard_labels(self):
+        fab = _fabric(2)
+        text = fab.obs.to_prometheus_text()
+        for name in LAYER_SPANS:
+            counter = name.replace(".", "_") + "_seconds_total"
+            assert f"# TYPE {counter} counter" in text
+            for shard in (0, 1):
+                assert f'{counter}{{shard="{shard}"}} 0' in text
+        assert 'flow_lookup_seconds_total{shard="1"} 0' in text
+        assert 'ingress_device_wait_seconds_total{shard="0"} 0' in text
+        fab.submit_raw(_trace(256, 3))
+        fab.drain_packets()
+        snap = fab.obs.registry.snapshot()["flow_lookup_seconds_total"]
+        assert set(snap) == {'shard="0"', 'shard="1"'}
+        assert all(v > 0 for v in snap.values())
+
+    def test_raw_traffic_times_the_flow_engine(self):
+        # a 64-slot flow table under 200 flows: expiry, compaction and
+        # wholesale eviction all run
+        srv = _plain(flow_capacity_pow2=6, flow_idle_timeout=50)
+        raw = _trace(2048, 5, n_flows=200)
+        for i in range(0, raw.shape[0], 32):
+            srv.submit_raw(raw[i: i + 32])
+        srv.drain_packets()
+        for name in LAYER_SPANS:
+            got = _seconds(srv.obs, name)
+            if name == "ingress.parse":
+                assert got == 0, name
+            elif name.startswith(("flow.", "ingress.", "egress.")):
+                assert got > 0, name
+        assert srv.flow.table.stats["flow_compactions_total"] \
+            + srv.flow.table.stats["flow_flushes_total"] > 0
+
+    def test_wire_traffic_times_the_wire_parse(self):
+        srv = _plain()
+        wire = _dup_wire(4)
+        for i in range(0, len(wire), 64):
+            srv.submit_packets(wire[i: i + 64])
+        srv.drain_packets()
+        for name in LAYER_SPANS:
+            got = _seconds(srv.obs, name)
+            if name.startswith("flow."):
+                assert got == 0, name
+            elif name in ("ingress.parse", "ingress.ingest",
+                          "ingress.stage", "ingress.dispatch",
+                          "ingress.device_wait", "egress.encode",
+                          "egress.resolve"):
+                assert got > 0, name
+
+    def test_annotations_only_while_a_profiler_runs(self, monkeypatch):
+        made = []
+        enabled = [False]
+
+        class FakeAnnotation:
+            def __init__(self, name):
+                made.append(name)
+
+            @staticmethod
+            def is_enabled():
+                return enabled[0]
+
+            def __enter__(self):
+                return self
+
+            def __exit__(self, *exc):
+                return False
+
+        srv = _plain()
+        monkeypatch.setattr(srv.obs.layer_spans, "_annotation",
+                            FakeAnnotation)
+        raw = _trace(256, 6)
+        srv.submit_raw(raw)
+        srv.drain_packets()
+        assert made == []
+        assert _seconds(srv.obs, "flow.lookup") > 0
+        enabled[0] = True
+        srv.submit_raw(raw)
+        srv.drain_packets()
+        assert made and all(n.startswith("repro.") for n in made)
+        assert {"repro.flow.lookup", "repro.ingress.device_wait",
+                "repro.egress.encode"} <= set(made)
+        assert set(made) <= {"repro." + n for n in LAYER_SPANS}
+
+    def test_spans_never_retrace(self):
+        srv = _plain()
+        srv.submit_raw(_trace(256, 7))
+        srv.drain_packets()
+        n = srv.engine.trace_count
+        srv.submit_raw(_trace(256, 8))
+        srv.drain_packets()
+        assert srv.engine.trace_count == n
 
 
 class TestEventLog:
