@@ -178,20 +178,25 @@ def _dedup_rows(words: np.ndarray, hashes: np.ndarray,
     Sorts by the *folded* 32-bit hash (numpy's stable radix sort scales
     with key bytes — 4-byte keys sort ~2× faster than 8-byte ones; the
     mixing hash's low word is uniformly distributed) and verifies the full
-    64-bit hash plus word equality between sort-neighbours, so a hash or
-    fold collision can only ever *miss* a coalescing opportunity, never
-    merge two distinct packets (identical rows share a fold, so they stay
-    adjacent; an interleaving fold collision merely splits their group).
+    64-bit hash between sort-neighbours, then word equality only where
+    those hashes agree, so a hash or fold collision can only ever *miss* a
+    coalescing opportunity, never merge two distinct packets (identical
+    rows share a fold, so they stay adjacent; an interleaving fold
+    collision merely splits their group).
     Returns ``(uniq_idx, inverse)`` with ``rows[uniq_idx][inverse] ==
     rows``.
     """
     n = words.shape[0]
     order = np.argsort(hashes.astype(np.uint32), kind="stable")
-    sw = words[order]
+    sh = hashes[order]
     new = np.empty(n, bool)
     new[0] = True
-    new[1:] = (hashes[order][1:] != hashes[order][:-1]) \
-        | (sw[1:] != sw[:-1]).any(axis=1)
+    new[1:] = sh[1:] != sh[:-1]
+    # hash-first: only neighbour pairs with equal 64-bit hashes (repeats,
+    # or a true collision) read their key words
+    eq = np.flatnonzero(~new[1:])
+    if eq.size:
+        new[eq + 1] = (words[order[eq]] != words[order[eq + 1]]).any(axis=1)
     group = np.cumsum(new) - 1
     inverse = np.empty(n, np.int64)
     inverse[order] = group
@@ -234,7 +239,11 @@ class ResultCache:
 
     Keys are ingress rows packed into uint64 words (:func:`pack_rows`); all
     operations take the whole packet chunk at once and run as vectorized
-    numpy probe sweeps (double hashing over a power-of-two table).
+    numpy probe sweeps (double hashing over a power-of-two table).  Each
+    slot keeps its key's 64-bit row hash as a tag, and a probe reads the
+    key words only where the tag matches: a cold row costs one tag
+    compare per slot examined, not a key compare (``probes`` counts the
+    slots examined, ``key_verifies`` the keys read).
     """
 
     def __init__(self, key_words: int, val_bytes: int, *,
@@ -253,6 +262,7 @@ class ResultCache:
         self.key_words = key_words
         self.val_bytes = val_bytes
         self._keys = np.zeros((cap, key_words), np.uint64)
+        self._tag = np.zeros(cap, np.uint64)  # the key's row hash
         self._vals = np.zeros((cap, val_bytes), np.uint8)
         self._state = np.zeros(cap, np.uint8)  # 0 empty · 1 full · 2 tombstone
         self._model = np.full(cap, -1, np.int64)
@@ -268,6 +278,8 @@ class ResultCache:
         self.flushes = 0
         self.compactions = 0
         self.stale_inserts_dropped = 0
+        self.probes = 0
+        self.key_verifies = 0
         # layer span of maintenance (flush, compaction): the owning
         # pipeline binds its own
         self.span = no_span
@@ -280,6 +292,20 @@ class ResultCache:
         step = ((((hashes >> np.uint64(32)) << np.uint64(1)) | np.uint64(1))
                 .astype(np.int64)) & self._mask
         return slot, step
+
+    def _match(self, s: np.ndarray, st: np.ndarray, h: np.ndarray,
+               words: np.ndarray, rows: np.ndarray) -> np.ndarray:
+        """Which of the full slots ``s`` (state ``st``) hold the keys
+        ``words[rows]`` (row hashes ``h``): the tag decides, and only a
+        slot whose tag matches reads its key words (a tag match with a
+        foreign key is a 64-bit collision — not a match)."""
+        m = (st == 1) & (self._tag[s] == h)
+        vi = np.flatnonzero(m)
+        self.probes += s.size
+        self.key_verifies += vi.size
+        if vi.size:
+            m[vi] = (self._keys[s[vi]] == words[rows[vi]]).all(axis=1)
+        return m
 
     def _sync_generation(self, generation: int) -> bool:
         """Flush on a newer generation; return False if ``generation`` is
@@ -311,6 +337,7 @@ class ResultCache:
             keys = self._keys[live].copy()
             vals = self._vals[live].copy()
             mids = self._model[live].copy()
+            tags = self._tag[live].copy()
             self._state[:] = 0
             self._count = 0
             self._tombstones = 0
@@ -318,7 +345,7 @@ class ResultCache:
             if keys.shape[0]:
                 # re-admissions are not new insertions
                 ins0 = self.insertions
-                self.insert(keys, vals, mids, self._gen)
+                self.insert(keys, vals, mids, self._gen, tags)
                 self.insertions = ins0
 
     @property
@@ -353,7 +380,7 @@ class ResultCache:
         # fast first round, no indirection: with load < load_limit almost
         # every probe resolves at its home slot
         st = self._state[slot]
-        match = (self._keys[slot] == words).all(axis=1) & (st == 1)
+        match = self._match(slot, st, hashes, words, np.arange(n))
         hit_slot = np.where(match, slot, np.int64(-1))
         # keep probing through tombstones and colliding keys; an empty slot
         # terminates the probe chain → definitive miss
@@ -368,7 +395,7 @@ class ResultCache:
                 s = cur[active]
                 rows = pending[active]
                 st = self._state[s]
-                m = (self._keys[s] == words[rows]).all(axis=1) & (st == 1)
+                m = self._match(s, st, hashes[rows], words, rows)
                 hit_slot[rows[m]] = s[m]
                 keep = ~m & (st != 0)
                 active = active[keep]
@@ -432,7 +459,7 @@ class ResultCache:
             nonlocal admitted
             st = self._state[s]
             full = st == 1
-            match = (self._keys[s] == words[rows]).all(axis=1) & full
+            match = self._match(s, st, hashes[rows], words, rows)
             if match.any():
                 self._vals[s[match]] = vals[rows[match]]
             claim = ~full
@@ -449,6 +476,7 @@ class ResultCache:
                 rw = rows[wi]
                 self._tombstones -= int((st[wi] == 2).sum())  # reclaimed
                 self._keys[ws] = words[rw]
+                self._tag[ws] = hashes[rw]
                 self._vals[ws] = vals[rw]
                 self._model[ws] = model_ids[rw]
                 self._state[ws] = 1
@@ -462,8 +490,9 @@ class ResultCache:
                 li = ci[~win]
                 if li.size:
                     ls = s[li]
-                    lm = (self._keys[ls] == words[rows[li]]).all(axis=1) \
-                        & (self._state[ls] == 1)
+                    lr = rows[li]
+                    lm = self._match(ls, self._state[ls], hashes[lr],
+                                     words, lr)
                     if lm.any():
                         sel = li[lm]
                         self._vals[s[sel]] = vals[rows[sel]]
@@ -875,6 +904,13 @@ class IngressPipeline:
                                                    shard=sid),
             "cache_stale_inserts_total": reg.counter(
                 "cache_stale_inserts_total", shard=sid),
+            "cache_probes_total": reg.counter(
+                "cache_probes_total", "result-cache slots examined",
+                shard=sid),
+            "cache_key_verifies_total": reg.counter(
+                "cache_key_verifies_total",
+                "result-cache slots whose hash tag matched (key read)",
+                shard=sid),
         }
         g_entries = reg.gauge("cache_entries", shard=sid)
         g_tomb = reg.gauge("cache_tombstones", shard=sid)
@@ -902,6 +938,9 @@ class IngressPipeline:
                 cache_cells["cache_compactions_total"].set(cache.compactions)
                 cache_cells["cache_stale_inserts_total"].set(
                     cache.stale_inserts_dropped)
+                cache_cells["cache_probes_total"].set(cache.probes)
+                cache_cells["cache_key_verifies_total"].set(
+                    cache.key_verifies)
                 g_entries.set(len(cache))
                 g_tomb.set(cache.tombstones)
             g_gate.set(1.0 if self._gate_open else 0.0)

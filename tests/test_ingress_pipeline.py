@@ -15,7 +15,8 @@ from repro.core import packet as pk
 from repro.core.control_plane import ControlPlane
 from repro.core.inference import DataPlaneEngine
 from repro.core.ingress import (BatchError, IngressPipeline, PacketError,
-                                ResultCache, hash_words, pack_rows)
+                                ResultCache, _dedup_rows, hash_words,
+                                pack_rows)
 
 FRAC = 8
 WIDTH = 8
@@ -236,6 +237,229 @@ class TestResultCache:
         row_in = (other[:, None, :] == words[None, :, :]).all(-1).any(1)
         hm2, _ = c.lookup(other, 1)
         assert not (hm2 & ~row_in).any()
+
+    # -- hash-tag checks: a slot's key is read only where its tag matches --
+
+    def test_planted_tag_collision_lookup_misses_foreign_key(self):
+        """A row whose hash equals a cached row's but whose key differs is a
+        64-bit collision: the tag matches, the key words do not, so the
+        lookup probes on and misses — it never serves the foreign value."""
+        rng = np.random.default_rng(50)
+        a, vals, mids = self._kv(rng, 200)
+        b, _, _ = self._kv(rng, 200)
+        h = hash_words(a)
+        c = ResultCache(3, 16, capacity_pow2=10)
+        c.insert(a, vals, mids, 1, h)
+        v0 = c.key_verifies
+        hm, _ = c.lookup(b, 1, h)  # b's rows planted on a's tags
+        assert not hm.any()
+        assert c.key_verifies - v0 >= 200  # every b row met a's tag
+        hm, got = c.lookup(a, 1, h)
+        assert hm.all()
+        np.testing.assert_array_equal(got, vals)
+
+    def test_planted_tag_collision_insert_claims_own_slot(self):
+        """Inserting a colliding foreign key must claim a slot of its own
+        and leave the cached key's value untouched (no refresh across
+        keys)."""
+        rng = np.random.default_rng(51)
+        a, va, ma = self._kv(rng, 150)
+        b, vb, mb = self._kv(rng, 150)
+        h = hash_words(a)
+        c = ResultCache(3, 16, capacity_pow2=10)
+        c.insert(a, va, ma, 1, h, assume_unique=True)
+        assert c.insert(b, vb, mb, 1, h, assume_unique=True) == 150
+        assert len(c) == 300
+        hm, got = c.lookup(a, 1, h)
+        assert hm.all()
+        np.testing.assert_array_equal(got, va)
+        hm, got = c.lookup(b, 1, h)
+        assert hm.all()
+        np.testing.assert_array_equal(got, vb)
+
+    def test_planted_tag_collision_arbitration_loser(self):
+        """One call with rows ``a, b, a`` on one hash: all three race one
+        home slot.  The last writer (the second ``a``) wins; the first
+        ``a`` loses to its own key and refreshes in place; ``b`` loses to
+        a foreign key with its tag and must probe on to a slot of its
+        own."""
+        rng = np.random.default_rng(52)
+        w, v, m = self._kv(rng, 2)
+        words = w[[0, 1, 0]]
+        vals = v[[0, 1, 0]]
+        mids = m[[0, 1, 0]]
+        h = np.full(3, hash_words(w[:1])[0], np.uint64)
+        c = ResultCache(3, 16, capacity_pow2=8)
+        assert c.insert(words, vals, mids, 1, h, assume_unique=True) == 2
+        assert len(c) == 2
+        hm, got = c.lookup(w, 1, h[:2])
+        assert hm.all()
+        np.testing.assert_array_equal(got, v)
+
+    def test_tags_hold_after_tombstone_reuse_and_compaction(self):
+        """Tags are written wherever a slot is filled: an insert reclaiming
+        a ``drop_model`` tombstone, and ``_compact``'s re-insert, which
+        re-homes every live entry under its stored tag — so entries cached
+        under caller-supplied hashes stay reachable through them."""
+        rng = np.random.default_rng(53)
+        words, vals, mids = self._kv(rng, 120)
+        # planted hashes: every row shares its tag with three others
+        h = hash_words(words[np.arange(120) // 4])
+        c = ResultCache(3, 16, capacity_pow2=8, tombstone_limit=0.1)
+        c.insert(words, vals, mids, 1, h)
+        c.drop_model(3)
+        assert c.tombstones > 0 and c.compactions == 0
+        c.insert(words, vals, mids, 1, h)  # re-admit: reclaims tombstones
+        hm, got = c.lookup(words, 1, h)
+        assert hm.all()
+        np.testing.assert_array_equal(got, vals)
+        c.drop_model(4)
+        c.drop_model(5)  # cumulative tombstones cross 10% → compact
+        assert c.compactions >= 1 and c.tombstones == 0
+        keep = (mids != 4) & (mids != 5)
+        hm, got = c.lookup(words, 1, h)
+        np.testing.assert_array_equal(hm, keep)
+        np.testing.assert_array_equal(got, vals[keep])
+        live = np.flatnonzero(c._state == 1)
+        want = {words[i].tobytes(): h[i] for i in range(120)}
+        assert [c._tag[s] for s in live] \
+            == [want[c._keys[s].tobytes()] for s in live]
+
+    @settings(max_examples=25, deadline=None)
+    @given(seed=st.integers(min_value=0, max_value=2 ** 16),
+           n_ops=st.integers(min_value=1, max_value=16))
+    def test_property_matches_dict_oracle_under_tag_collisions(self, seed,
+                                                               n_ops):
+        """Random inserts (with and without ``assume_unique``, repeats
+        allowed), model drops and generation bumps, against a dict oracle.
+        The hashes are folded to 3 bits, so nearly every probe meets a
+        foreign key under its own tag.  With a probe budget of the whole
+        table no row is refused admission, so after every step the cache
+        must hold exactly the oracle's keys, each with its own value."""
+        rng = np.random.default_rng(seed)
+        pool, pool_vals, _ = self._kv(rng, 48)
+        pool_mids = np.arange(48, dtype=np.int64) % 4
+        h = hash_words(pool) & np.uint64(7)
+        c = ResultCache(3, 16, capacity_pow2=8, max_probe=1 << 8,
+                        load_limit=1.0, tombstone_limit=0.1)
+        gen = 1
+        oracle = set()
+        for _ in range(n_ops):
+            op = rng.integers(0, 4)
+            if op <= 1:
+                idx = rng.integers(0, 48, int(rng.integers(1, 24)))
+                flushes = c.flushes
+                c.insert(pool[idx], pool_vals[idx] ^ np.uint8(gen),
+                         pool_mids[idx], gen, h[idx],
+                         assume_unique=bool(op))
+                if c.flushes != flushes:
+                    oracle.clear()
+                oracle.update(idx.tolist())
+            elif op == 2:
+                m = int(rng.integers(0, 4))
+                assert c.drop_model(m) >= sum(
+                    1 for i in oracle if pool_mids[i] == m)
+                oracle = {i for i in oracle if pool_mids[i] != m}
+            else:
+                gen += 1
+                oracle.clear()
+            hm, got = c.lookup(pool, gen, h)
+            want = np.isin(np.arange(48), sorted(oracle))
+            np.testing.assert_array_equal(hm, want)
+            np.testing.assert_array_equal(got, pool_vals[want] ^ np.uint8(gen))
+
+    def test_probe_counters(self):
+        """``probes`` counts slots examined, ``key_verifies`` the slots
+        whose tag matched: cold rows read no key, cached rows read exactly
+        their own, colliding rows read the foreign key they collide
+        with."""
+        rng = np.random.default_rng(54)
+        a, vals, mids = self._kv(rng, 300)
+        b, _, _ = self._kv(rng, 300)
+        ha = hash_words(a)
+        c = ResultCache(3, 16, capacity_pow2=10)
+        c.insert(a, vals, mids, 1, ha)
+
+        def delta(fn):
+            p0, v0 = c.probes, c.key_verifies
+            fn()
+            return c.probes - p0, c.key_verifies - v0
+
+        p, v = delta(lambda: c.lookup(b, 1))  # cold
+        assert p >= 300 and v == 0
+        p, v = delta(lambda: c.lookup(a, 1, ha))  # repeats
+        assert p >= 300 and v == 300
+        p, v = delta(lambda: c.lookup(b, 1, ha))  # planted collisions
+        assert p >= 300 and v >= 300
+        assert c.key_verifies <= c.probes
+
+    def test_probe_counters_mirrored_in_registry(self):
+        rng = np.random.default_rng(55)
+        _, _, pipe = _pipeline(batch_size=32)
+        wire = _wire(rng, 96)
+        pipe.submit(wire)
+        pipe.drain()
+        pipe.submit(wire)  # served from the cache: every row reads its key
+        pipe.drain()
+        snap = pipe.obs.registry.snapshot()
+        probes = snap["cache_probes_total"]['shard="0"']
+        verifies = snap["cache_key_verifies_total"]['shard="0"']
+        assert probes == pipe.cache.probes > 0
+        assert verifies == pipe.cache.key_verifies >= 96
+
+
+# ---------------------------------------------------------------------------
+# _dedup_rows
+# ---------------------------------------------------------------------------
+
+
+def _dedup_reference(words, hashes):
+    """Word-by-word reference of ``_dedup_rows``: stable sort on the folded
+    hash, then a new group wherever a row differs from its sort neighbour
+    in hash or in any word; rank counts earlier rows of the same group."""
+    order = np.argsort(hashes.astype(np.uint32), kind="stable")
+    group, uniq, prev = [], [], None
+    for i in order:
+        cur = (int(hashes[i]), tuple(words[i].tolist()))
+        if cur != prev:
+            uniq.append(i)
+        group.append(len(uniq) - 1)
+        prev = cur
+    inverse = np.empty(len(order), np.int64)
+    inverse[order] = group
+    seen = {}
+    rank = np.empty(len(order), np.int64)
+    for i, g in enumerate(inverse):
+        rank[i] = seen.get(g, 0)
+        seen[g] = rank[i] + 1
+    return np.array(uniq, np.int64), inverse, rank
+
+
+class TestDedupRows:
+    @pytest.mark.parametrize("fold_bits", [64, 3, 0])
+    @pytest.mark.parametrize("dup_share", [0.0, 0.5])
+    def test_matches_word_by_word_reference(self, fold_bits, dup_share):
+        """Equal hashes on distinct rows (``fold_bits`` < 64 plants
+        collisions) and real duplicates: the hash-first compare gives the
+        reference's ``uniq_idx``, ``inverse`` and ``rank`` exactly."""
+        rng = np.random.default_rng(60 + fold_bits)
+        n = 400
+        base = pack_rows(rng.integers(0, 256, (n, 21)).astype(np.uint8), 3)
+        src = np.where(rng.random(n) < dup_share,
+                       rng.integers(0, max(1, n // 8), n), np.arange(n))
+        words = base[src]
+        h = hash_words(words)
+        if fold_bits < 64:
+            h &= np.uint64((1 << fold_bits) - 1)
+        uniq, inverse, rank = _dedup_rows(words, h, want_rank=True)
+        r_uniq, r_inverse, r_rank = _dedup_reference(words, h)
+        np.testing.assert_array_equal(uniq, r_uniq)
+        np.testing.assert_array_equal(inverse, r_inverse)
+        np.testing.assert_array_equal(rank, r_rank)
+        np.testing.assert_array_equal(words[uniq][inverse], words)
+        u2, i2 = _dedup_rows(words, h)
+        np.testing.assert_array_equal(u2, uniq)
+        np.testing.assert_array_equal(i2, inverse)
 
 
 # ---------------------------------------------------------------------------
