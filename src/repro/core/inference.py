@@ -53,6 +53,7 @@ from typing import Sequence
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from ..kernels.fused_serve import LaneConfig, serve_lanes
 from ..kernels.forest_traversal import FOREST_VARIANTS
@@ -227,12 +228,16 @@ class DataPlaneEngine:
         return self.cp.forest_snapshots(self.forest_variant == "range",
                                         device=self.device)
 
-    def _place(self, arr: jax.Array) -> jax.Array:
-        """Commit one batch operand to this engine's device (identity when
-        unplaced — the computation then follows the uncommitted default)."""
+    def _place(self, arr, dtype) -> jax.Array:
+        """One batch operand as a ``dtype`` device array, committed to this
+        engine's device; a host array goes straight there, not by way of
+        the default device.  Unplaced, it follows the uncommitted
+        default."""
         if self.device is None:
-            return arr
-        return jax.device_put(arr, self.device)
+            return jnp.asarray(arr, dtype)
+        if isinstance(arr, jax.Array):
+            return jax.device_put(arr.astype(dtype), self.device)
+        return jax.device_put(np.asarray(arr, dtype), self.device)
 
     # -- host API -----------------------------------------------------------
 
@@ -254,7 +259,7 @@ class DataPlaneEngine:
         """
         if lanes not in ("both", "mlp", "forest"):
             raise ValueError(f"unknown lanes hint: {lanes!r}")
-        pkts = self._place(jnp.asarray(pkts, jnp.uint8))
+        pkts = self._place(pkts, jnp.uint8)
         tables = self.cp.tables(device=self.device)  # current generation
         use_mlp, use_forest = self._lane_flags(lanes)
         ftables, rtables = self._forest_snapshots(use_forest)
@@ -281,8 +286,8 @@ class DataPlaneEngine:
         """
         if lanes not in ("both", "mlp", "forest"):
             raise ValueError(f"unknown lanes hint: {lanes!r}")
-        feats_q = self._place(jnp.asarray(feats_q, jnp.int32))
-        model_id = self._place(jnp.asarray(model_id, jnp.int32))
+        feats_q = self._place(feats_q, jnp.int32)
+        model_id = self._place(model_id, jnp.int32)
         tables = self.cp.tables(device=self.device)
         use_mlp, use_forest = self._lane_flags(lanes)
         args = (feats_q, model_id, tables,
@@ -324,9 +329,9 @@ class DataPlaneEngine:
         program = self._programs.get((n_rows, use_mlp, use_forest))
         if program is not None:  # the per-dispatch check stays cheap
             return program
-        feats_q = self._place(jnp.zeros((n_rows, self.max_features),
-                                        jnp.int32))
-        model_id = self._place(jnp.zeros((n_rows,), jnp.int32))
+        feats_q = self._place(np.zeros((n_rows, self.max_features)),
+                              jnp.int32)
+        model_id = self._place(np.zeros(n_rows), jnp.int32)
         return self._program(
             (feats_q, model_id, self.cp.tables(device=self.device),
              *self._forest_snapshots(use_forest)), use_mlp, use_forest)

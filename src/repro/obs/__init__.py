@@ -34,8 +34,8 @@ from .events import EVENT_KINDS, Event, EventLog
 from .health import AlertRule, HealthMonitor
 from .metrics import (Counter, Gauge, Histogram, MetricsRegistry,
                       StatsAdapter)
-from .trace import (LAYER_SPANS, TRACE_STAGES, LayerSpans, PacketTracer,
-                    no_span)
+from .trace import (FABRIC_SPANS, LAYER_SPANS, TRACE_STAGES, LayerSpans,
+                    PacketTracer, no_span)
 
 __all__ = [
     "Observability",
@@ -47,6 +47,7 @@ __all__ = [
     "EventLog",
     "Event",
     "EVENT_KINDS",
+    "FABRIC_SPANS",
     "LAYER_SPANS",
     "LayerSpans",
     "PacketTracer",

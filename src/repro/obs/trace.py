@@ -4,7 +4,8 @@ packet-lifecycle tracer.
 **Layer spans** (:class:`LayerSpans`, reached as
 ``Observability.span(name, shard)``) time the serving path's layers —
 flow parse, lookup and update, ingest, staging, dispatch, the wait on the
-device, egress — on every call:
+device, egress, and on a sharded fabric its RSS route and ordered merge —
+on every call:
 
 * each span adds its **self time** (its duration minus the spans nested
   inside it) to the registry counter named after it: ``flow.lookup`` →
@@ -58,8 +59,8 @@ from typing import Dict, List, Optional
 
 import numpy as np
 
-__all__ = ["LAYER_SPANS", "LayerSpans", "PacketTracer", "TRACE_STAGES",
-           "no_span"]
+__all__ = ["FABRIC_SPANS", "LAYER_SPANS", "LayerSpans", "PacketTracer",
+           "TRACE_STAGES", "no_span"]
 
 # The serving path's layer spans, in path order (README "Observability"
 # says where each one sits).  A pipeline registers every counter up front,
@@ -70,6 +71,12 @@ LAYER_SPANS = (
     "ingress.dispatch", "engine.compile", "ingress.device_wait",
     "egress.encode", "egress.cache_insert", "cache.compact",
     "egress.resolve")
+
+# The sharded fabric's own host layers, around its shards' spans: the RSS
+# route of a raw submit and the merge of a drain back into global order.
+# Only a fabric registers them (under its own label, ``shard=-1``), so a
+# single-shard server reports neither.
+FABRIC_SPANS = ("fabric.route", "fabric.merge")
 
 TRACE_STAGES = ("submit", "stage", "dispatch", "result_ready", "retire")
 

@@ -86,9 +86,12 @@ from ..flow.table import FlowTable
 from ..kernels.flow_update import cms_estimate_update
 from ..kernels.ref import sat_shl_np
 from ..launch.mesh import shard_devices
-from ..obs import Observability, StatsAdapter
+from ..obs import FABRIC_SPANS, Observability, StatsAdapter
 
 __all__ = ["ShardedPacketServer", "rss_shard"]
+
+# the label of the fabric's own spans and events, beside its shards' 0..N-1
+FABRIC = -1
 
 
 def rss_shard(key_hashes: np.ndarray, n_shards: int) -> np.ndarray:
@@ -302,6 +305,8 @@ class ShardedPacketServer:
         self._submit_hist = [
             reg.histogram("fabric_submit_seconds", shard=s)
             for s in range(n_shards)]
+        # the dispatcher's own host layers (route, merge), at 0 until used
+        self.obs.layer_spans.register(FABRIC, FABRIC_SPANS)
         # -- model-quality plane (PR 9): drift taps + shadow lane + SLO ----
         if drift_window or shadow_model is not None or slo_budget is not None:
             mon = self.obs.enable_drift(
@@ -476,11 +481,13 @@ class ShardedPacketServer:
     # -- dispatch ----------------------------------------------------------
 
     def dispatch_shards(self, raw) -> np.ndarray:
-        """Pure RSS mapping for a raw header batch: per-packet shard ids
-        (no state is touched — exposed for tests and observability)."""
+        """Per-packet shard ids that :meth:`submit_raw` would route a raw
+        header batch to over the current alive set (no state is touched —
+        exposed for tests and observability)."""
         fields = parse_raw_headers(raw)
         _, hashes = FlowTable.pack_keys(fields.key_bytes, self._key_words)
-        return rss_shard(hashes, self.n_shards)
+        with self._lock:
+            return self._route(hashes)
 
     def submit_raw(self, raw) -> Tuple[int, int]:
         """Raw 5-tuple ingress through the RSS dispatcher: parse once,
@@ -488,70 +495,82 @@ class ShardedPacketServer:
         scatter each packet to its flow's home shard (relative order
         preserved).  Returns global ``(first_ticket, n_packets)``."""
         with self._lock:
-            raw_arr, bad, reasons = validate_raw_rows(raw)
-            n = raw_arr.shape[0]
-            first = self._n_slots
-            if n == 0:
-                return first, 0
-            shard_ids = np.full(n, -1, np.int64)
-            if bad is None:
-                gidx = np.arange(n)
-            else:
-                self.fault_stats["fabric_rejected_rows_total"] += int(bad.sum())
-                gidx = np.nonzero(~bad)[0]
-            if gidx.size:
-                rows = raw_arr if bad is None else raw_arr[gidx]
-                fields = parse_raw_headers(rows)
-                _, hashes = FlowTable.pack_keys(fields.key_bytes,
-                                                self._key_words)
-                sids = self._route(hashes)
-                shard_ids[gidx] = sids
-                # global CMS over *admitted* rows, arrival order, against
-                # the fabric sketch — exactly the N=1 computation (the
-                # single-engine server rejects malformed rows before its
-                # sketch sees them too)
-                cells = self.flow_params.cms_cells(hashes)
-                est = cms_estimate_update(self.cms, cells)
-                est_q = sat_shl_np(est, self.flow_params.frac)
-                for s in np.unique(sids).tolist():
-                    sel = sids == s
-                    fields_s = RawHeaderBatch(
-                        key_bytes=fields.key_bytes[sel],
-                        model_id=fields.model_id[sel],
-                        ts=fields.ts[sel], length=fields.length[sel])
-                    t0 = time.perf_counter()
-                    try:
-                        self.shards[s].flow.submit_raw(
-                            rows[sel], fields=fields_s,
-                            cms_est_q=est_q[sel])
-                    except CompileError:
-                        raise  # a deployment fault: no shard is to blame
-                    except Exception as e:  # shard wedged at submit
-                        self.fault_stats["fabric_submit_failures_total"] += 1
-                        self._window_degraded = True
-                        if reasons is None:
-                            reasons = np.full(n, None, object)
-                        idx = gidx[sel]
-                        shard_ids[idx] = -1
-                        reasons[idx] = f"shard {s} submit failed: {e}"
-                        self._strike(s, f"submit raised: {e}")
-                        continue
-                    dt = time.perf_counter() - t0
-                    self._submit_hist[s].observe(dt)
-                    pl = self.shards[s].pipeline
-                    if (pl.consecutive_dispatch_failures
-                            >= self.max_consecutive_failures):
-                        self.kill_shard(
-                            s, "consecutive whole-batch dispatch failures")
-                    elif (self.watchdog_timeout is not None
-                            and dt > self.watchdog_timeout):
-                        self._strike(
-                            s, f"watchdog: submit took {dt * 1e3:.1f}ms")
-                    else:
-                        self._strikes[s] = 0
+            with self.obs.span("fabric.route", FABRIC):
+                raw_arr, bad, reasons = validate_raw_rows(raw)
+                n = raw_arr.shape[0]
+                first = self._n_slots
+                if n == 0:
+                    return first, 0
+                shard_ids = np.full(n, -1, np.int64)
+                if bad is None:
+                    gidx = np.arange(n)
+                else:
+                    self.fault_stats["fabric_rejected_rows_total"] += int(
+                        bad.sum())
+                    gidx = np.nonzero(~bad)[0]
+                parts = self._split(raw_arr, bad, gidx, shard_ids)
+            for s, sel, rows_s, fields_s, est_s in parts:
+                t0 = time.perf_counter()
+                try:
+                    self.shards[s].flow.submit_raw(
+                        rows_s, fields=fields_s, cms_est_q=est_s)
+                except CompileError:
+                    raise  # a deployment fault: no shard is to blame
+                except Exception as e:  # shard wedged at submit
+                    self.fault_stats["fabric_submit_failures_total"] += 1
+                    self._window_degraded = True
+                    if reasons is None:
+                        reasons = np.full(n, None, object)
+                    idx = gidx[sel]
+                    shard_ids[idx] = -1
+                    reasons[idx] = f"shard {s} submit failed: {e}"
+                    self._strike(s, f"submit raised: {e}")
+                    continue
+                dt = time.perf_counter() - t0
+                self._submit_hist[s].observe(dt)
+                pl = self.shards[s].pipeline
+                if (pl.consecutive_dispatch_failures
+                        >= self.max_consecutive_failures):
+                    self.kill_shard(
+                        s, "consecutive whole-batch dispatch failures")
+                elif (self.watchdog_timeout is not None
+                        and dt > self.watchdog_timeout):
+                    self._strike(
+                        s, f"watchdog: submit took {dt * 1e3:.1f}ms")
+                else:
+                    self._strikes[s] = 0
             self._order.append(_Submit(shard_ids, reasons))
             self._n_slots += n
             return first, n
+
+    def _split(self, raw_arr: np.ndarray, bad: Optional[np.ndarray],
+               gidx: np.ndarray, shard_ids: np.ndarray) -> list:
+        """The dispatcher's work on the admitted rows ``gidx`` of one raw
+        submit: parse, hash, route (written into ``shard_ids``), the global
+        sketch, and each shard's slice as ``(shard, mask over gidx, rows,
+        fields, sketch estimates)``, in shard order."""
+        if not gidx.size:
+            return []
+        rows = raw_arr if bad is None else raw_arr[gidx]
+        fields = parse_raw_headers(rows)
+        _, hashes = FlowTable.pack_keys(fields.key_bytes, self._key_words)
+        sids = self._route(hashes)
+        shard_ids[gidx] = sids
+        # global CMS over *admitted* rows, arrival order, against the
+        # fabric sketch — exactly the N=1 computation (the single-engine
+        # server rejects malformed rows before its sketch sees them too)
+        cells = self.flow_params.cms_cells(hashes)
+        est = cms_estimate_update(self.cms, cells)
+        est_q = sat_shl_np(est, self.flow_params.frac)
+        parts = []
+        for s in np.unique(sids).tolist():
+            sel = sids == s
+            fields_s = RawHeaderBatch(
+                key_bytes=fields.key_bytes[sel],
+                model_id=fields.model_id[sel],
+                ts=fields.ts[sel], length=fields.length[sel])
+            parts.append((s, sel, rows[sel], fields_s, est_q[sel]))
+        return parts
 
     def submit_packets(self, packets) -> Tuple[int, int]:
         """Encapsulated-packet ingress (no flow state): whole chunks
@@ -603,33 +622,15 @@ class ShardedPacketServer:
                     self._window_degraded = True
                     per.append(deque())
                     self._strike(sh.shard_id, f"drain raised: {e}")
-            out: List[Union[np.ndarray, PacketError]] = []
-            for rec in self._order:
-                rl = rec.reasons
-                for i, sid in enumerate(rec.shard_ids.tolist()):
-                    if sid < 0:  # never reached a shard
-                        why = (rl[i] if rl is not None and rl[i]
-                               else "rejected at admission")
-                        out.append(PacketError(ticket=len(out), reason=why))
-                        continue
-                    if not per[sid]:  # shard died with this result pending
-                        self.fault_stats["fabric_lost_results_total"] += 1
-                        out.append(PacketError(
-                            ticket=len(out),
-                            reason=f"shard {sid} lost this result "
-                                   "(shard failure)"))
-                        continue
-                    r = per[sid].popleft()
-                    if isinstance(r, PacketError):
-                        r = PacketError(ticket=len(out), reason=r.reason)
-                    out.append(r)
+            with self.obs.span("fabric.merge", FABRIC):
+                out = self._merge(per)
             if not self._window_degraded:
                 assert all(not q for q in per), \
                     "shard drained more results than the fabric dispatched"
             else:
                 self.fault_stats["fabric_degraded_windows_total"] += 1
                 self.obs.events.emit(
-                    "window_degraded", shard=-1,
+                    "window_degraded", shard=FABRIC,
                     generation=self.control_plane.version,
                     packets=len(out))
             self._window_degraded = False
@@ -640,6 +641,33 @@ class ShardedPacketServer:
                 # step on the monitor's own window cadence)
                 self.obs.health.evaluate()
             return out
+
+    def _merge(self, per: List[deque]
+               ) -> List[Union[np.ndarray, PacketError]]:
+        """Interleave the shards' drained results (``per[s]``, each in
+        shard order) into global submission order by the recorded
+        scatter; consumes ``per``."""
+        out: List[Union[np.ndarray, PacketError]] = []
+        for rec in self._order:
+            rl = rec.reasons
+            for i, sid in enumerate(rec.shard_ids.tolist()):
+                if sid < 0:  # never reached a shard
+                    why = (rl[i] if rl is not None and rl[i]
+                           else "rejected at admission")
+                    out.append(PacketError(ticket=len(out), reason=why))
+                    continue
+                if not per[sid]:  # shard died with this result pending
+                    self.fault_stats["fabric_lost_results_total"] += 1
+                    out.append(PacketError(
+                        ticket=len(out),
+                        reason=f"shard {sid} lost this result "
+                               "(shard failure)"))
+                    continue
+                r = per[sid].popleft()
+                if isinstance(r, PacketError):
+                    r = PacketError(ticket=len(out), reason=r.reason)
+                out.append(r)
+        return out
 
     def process(self, packets):
         """Synchronous single-batch path (first alive shard — API parity

@@ -127,6 +127,30 @@ class TestRSSDispatch:
             assert len(sh.flow.table) == len(flows)
         assert sum(len(f) for f in per_shard_flows) == 48
 
+    def test_dispatch_shards_follows_the_route_after_a_kill(self):
+        """After a shard dies, ``dispatch_shards`` names the shard that
+        ``submit_raw`` now routes each flow to: never the dead one, and
+        the RSS home for every flow the kill did not touch."""
+        rng = np.random.default_rng(4)
+        srv = _fabric(4)
+        raw = raw_trace(rng, 2000, n_flows=64, model_ids=(1,))
+        before = srv.dispatch_shards(raw)
+        assert (before == 1).any()
+        assert srv.kill_shard(1)
+        after = srv.dispatch_shards(raw)
+        assert not (after == 1).any()
+        np.testing.assert_array_equal(after[before != 1],
+                                      before[before != 1])
+        srv.submit_raw(raw)
+        srv.drain_packets()
+        fields = parse_raw_headers(raw)
+        per_shard_flows = [set() for _ in range(4)]
+        for k, s in zip(fields.key_bytes, after.tolist()):
+            per_shard_flows[s].add(bytes(k))
+        for s in srv.alive_shards:
+            assert len(srv.shards[s].flow.table) == len(per_shard_flows[s])
+        assert sum(len(f) for f in per_shard_flows) == 64
+
 
 class TestShardedBitExact:
     def _mixed_run(self, srv, rng):
@@ -240,3 +264,64 @@ class TestCrossShardInstallFence:
         assert all(sh.pipeline.cp is fab.control_plane
                    for sh in fab.shards)
         assert all(sh.engine.cp is fab.control_plane for sh in fab.shards)
+
+
+def _counters(srv, suffix="_seconds_total"):
+    snap = srv.obs.registry.snapshot()
+    return {k: v for k, v in snap.items() if k.endswith(suffix)}
+
+
+class TestFabricSpans:
+    def test_only_a_fabric_registers_route_and_merge(self):
+        fab = _counters(_fabric(4))
+        for name in ("fabric_route_seconds_total",
+                     "fabric_merge_seconds_total"):
+            assert fab[name] == {'shard="-1"': 0.0}
+        plain = _counters(_plain())
+        assert not {"fabric_route_seconds_total",
+                    "fabric_merge_seconds_total"} & set(plain)
+
+    def test_route_and_merge_advance(self):
+        srv = _fabric(4)
+        raw = raw_trace(np.random.default_rng(12), 1000, n_flows=40,
+                        model_ids=(1,))
+        srv.submit_raw(raw)
+        c = _counters(srv)
+        route = c["fabric_route_seconds_total"]['shard="-1"']
+        assert route > 0
+        assert c["fabric_merge_seconds_total"]['shard="-1"'] == 0
+        srv.drain_packets()
+        c = _counters(srv)
+        assert c["fabric_merge_seconds_total"]['shard="-1"'] > 0
+        assert c["fabric_route_seconds_total"]['shard="-1"'] == route
+
+    def test_route_leaves_out_the_shards_and_layers_fit_wall_time(
+            self, monkeypatch):
+        """A shard's own submit is outside ``fabric.route``: 50 ms slept
+        in each shard's ``flow.submit_raw``, under no span, never reaches
+        the route counter; and all layer counters together stay inside the
+        wall time of the submits and drains."""
+        import time
+
+        from repro.flow.frontend import FlowFrontend
+        submit = FlowFrontend.submit_raw
+
+        def slow(self, *a, **kw):
+            time.sleep(0.05)
+            return submit(self, *a, **kw)
+        monkeypatch.setattr(FlowFrontend, "submit_raw", slow)
+        srv = _fabric(4)
+        rng = np.random.default_rng(13)
+        wall = 0.0
+        for _ in range(3):
+            raw = raw_trace(rng, 512, n_flows=40, model_ids=(1,))
+            t0 = time.perf_counter()
+            srv.submit_raw(raw)
+            srv.drain_packets()
+            wall += time.perf_counter() - t0
+        c = _counters(srv)
+        assert len(set(srv.dispatch_shards(raw).tolist())) == 4
+        assert c["fabric_route_seconds_total"]['shard="-1"'] < 0.05
+        total = sum(sum(v.values()) for v in c.values())
+        assert 0 < total <= wall
+        assert wall - total >= 3 * 4 * 0.05 * 0.99
