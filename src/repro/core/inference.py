@@ -283,6 +283,10 @@ class DataPlaneEngine:
         int32 → device future of (B, out_features) int32 output codes.
         Byte counters credit the equivalent wire row sizes, so they stay
         comparable across the two surfaces.
+
+        With ``block=False`` the future's device-to-host copy is started
+        before return: every caller reads the result on the host, and the
+        copy then runs behind the caller's next host work.
         """
         if lanes not in ("both", "mlp", "forest"):
             raise ValueError(f"unknown lanes hint: {lanes!r}")
@@ -302,6 +306,15 @@ class DataPlaneEngine:
                                         + FEATURE_BYTES * self.out_features)
         if block:
             out.block_until_ready()
+        else:
+            start_copy = getattr(out, "copy_to_host_async", None)
+            if start_copy is not None:  # a test double may have none
+                try:
+                    start_copy()
+                except Exception:  # noqa: BLE001 — the batch is credited
+                    # already: whatever broke the copy raises again at the
+                    # caller's read, where the retire salvages it once
+                    pass
         return out
 
     def _program(self, args, use_mlp: bool, use_forest: bool):

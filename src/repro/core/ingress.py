@@ -65,7 +65,8 @@ Packet-level flow::
                                                   once) ──▶ staging ──▶ full?
                                                         │ yes
                                                         ▼
-                          engine.run_features(x0, mids, block=False) (async)
+                          engine.run_features(x0, mids, block=False) (async,
+                                                        │ host copy started)
                                                         │ retire
                                                         ▼
                emit egress rows (host, once) ──▶ scatter to tickets +
@@ -873,6 +874,7 @@ class IngressPipeline:
         _c("ingress_dispatched_rows_total")
         _c("ingress_padded_rows_total")
         _c("ingress_batches_total")
+        _c("ingress_retires_ready_total")
         _c("ingress_errors_total")
         _c("ingress_dispatch_retries_total")
         _c("ingress_dispatch_failures_total")
@@ -1805,9 +1807,19 @@ class IngressPipeline:
             rem = rec.hold_until - self._clock()
             if rem > 0:       # injected slow device: the batch is not done
                 time.sleep(rem)
+        # a batch whose result is already computed cannot be waiting on
+        # compute: any wait left below is the copy that dispatch started
+        ready = getattr(rec.future, "is_ready", None)
+        try:
+            if ready is not None and ready():
+                self.stats["ingress_retires_ready_total"] += 1
+        except Exception:  # noqa: BLE001 — a dying future raises at the read
+            pass
         try:
             with self.span("ingress.device_wait"):
-                out = np.asarray(rec.future)  # blocks until the batch is done
+                # blocks until the batch is done and its host copy, started
+                # at dispatch, has landed
+                out = np.asarray(rec.future)
         except Exception as err:  # noqa: BLE001 — device died mid-batch
             # run_features credited this batch when it dispatched; cancel
             # so the salvage pass accounts it exactly once

@@ -289,3 +289,66 @@ class TestAsyncServing:
         srv.drain()
         assert srv.engine.trace_count == 1
         assert srv.control_plane.version > gen
+
+
+class _CopySpy:
+    """A device result that counts the host-copy starts made on it."""
+
+    def __init__(self, out, starts):
+        self._out = out
+        self._starts = starts
+
+    def copy_to_host_async(self):
+        self._starts.append(1)
+        self._out.copy_to_host_async()
+
+    def block_until_ready(self):
+        self._out.block_until_ready()
+        return self
+
+    def __array__(self, dtype=None, copy=None):
+        return np.asarray(self._out, dtype)
+
+
+class TestHostCopyAtDispatch:
+    """``run_features(block=False)`` starts the result's device-to-host copy
+    before it returns, so the retire reads bytes already on their way."""
+
+    def _engine(self):
+        from repro.data.packets import anomaly_dataset
+        from repro.forest import train_forest
+        rng = np.random.default_rng(61)
+        cp = ControlPlane(max_models=4, max_layers=2, max_width=8,
+                          frac_bits=FRAC, max_forests=2, max_trees=4,
+                          max_nodes=16, max_tree_depth=4)
+        _install_zoo(cp, rng, 2, 8)
+        X, y = anomaly_dataset(rng, 200, 8)
+        cp.install_forest(7, train_forest(X, y, task="classify", n_trees=3,
+                                          max_depth=3, max_nodes=15, seed=3))
+        return cp, DataPlaneEngine(cp, max_features=8)
+
+    @pytest.mark.parametrize("lanes", ["mlp", "forest", "both"])
+    def test_copy_started_once_and_rows_match_blocking(self, lanes,
+                                                       monkeypatch):
+        cp, eng = self._engine()
+        rng = np.random.default_rng(62)
+        mids = rng.choice([100, 101, 7, 60000], 64).astype(np.int32)
+        codes = rng.integers(-2000, 2000, (64, 8)).astype(np.int32)
+        starts = []
+        real = eng._program
+
+        def spying(args, use_mlp, use_forest):
+            prog = real(args, use_mlp, use_forest)
+            return lambda *a: _CopySpy(prog(*a), starts)
+        monkeypatch.setattr(eng, "_program", spying)
+        fut = eng.run_features(codes, mids, block=False, lanes=lanes)
+        assert len(starts) == 1
+        got = np.asarray(fut)
+        assert len(starts) == 1           # reading starts no second copy
+        want = np.asarray(eng.run_features(codes, mids, block=True,
+                                           lanes=lanes))
+        assert len(starts) == 1           # a blocking call starts none
+        np.testing.assert_array_equal(got, want)
+        assert got.dtype == np.int32 and got.shape == (64, eng.out_features)
+        assert eng.stats["packets"] == 128
+        assert eng.trace_count == 1

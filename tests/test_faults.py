@@ -150,6 +150,16 @@ class TestFaultPlan:
             plan.install(object())
 
 
+class _LostResult:
+    """A batch result the device lost: starting its host copy raises, and
+    so does every later read of it."""
+
+    def _lost(self, *a, **kw):
+        raise RuntimeError("device lost the batch result")
+
+    copy_to_host_async = is_ready = block_until_ready = __array__ = _lost
+
+
 class TestGracefulPipeline:
     def test_transient_dispatch_fault_is_invisible(self):
         """A fault window the retry path covers: results bit-exact with an
@@ -241,6 +251,71 @@ class TestGracefulPipeline:
         srv.submit_raw(raw)
         ref.submit_raw(raw)
         _assert_bitexact(srv.drain_packets(), ref.drain_packets())
+
+    @pytest.mark.parametrize("lost", ["first", "every"])
+    def test_lost_host_copy_salvaged_once(self, lost, monkeypatch):
+        """A batch result whose host copy cannot start (nor its read
+        finish) fails at retire, not at dispatch: no dispatch retry, one
+        salvage per lost batch, and the engine's packet and byte counters
+        match a clean run's.  Losing only the first result, the probes
+        serve every row bit-exact; losing every result, each slot is the
+        quarantine error a dispatch-site failure gives."""
+        rng = np.random.default_rng(23)
+        wire = _wire(rng, 200, np.ones(200, np.int64))  # four batches
+        srv, clean = _plain(), _plain()
+        ingress, eng = srv.ingress, srv.ingress.engine
+        real = eng._program
+        runs = []
+
+        def losing(args, use_mlp, use_forest):
+            prog = real(args, use_mlp, use_forest)
+
+            def run(*a):
+                runs.append(1)
+                out = prog(*a)
+                return _LostResult() if lost == "every" or len(runs) == 1 \
+                    else out
+            return run
+        monkeypatch.setattr(eng, "_program", losing)
+        salvaged = []
+        real_salvage = ingress._salvage_failed_batch
+
+        def counting(*a, **kw):
+            salvaged.append(1)
+            return real_salvage(*a, **kw)
+        monkeypatch.setattr(ingress, "_salvage_failed_batch", counting)
+        srv.submit_packets(wire)
+        clean.submit_packets(wire)
+        got, want = srv.drain_packets(), clean.drain_packets()
+        n_batches = ingress.stats["ingress_batches_total"]
+        assert n_batches == 4
+        assert ingress.stats["ingress_dispatch_retries_total"] == 0
+        if lost == "first":
+            _assert_bitexact(got, want)
+            assert len(salvaged) == 1
+            assert ingress.stats["ingress_probe_batches_total"] == 1
+        else:
+            failed = _plain()
+            FaultPlan([FaultSpec(site="dispatch",
+                                 count=FOREVER)]).install(failed)
+            failed.submit_packets(wire)
+            slots = failed.drain_packets()
+            assert len(got) == len(slots) == 200
+            for a, b in zip(got, slots):
+                assert isinstance(a, PacketError)
+                assert isinstance(b, PacketError)
+                assert a.reason == b.reason
+                assert "quarantined" in a.reason
+            assert len(salvaged) == n_batches
+            for k in ("ingress_dispatch_failures_total",
+                      "ingress_quarantined_rows_total"):
+                assert ingress.stats[k] == failed.ingress.stats[k], k
+            assert (failed.ingress.engine.stats["packets"]
+                    == eng.stats["packets"])
+        assert ingress.stats["ingress_dispatch_failures_total"] \
+            == len(salvaged)
+        for k in ("packets", "bytes_in", "bytes_out"):
+            assert eng.stats[k] == clean.ingress.engine.stats[k], k
 
     @pytest.mark.parametrize("n_shards", [0, 2])
     def test_compile_error_raises_not_retried(self, n_shards):

@@ -555,6 +555,75 @@ class TestPipelineCorrectness:
         np.testing.assert_array_equal(np.stack(got), want)
 
 
+class _Scripted:
+    """A device result whose readiness the test decides."""
+
+    def __init__(self, out, ready):
+        self._out = out
+        self._ready = ready
+
+    def is_ready(self):
+        return self._ready
+
+    def block_until_ready(self):
+        return self
+
+    def __array__(self, dtype=None, copy=None):
+        return np.asarray(self._out, dtype)
+
+
+class TestRetiresReadyCounter:
+    """``ingress_retires_ready_total`` counts the retires whose batch had
+    already been computed when the retire began."""
+
+    def test_registered_at_zero_and_counts_ready_retires(self, monkeypatch):
+        rng = np.random.default_rng(71)
+        _, eng, pipe = _pipeline(batch_size=32, max_inflight=2)
+        snap = pipe.obs.registry.snapshot()
+        assert snap["ingress_retires_ready_total"]['shard="0"'] == 0
+        assert pipe.stats["ingress_retires_ready_total"] == 0
+        real = eng._program
+        runs = []
+
+        def scripted(args, use_mlp, use_forest):
+            prog = real(args, use_mlp, use_forest)
+
+            def run(*a):
+                runs.append(1)
+                return _Scripted(prog(*a), len(runs) % 3 != 2)
+            return run
+        monkeypatch.setattr(eng, "_program", scripted)
+        wire = _wire(rng, 7 * 32)        # distinct rows: seven full batches
+        want = np.asarray(eng.process(wire))[:, : pipe.out_bytes]
+        ready_seen = []
+        for k in range(7):
+            pipe.submit(wire[32 * k: 32 * (k + 1)])
+            n = pipe.stats["ingress_retires_ready_total"]
+            assert n <= pipe.stats["ingress_batches_total"]
+            ready_seen.append(n)
+        got = pipe.drain()
+        np.testing.assert_array_equal(np.stack(got), want)
+        assert pipe.stats["ingress_batches_total"] == len(runs) == 7
+        # batches 2 and 5 were still cooking when they retired
+        assert pipe.stats["ingress_retires_ready_total"] == 5
+        assert ready_seen == sorted(ready_seen)
+        snap = pipe.obs.registry.snapshot()
+        assert snap["ingress_retires_ready_total"]['shard="0"'] == 5
+
+    def test_one_per_retire_of_a_finished_batch(self):
+        rng = np.random.default_rng(72)
+        _, _, pipe = _pipeline(batch_size=32, max_inflight=4)
+        for k in range(3):
+            pipe.submit(_wire(rng, 32))
+            assert len(pipe._inflight) == k + 1
+            pipe._inflight[-1].future.block_until_ready()
+        pipe.flush()
+        assert pipe.stats["ingress_batches_total"] == 3
+        assert pipe.stats["ingress_retires_ready_total"] == 3
+        snap = pipe.obs.registry.snapshot()
+        assert snap["ingress_retires_ready_total"]['shard="0"'] == 3
+
+
 class _FakeClock:
     """Deterministic injectable clock: age-based pipeline behavior is
     tested by advancing time, not by sleeping against the scheduler."""
