@@ -14,7 +14,7 @@ import numpy as np
 
 from repro.configs import get_config, reduced
 from repro.core.quantize import quantize_tree
-from repro.launch.serve import LMServer
+from repro.launch.lm_serve import LMServer
 
 
 def main():

@@ -1098,17 +1098,6 @@ class ControlPlane:
                 self._snapshot[device] = snap
             return snap[1]
 
-    def invalidate_snapshot(self) -> None:
-        """Drop every cached device snapshot so the next ``tables()`` call
-        re-uploads from host buffers.  Not needed in normal operation (the
-        generation counter invalidates automatically); exists for benchmarks
-        emulating the pre-double-buffer per-batch-upload behavior and for
-        tests that want to force a fresh transfer."""
-        with self._lock:
-            self._snapshot.clear()
-            self._forest_snapshot.clear()
-            self._range_snapshot.clear()
-
     @property
     def version(self) -> int:
         """Table generation — bumped by every install/remove swap."""

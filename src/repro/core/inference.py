@@ -21,9 +21,9 @@ MLP deployment compiles exactly the PR-1 program).
 The lane-dispatch core lives in ``kernels.fused_serve.serve_lanes`` — one
 definition shared by both serving surfaces:
 
-  * ``run()`` / ``process()`` — the **wire path**: uint8 packet batches,
-    byte parse and egress deparse inside the program (the PR-1 surface,
-    kept for the legacy batch API and as the byte-level oracle).
+  * ``process()`` — the **wire path**: uint8 packet batches, byte parse
+    and egress deparse inside the program (blocking; kept as the
+    byte-level reference the serving paths are tested against).
   * ``run_features()`` — the **feature path** (the cold-path tentpole):
     already-parsed int32 feature codes and Model IDs in, int32 output codes
     out — pure compute, one dispatch, no byte codec in the program.  The
@@ -38,13 +38,13 @@ All arithmetic inside the program is integer (int32 accumulate, rounding
 arithmetic shifts) — bit-exact with what the P4/FPGA pipeline would compute —
 and every parameter is a traced argument fetched from the control plane, so
 weight updates never recompile (asserted by ``trace_count``).  The control
-plane double-buffers its tables: ``run()`` snapshots the current generation,
-so an ``install()`` racing an in-flight batch is safe (the batch keeps the
-old buffers; the next batch picks up the new generation).
+plane double-buffers its tables: every dispatch snapshots the current
+generation, so an ``install()`` racing an in-flight batch is safe (the
+batch keeps the old buffers; the next batch picks up the new generation).
 
-``run(pkts, block=False)`` dispatches without waiting for the device —
-callers (``launch.serve.PacketServer``) overlap host-side packet encode with
-device compute.
+``run_features(..., block=False)`` dispatches without waiting for the
+device — the ingress pipeline overlaps host-side staging and egress encode
+with device compute.
 """
 
 from __future__ import annotations
@@ -88,11 +88,8 @@ class DataPlaneEngine:
         Static parser bound (P4 header-stack depth).
     taylor_order:
         Sigmoid polynomial order (paper Table 3: 1, 3 or 5).
-    dispatch:
-        ``"fused"`` (stacked-table masked-GEMM kernel, default) or
-        ``"gather"`` (per-packet weight gather — the seed baseline).
     backend:
-        Kernel backend for the fused path: ``"auto"`` (Pallas on TPU, the
+        Kernel backend of both lanes: ``"auto"`` (Pallas on TPU, the
         gathered jnp lowering on CPU), ``"pallas"`` (force kernel,
         interpreted off-TPU) or ``"ref"``.
     kernel_variant:
@@ -118,12 +115,10 @@ class DataPlaneEngine:
 
     def __init__(self, control_plane: ControlPlane, *, max_features: int = 16,
                  taylor_order: int = 3, leaky_alpha: float = 0.01,
-                 dispatch: str = "fused", backend: str = "auto",
+                 backend: str = "auto",
                  kernel_variant: str = "int16",
                  forest_variant: str = "auto",
                  device=None):
-        if dispatch not in ("fused", "gather"):
-            raise ValueError(f"unknown dispatch strategy: {dispatch!r}")
         if backend not in ("auto", "pallas", "ref"):
             raise ValueError(f"unknown kernel backend: {backend!r}")
         if kernel_variant not in ("int16", "int8"):
@@ -158,7 +153,6 @@ class DataPlaneEngine:
         # property of the data plane, like max_layers for the MLP lane)
         self.max_tree_depth = control_plane.max_tree_depth
         self.taylor_order = taylor_order
-        self.dispatch = dispatch
         self.backend = backend
         self.frac = control_plane.frac_bits
         self._leaky_alpha_q = int(round(leaky_alpha * (1 << self.frac)))
@@ -167,9 +161,8 @@ class DataPlaneEngine:
         self.lane_cfg = LaneConfig(
             frac=self.frac, sig_coeffs=self._sig_coeffs,
             leaky_alpha_q=self._leaky_alpha_q, max_features=max_features,
-            max_tree_depth=self.max_tree_depth, dispatch=dispatch,
-            backend=backend, kernel_variant=kernel_variant,
-            forest_variant=forest_variant)
+            max_tree_depth=self.max_tree_depth, backend=backend,
+            kernel_variant=kernel_variant, forest_variant=forest_variant)
         self.out_features = min(max_features, int(control_plane.max_width))
         self.trace_count = 0
         self.stats = {"packets": 0, "bytes_in": 0, "bytes_out": 0}
@@ -241,15 +234,10 @@ class DataPlaneEngine:
 
     # -- host API -----------------------------------------------------------
 
-    def run(self, pkts, *, block: bool = True, lanes: str = "both") -> jax.Array:
+    def process(self, pkts, *, lanes: str = "both") -> jax.Array:
         """Run one mixed-model batch of ingress packets → egress packets
-        (the wire path: byte parse/deparse inside the program).
-
-        ``block=False`` returns as soon as the batch is *dispatched*: the
-        returned array is a device future, so callers can pipeline host-side
-        encode/decode of neighbouring batches against device compute (see
-        ``PacketServer.submit_async``).  Packet/byte counters update
-        immediately.
+        (the wire path: byte parse/deparse inside the program), blocking
+        until the egress is ready.
 
         ``lanes`` is the lane-pure dispatch hint: ``"both"`` (default —
         correct for any batch), ``"mlp"`` or ``"forest"`` skip the other
@@ -268,8 +256,7 @@ class DataPlaneEngine:
         self.stats["packets"] += int(pkts.shape[0])
         self.stats["bytes_in"] += int(pkts.size)
         self.stats["bytes_out"] += int(out.size)
-        if block:
-            out.block_until_ready()
+        out.block_until_ready()
         return out
 
     def run_features(self, feats_q, model_id, *, block: bool = True,
@@ -355,10 +342,6 @@ class DataPlaneEngine:
         return {(k[0], _LANE_NAMES[k[1:]]): v
                 for k, v in self._programs.items()}
 
-    def process(self, pkts) -> jax.Array:
-        """Blocking alias of :meth:`run` (the seed API)."""
-        return self.run(pkts, block=True)
-
     def warm(self, batch_size: int, wire_len: int, *,
              lanes: Sequence[str] = ("both",),
              feature_batches: "Sequence[int] | None" = None) -> None:
@@ -377,7 +360,7 @@ class DataPlaneEngine:
         pkts = jnp.zeros((batch_size, wire_len), jnp.uint8)
         before = dict(self.stats)
         for lane in lanes:
-            self.run(pkts, block=True, lanes=lane)
+            self.process(pkts, lanes=lane)
             for fb in feature_batches:
                 x0 = jnp.zeros((fb, self.max_features), jnp.int32)
                 mid = jnp.zeros((fb,), jnp.int32)

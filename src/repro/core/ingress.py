@@ -88,7 +88,7 @@ from .packet import (FEATURE_BYTES, FLAG_REFLEX, HEADER_BYTES,
                      emit_results_np, parse_packets_np)
 from ..obs import Observability, StatsAdapter, no_span
 
-__all__ = ["PacketError", "BatchError", "ResultCache", "IngressPipeline",
+__all__ = ["PacketError", "ResultCache", "IngressPipeline",
            "pack_rows", "STATUS_PENDING", "STATUS_READY", "STATUS_ERROR",
            "DEADLINE_SHED", "DRAIN_TIMEOUT"]
 
@@ -110,21 +110,6 @@ class PacketError:
 
     ticket: int
     reason: str
-
-
-@dataclasses.dataclass(frozen=True)
-class BatchError:
-    """Batch-level rejection marker for the legacy ``PacketServer`` drain
-    path: occupies the rejected batch's submission-order slot and expands to
-    per-packet error slots."""
-
-    reason: str
-    n_packets: int
-
-    @property
-    def per_packet(self) -> List[PacketError]:
-        return [PacketError(ticket=i, reason=self.reason)
-                for i in range(self.n_packets)]
 
 
 # ---------------------------------------------------------------------------

@@ -6,10 +6,11 @@ surface: Model-ID resolution through both id maps, the fused MLP kernel
 (``kernels.forest_traversal`` — pointer-chase or range-table variant) and
 per-model output masking, over already-parsed int32 feature codes.
 ``core.inference.DataPlaneEngine`` jits it directly for the feature path
-(``run_features``) and composes it with the byte codec for the legacy wire
-path — one definition, so the two surfaces cannot drift.  Raw traffic
-enters here too: the flow update and the feature-spec gather run on the
-host (``repro.flow``), and the wire byte layout is paid once, at egress.
+(``run_features``) and composes it with the byte codec for the wire path
+(``process``, the byte-level reference) — one definition, so the two
+surfaces cannot drift.  Raw traffic enters here too: the flow update and
+the feature-spec gather run on the host (``repro.flow``), and the wire
+byte layout is paid once, at egress.
 
 Everything here is trace-time composition: pure jnp/Pallas-kernel call
 graphs with static lane/variant switches, jitted by their callers (the
@@ -37,7 +38,6 @@ class LaneConfig(NamedTuple):
     leaky_alpha_q: int
     max_features: int
     max_tree_depth: int
-    dispatch: str = "fused"         # "fused" | "gather" (MLP lane)
     backend: str = "auto"           # kernel backend selection
     kernel_variant: str = "int16"   # MLP weight lane
     forest_variant: str = "chase"   # forest traversal lowering
@@ -53,8 +53,6 @@ def serve_lanes(x0: jax.Array, model_id: jax.Array, tables, ftables, rtables,
     id map resolves the Model ID picks the egress row; unresolved ids (and
     dead padding rows, which carry Model ID 0) egress zeros.
     """
-    from .ref import fused_mlp_gather_ref  # local: avoid import cycle noise
-
     width = tables.w.shape[-1]
     if x0.shape[1] < width:
         x0 = jnp.pad(x0, ((0, 0), (0, width - x0.shape[1])))
@@ -67,18 +65,11 @@ def serve_lanes(x0: jax.Array, model_id: jax.Array, tables, ftables, rtables,
         slot = tables.id_map[model_id]  # (B,) — mixed models
         valid = slot >= 0
         slot = jnp.maximum(slot, 0)
-        if cfg.dispatch == "fused":
-            x = fused_mlp(x0, slot, tables.w, tables.b, tables.act,
-                          tables.layer_on, frac=cfg.frac,
-                          sig_coeffs=cfg.sig_coeffs,
-                          leaky_alpha_q=cfg.leaky_alpha_q,
-                          backend=cfg.backend, variant=cfg.kernel_variant)
-        else:
-            x = fused_mlp_gather_ref(
-                x0, slot, tables.w, tables.b, tables.act, tables.layer_on,
-                frac=cfg.frac, sig_coeffs=cfg.sig_coeffs,
-                leaky_alpha_q=cfg.leaky_alpha_q,
-                lane_bits=8 if cfg.kernel_variant == "int8" else None)
+        x = fused_mlp(x0, slot, tables.w, tables.b, tables.act,
+                      tables.layer_on, frac=cfg.frac,
+                      sig_coeffs=cfg.sig_coeffs,
+                      leaky_alpha_q=cfg.leaky_alpha_q,
+                      backend=cfg.backend, variant=cfg.kernel_variant)
         out_dim = tables.out_dim[slot][:, None]
         outputs = jnp.where((lane < out_dim) & valid[:, None], x, 0)
     else:

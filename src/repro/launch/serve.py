@@ -1,51 +1,41 @@
-"""Serving drivers — both of the paper's deployment shapes:
+"""The packet server — the paper's system: the in-network data plane
+processing encapsulated feature packets against control-plane tables
+(µs-scale inference, weight hot-swap without recompile).
 
-  * :class:`PacketServer` — the paper's actual system: the in-network data
-    plane processing encapsulated feature packets against control-plane
-    tables (µs-scale inference, weight hot-swap without recompile).  Serving
-    runs through the **ingress pipeline** (``core/ingress.py``): ragged
-    per-connection chunks are coalesced into fixed-shape mixed-model batches
-    (zero retraces), byte-identical duplicate packets short-circuit through
-    a generation-aware result cache (invalidated automatically by
-    ``install()``/``remove()``), and host staging is double-buffered so
-    packing batch N+1 overlaps device compute of batch N.  The legacy
-    batch-level async API (``submit_async()``/``drain()``) is kept for
-    callers that already batch their traffic; rejected batches occupy
-    **error slots** in submission order instead of silently vanishing from
-    the drain.  ``install()`` during serving is safe and retrace-free: the
-    control plane publishes a new table generation while in-flight batches
-    keep the old buffers (double buffering).
-  * :class:`LMServer` — the framework-scale generalization: batched LM
-    decode with KV caches, W8A8 fixed-point weights (C1), Taylor activations
-    (C2), and the same control-plane hot-swap semantics via WeightRegistry.
+:class:`PacketServer` serves through the **ingress pipeline**
+(``core/ingress.py``): ragged per-connection chunks are coalesced into
+fixed-shape mixed-model batches (zero retraces), byte-identical duplicate
+packets short-circuit through a generation-aware result cache (invalidated
+automatically by ``install()``/``remove()``), and host staging is
+double-buffered so packing batch N+1 overlaps device compute of batch N.
+``install()`` during serving is safe and retrace-free: the control plane
+publishes a new table generation while in-flight batches keep the old
+buffers (double buffering).
+
+The LM decode server lives in :mod:`repro.launch.lm_serve`, so importing
+this module loads no LM model code.
 """
 
 from __future__ import annotations
 
 import time
-from collections import deque
-from typing import Any, Dict, List, Optional, Union
+from typing import Any, Dict, Optional
 
-import jax
-import jax.numpy as jnp
 import numpy as np
 
-from ..configs import get_config, reduced
-from ..core.control_plane import ControlPlane, WeightRegistry
+from ..core.control_plane import ControlPlane
 from ..core.inference import DataPlaneEngine
-from ..core.ingress import BatchError, IngressPipeline
-from ..core.packet import HEADER_BYTES
-from ..models import build_model
+from ..core.ingress import IngressPipeline
 from ..serve import ShardedPacketServer
 
-__all__ = ["PacketServer", "ShardedPacketServer", "LMServer", "BatchError"]
+__all__ = ["PacketServer", "ShardedPacketServer"]
 
 
 class PacketServer:
     """Deployment wrapper: ControlPlane + DataPlaneEngine + ingress pipeline
     (+ the stateful flow engine, created on first use).
 
-    Three serving surfaces:
+    Two serving surfaces:
 
       * **raw-packet API** — ``submit_raw()`` accepts raw 5-tuple header
         batches (no feature block): the flow engine (``repro.flow``)
@@ -62,18 +52,15 @@ class PacketServer:
         (:meth:`install_forest`), the queue stages MLP- and forest-family
         packets into lane-pure device batches, so mixed-family traffic pays
         each packet's own compute lane only.
-      * **legacy batch API** — ``submit_async()``/``drain()`` dispatch
-        caller-formed batches with up to ``max_inflight`` device futures
-        outstanding.  A batch failing validation occupies a
-        :class:`~repro.core.ingress.BatchError` slot in the drain (order
-        preserved, per-packet errors attached) instead of raising away the
-        submissions behind it.
+
+    :meth:`process` is the blocking wire-path reference: one batch through
+    the engine, with no pipeline, cache or flow state.
     """
 
     def __init__(self, *, max_models: int = 16, max_layers: int = 4,
                  max_width: int = 32, frac_bits: int = 8,
                  weight_bits: int = 16, taylor_order: int = 3,
-                 dispatch: str = "fused", kernel_variant: str = "int16",
+                 kernel_variant: str = "int16",
                  forest_variant: str = "auto",
                  max_inflight: int = 8, ingress_batch: int = 2048,
                  use_cache: bool = True, cache_capacity_pow2: int = 16,
@@ -107,12 +94,11 @@ class PacketServer:
         self.engine = DataPlaneEngine(self.control_plane,
                                       max_features=max_width,
                                       taylor_order=taylor_order,
-                                      dispatch=dispatch,
                                       kernel_variant=kernel_variant,
                                       forest_variant=forest_variant)
         # the pipeline pools max_inflight+2 staging buffers of
         # ingress_batch feature rows each (two open family batches + the
-        # in-flight window) — the same window the batch API gets
+        # in-flight window)
         self.ingress = IngressPipeline(
             self.engine, batch_size=ingress_batch,
             max_inflight=max_inflight, use_cache=use_cache,
@@ -145,9 +131,7 @@ class PacketServer:
 
                 obs.health.add_rule("slo:submit_p99", "slo_burn", _burn,
                                     1.0, budget_s=slo_budget)
-        self.max_inflight = max_inflight
         self.strict_model_ids = strict_model_ids
-        self._inflight: deque = deque()
         # flow engine (stage 0): created on first submit_raw() so pure
         # feature-vector deployments never allocate the register file
         self._flow_capacity_pow2 = flow_capacity_pow2
@@ -294,110 +278,6 @@ class PacketServer:
             self.obs.health.evaluate()
         return out
 
-    # -- async serving loop (legacy batch-level API) -----------------------
-
-    def _validate_batch(self, packets):
-        """Shape/dtype validation that never materializes a device array:
-        jax arrays are inspected through their metadata so the async hot
-        path stays free of device→host round trips.  Returns the batch in a
-        form ``engine.run`` accepts."""
-        shape = getattr(packets, "shape", None)
-        dtype = getattr(packets, "dtype", None)
-        if shape is None or dtype is None:
-            packets = np.asarray(packets)  # list-of-lists etc.; may raise
-            shape, dtype = packets.shape, packets.dtype
-        if len(shape) != 2:
-            raise ValueError(
-                f"packet batch must be 2-D (n_packets, wire_len), "
-                f"got shape {tuple(shape)}")
-        if shape[1] < HEADER_BYTES:
-            raise ValueError(
-                f"wire length {shape[1]} shorter than the "
-                f"{HEADER_BYTES}-byte encapsulation header")
-        if dtype != np.uint8:
-            if not np.issubdtype(np.dtype(dtype), np.integer):
-                raise ValueError(f"packet bytes must be integer, "
-                                 f"got dtype {dtype}")
-            # host arrays get a cheap range check; device arrays keep the
-            # engine's modular uint8 cast (the pre-existing batch semantics)
-            if isinstance(packets, np.ndarray) and packets.size \
-                    and (packets.min() < 0 or packets.max() > 255):
-                raise ValueError("packet byte values outside [0, 255]")
-        return packets
-
-    def submit_async(self, packets) -> Union[jax.Array, BatchError]:
-        """Dispatch one ingress batch without blocking; returns the egress
-        device future.  When ``max_inflight`` batches are pending, the
-        oldest is retired first (bounded queue → bounded device memory).
-
-        A batch that fails wire-format validation is **rejected in place**:
-        instead of raising (which used to silently drop the batch's slot and
-        reorder everything drained after it), a :class:`BatchError` carrying
-        per-packet error slots is queued in the batch's submission-order
-        position and returned to the caller.  ``n_packets`` is the leading
-        dimension when the input is recognizably 2-D, else 0 (unknown).
-        Error slots are bounded: past ``_MAX_ERROR_SLOTS`` undrained
-        rejections the oldest slots are pruned, so a caller that never
-        drains cannot grow the window without bound.
-        """
-        try:
-            arr = self._validate_batch(packets)
-        except (ValueError, TypeError) as e:
-            n = 0
-            try:
-                shape = getattr(packets, "shape", None)
-                if shape is not None and len(shape) == 2:
-                    n = int(shape[0])
-            except Exception:
-                pass
-            err = BatchError(reason=str(e), n_packets=n)
-            self._inflight.append(err)
-            self._prune_error_slots()
-            return err
-        while self._count_pending() >= self.max_inflight:
-            self._retire_one()
-        out = self.engine.run(arr, block=False)
-        self._inflight.append(out)
-        return out
-
-    _MAX_ERROR_SLOTS = 1024
-
-    def _prune_error_slots(self) -> None:
-        n_err = sum(1 for o in self._inflight if isinstance(o, BatchError))
-        i = 0
-        while n_err > self._MAX_ERROR_SLOTS and i < len(self._inflight):
-            if isinstance(self._inflight[i], BatchError):
-                del self._inflight[i]
-                n_err -= 1
-            else:
-                i += 1
-
-    def _count_pending(self) -> int:
-        return sum(1 for o in self._inflight if not isinstance(o, BatchError))
-
-    def _retire_one(self) -> None:
-        """Block on the oldest pending device future (skipping error slots,
-        which stay queued for the drain).  Index-based removal: jax arrays
-        overload ``==`` elementwise, so ``deque.remove`` must not be used."""
-        for i, o in enumerate(self._inflight):
-            if not isinstance(o, BatchError):
-                o.block_until_ready()
-                del self._inflight[i]
-                return
-
-    def drain(self) -> List[Union[jax.Array, BatchError]]:
-        """Block until every in-flight batch has retired.  Returns the
-        entries still in flight **in submission order** — device batches
-        interleaved with the :class:`BatchError` slots of rejected batches
-        (every ``submit_async`` call already handed its own future/error
-        to the caller)."""
-        outs = list(self._inflight)
-        self._inflight.clear()
-        for o in outs:
-            if not isinstance(o, BatchError):
-                o.block_until_ready()
-        return outs
-
     def stats(self) -> Dict[str, float]:
         out = {"recompiles": self.engine.trace_count,
                "table_generation": self.control_plane.version,
@@ -410,78 +290,12 @@ class PacketServer:
         return out
 
 
-class LMServer:
-    """Batched LM decode loop with control-plane weight hot-swap.
-
-    The decode step is jitted once over abstract weights; ``install()``
-    swaps checkpoints (e.g. freshly retrained) with zero recompiles —
-    asserted by ``trace_count`` exactly like the packet engine.
-    """
-
-    def __init__(self, cfg, *, batch: int = 8, max_seq: int = 256):
-        self.cfg = cfg
-        self.model = build_model(cfg)
-        self.registry = WeightRegistry()
-        self.batch = batch
-        self.max_seq = max_seq
-        self.trace_count = 0
-        self.stats = {"tokens": 0, "seconds": 0.0}
-
-        def _step(params, caches, tokens, pos):
-            self.trace_count += 1
-            return self.model.decode_step(params, caches, tokens, pos)
-
-        self._step = jax.jit(_step, donate_argnums=(1,))
-
-    def install(self, name: str, params) -> None:
-        self.registry.install(name, params)
-
-    def new_session(self):
-        return self.model.init_caches(self.batch, self.max_seq)
-
-    def generate(self, name: str, prompt_tokens: np.ndarray, n_tokens: int,
-                 temperature: float = 0.0, seed: int = 0):
-        """Greedy/temperature decode of ``n_tokens`` past the prompt."""
-        params = self.registry.get(name)
-        caches = self.new_session()
-        b, prompt_len = prompt_tokens.shape
-        assert b == self.batch
-        key = jax.random.key(seed)
-        toks = jnp.asarray(prompt_tokens, jnp.int32)
-        out = []
-        t0 = time.perf_counter()
-        cur = toks[:, :1]
-        logits = None
-        for t in range(prompt_len + n_tokens - 1):
-            pos = jnp.full((b,), t, jnp.int32)
-            logits, caches = self._step(params, caches, cur, pos)
-            if t + 1 < prompt_len:
-                cur = toks[:, t + 1: t + 2]
-            else:
-                if temperature > 0:
-                    key, sub = jax.random.split(key)
-                    nxt = jax.random.categorical(
-                        sub, logits[:, -1] / temperature, axis=-1)
-                else:
-                    nxt = jnp.argmax(logits[:, -1], axis=-1)
-                cur = nxt[:, None].astype(jnp.int32)
-                out.append(np.asarray(cur[:, 0]))
-        dt = time.perf_counter() - t0
-        self.stats["tokens"] += b * (prompt_len + n_tokens - 1)
-        self.stats["seconds"] += dt
-        return np.stack(out, axis=1)
-
-    def tokens_per_second(self) -> float:
-        s = self.stats
-        return s["tokens"] / s["seconds"] if s["seconds"] else 0.0
-
-
 def main(argv=None) -> int:
     """``python -m repro.launch.serve`` — drive a synthetic raw-header trace
     through a (possibly sharded) server and export the telemetry snapshot.
 
-    The point is operational: CI's smoke bench runs this with
-    ``--metrics-json`` to archive a metrics artifact per build, and
+    The point is operational: CI runs this with ``--metrics-json`` to
+    archive a metrics artifact per build, and
     ``--prometheus`` prints the text-exposition form for eyeballing.
     Exits 1 when any packet resolved to an error slot."""
     import argparse

@@ -126,7 +126,7 @@ class _Shard:
     pinned to one device."""
 
     def __init__(self, shard_id: int, cp: ControlPlane, device, *,
-                 max_width: int, taylor_order: int, dispatch: str,
+                 max_width: int, taylor_order: int,
                  kernel_variant: str, forest_variant: str,
                  ingress_batch: int, max_inflight: int, use_cache: bool,
                  cache_capacity_pow2: int,
@@ -140,8 +140,8 @@ class _Shard:
         self.device = device
         self.engine = DataPlaneEngine(
             cp, max_features=max_width, taylor_order=taylor_order,
-            dispatch=dispatch, kernel_variant=kernel_variant,
-            forest_variant=forest_variant, device=device)
+            kernel_variant=kernel_variant, forest_variant=forest_variant,
+            device=device)
         self.pipeline = IngressPipeline(
             self.engine, batch_size=ingress_batch,
             max_inflight=max_inflight, use_cache=use_cache,
@@ -199,8 +199,8 @@ class ShardedPacketServer:
     def __init__(self, *, n_shards: int = 1, max_models: int = 16,
                  max_layers: int = 4, max_width: int = 32,
                  frac_bits: int = 8, weight_bits: int = 16,
-                 taylor_order: int = 3, dispatch: str = "fused",
-                 kernel_variant: str = "int16", forest_variant: str = "auto",
+                 taylor_order: int = 3, kernel_variant: str = "int16",
+                 forest_variant: str = "auto",
                  max_inflight: int = 8, ingress_batch: int = 2048,
                  use_cache: bool = True, cache_capacity_pow2: int = 16,
                  max_forests: int = 8,
@@ -243,7 +243,7 @@ class ShardedPacketServer:
         self.shards = [
             _Shard(s, self.control_plane, devices[s],
                    max_width=max_width, taylor_order=taylor_order,
-                   dispatch=dispatch, kernel_variant=kernel_variant,
+                   kernel_variant=kernel_variant,
                    forest_variant=forest_variant,
                    ingress_batch=ingress_batch, max_inflight=max_inflight,
                    use_cache=use_cache,

@@ -1,11 +1,12 @@
 """Minimal stand-in for the ``hypothesis`` package.
 
 The test suite uses a small, stable slice of hypothesis — ``@given`` /
-``@settings`` with ``integers`` / ``floats`` / ``lists`` / ``sampled_from``
-strategies — but the runtime container does not ship the real package and the
-repo rule is "no new installs".  This shim implements exactly that slice with
-deterministic pseudo-random example generation so the property tests still
-execute (boundary values first, then seeded uniform draws).
+``@settings`` / ``@example`` with ``integers`` / ``floats`` / ``lists`` /
+``sampled_from`` strategies — but the runtime container does not ship the
+real package and the repo rule is "no new installs".  This shim implements
+exactly that slice with deterministic pseudo-random example generation so
+the property tests still execute (explicit examples, boundary values, then
+seeded uniform draws).
 
 It is only registered when the real package is absent (see tests/conftest.py),
 so CI with ``requirements-dev.txt`` installed runs genuine hypothesis and
@@ -100,8 +101,21 @@ def settings(max_examples: int = _DEFAULT_MAX_EXAMPLES, deadline=None, **_ignore
     return deco
 
 
+def example(*args: Any, **kwargs: Any):
+    """Decorator adding an explicit example, run before the generated ones
+    (order-agnostic, like :func:`settings`)."""
+
+    def deco(fn):
+        fn._shim_examples = [(args, kwargs)] + getattr(fn, "_shim_examples",
+                                                       [])
+        return fn
+
+    return deco
+
+
 def given(*strategies: _Strategy, **kw_strategies: _Strategy):
-    """Run the test once per generated example (boundaries, then random).
+    """Run the test once per explicit example, then once per generated
+    example (boundaries, then random).
 
     The RNG seed is derived from the test's qualified name, so failures are
     reproducible run-to-run without a shared example database.
@@ -114,6 +128,9 @@ def given(*strategies: _Strategy, **kw_strategies: _Strategy):
         def wrapper(*args, **kwargs):
             cfg = getattr(wrapper, "_shim_settings", None) or conf or {}
             n = cfg.get("max_examples", _DEFAULT_MAX_EXAMPLES)
+            # wraps() copied fn's examples; later ones land on the wrapper
+            for ex_args, ex_kw in getattr(wrapper, "_shim_examples", []):
+                fn(*args, *ex_args, **{**kwargs, **ex_kw})
             rng = random.Random(zlib.crc32(fn.__qualname__.encode()))
             for i in range(n):
                 vals = [s.example(rng, i) for s in strategies]
@@ -166,6 +183,7 @@ def install_hypothesis_shim() -> None:
     mod = types.ModuleType("hypothesis")
     mod.given = given
     mod.settings = settings
+    mod.example = example
     mod.assume = assume
     mod.HealthCheck = types.SimpleNamespace(too_slow=None, data_too_large=None)
     strat = types.ModuleType("hypothesis.strategies")

@@ -2,8 +2,9 @@
 double-buffered table installs, and the async serving loop.
 
 The fused kernel must be bit-exact with (a) its jnp oracle, (b) the fast CPU
-lowering, and (c) the seed per-packet-gather engine path — the data plane's
-integer semantics are the contract (P4/FPGA bit-equivalence, DESIGN.md §2).
+lowering, and (c) the per-packet weight gather of ``kernels.ref`` — the data
+plane's integer semantics are the contract (P4/FPGA bit-equivalence,
+DESIGN.md §2).
 """
 
 import jax
@@ -14,7 +15,9 @@ import pytest
 from repro.core import packet as pk
 from repro.core.control_plane import ControlPlane
 from repro.core.inference import DataPlaneEngine
+from repro.core.ingress import PacketError
 from repro.core.taylor import scaled_constants
+from repro.kernels import ref
 from repro.kernels.ops import fused_mlp
 
 FRAC = 8
@@ -53,12 +56,10 @@ class TestFusedKernel:
         outs = {b: np.asarray(fused_mlp(x, slot, t.w, t.b, t.act, t.layer_on,
                                         backend=b, **kw))
                 for b in ("ref", "pallas", "auto")}
-        # the seed per-packet-gather formulation (what dispatch="gather"
-        # routes through serve_lanes) — straight from kernels.ref, the one
-        # place the integer semantics live
-        from repro.kernels.ref import fused_mlp_gather_ref
+        # the per-packet weight gather, straight from kernels.ref, the
+        # one place the integer semantics live
         gathered = np.asarray(jax.jit(
-            lambda x, s: fused_mlp_gather_ref(
+            lambda x, s: ref.fused_mlp_gather_ref(
                 x, s, t.w, t.b, t.act, t.layer_on, **kw))(x, slot))
 
         np.testing.assert_array_equal(outs["pallas"], outs["ref"])
@@ -105,27 +106,47 @@ class TestFusedKernel:
             np.testing.assert_array_equal(a, b)
 
 
+def _gather_ref_egress(eng, pkts):
+    """The wire path's egress with the MLP lane computed by
+    ``ref.fused_mlp_gather_ref`` on the control plane's tables: each
+    packet's own weights gathered, out_dim lanes kept, unknown Model IDs
+    zeroed."""
+    t = eng.cp.tables()
+    parsed = pk.parse_packets(jnp.asarray(pkts), eng.max_features)
+    slot = t.id_map[parsed.model_id]
+    live = jnp.maximum(slot, 0)
+    x = ref.fused_mlp_gather_ref(
+        parsed.features_q, live, t.w, t.b, t.act, t.layer_on,
+        frac=eng.frac, sig_coeffs=eng.lane_cfg.sig_coeffs,
+        leaky_alpha_q=eng.lane_cfg.leaky_alpha_q)
+    keep = ((jnp.arange(x.shape[1])[None, :] < t.out_dim[live][:, None])
+            & (slot >= 0)[:, None])
+    return np.asarray(pk.emit_results(parsed, jnp.where(keep, x, 0),
+                                      eng.frac))
+
+
 class TestBatchedEngine:
-    def _engine(self, dispatch="fused", n_models=8, width=8):
+    def _engine(self, n_models=8, width=8):
         rng = np.random.default_rng(42)
         cp = ControlPlane(max_models=n_models, max_layers=3, max_width=width,
                           frac_bits=FRAC)
         _install_zoo(cp, rng, n_models, width)
-        return cp, DataPlaneEngine(cp, max_features=width, dispatch=dispatch)
+        return cp, DataPlaneEngine(cp, max_features=width)
 
     def test_fused_matches_gather_engine(self):
-        """Whole-pipeline equality on an arbitrarily interleaved batch,
-        including unknown Model IDs (zeroed egress)."""
+        """Whole-pipeline equality with the per-packet weight gather on an
+        arbitrarily interleaved batch, including unknown Model IDs (zeroed
+        egress)."""
         rng = np.random.default_rng(3)
-        cp_f, eng_f = self._engine("fused")
-        cp_g, eng_g = self._engine("gather")
+        cp, eng = self._engine()
         b = 200
         mids = rng.integers(100, 110, b).astype(np.int32)  # 108/109 unknown
         codes = rng.integers(-2000, 2000, (b, 8)).astype(np.int32)
         pkts = pk.encode_packets(jnp.asarray(mids), jnp.int32(FRAC),
                                  jnp.asarray(codes))
-        np.testing.assert_array_equal(np.asarray(eng_f.process(pkts)),
-                                      np.asarray(eng_g.process(pkts)))
+        want = _gather_ref_egress(eng, pkts)
+        assert want[mids < 108, pk.HEADER_BYTES:].any()  # real outputs
+        np.testing.assert_array_equal(np.asarray(eng.process(pkts)), want)
 
     def test_mixed_batch_matches_float_reference(self):
         """Each packet's output ≈ its own model's float forward pass."""
@@ -154,7 +175,7 @@ class TestBatchedEngine:
 
     def test_zero_retraces_across_installs(self):
         rng = np.random.default_rng(6)
-        cp, eng = self._engine("fused")
+        cp, eng = self._engine()
         pkts = pk.encode_packets(jnp.int32(100), jnp.int32(FRAC),
                                  jnp.zeros((16, 8), jnp.int32))
         eng.process(pkts)
@@ -237,6 +258,10 @@ class TestDoubleBufferedInstall:
 
 
 class TestAsyncServing:
+    """The stream surface serves asynchronously: ``submit_packets`` stages
+    and dispatches without waiting for the device, ``drain_packets``
+    retires in submission order."""
+
     def _server(self, **kw):
         from repro.launch.serve import PacketServer
         rng = np.random.default_rng(9)
@@ -245,50 +270,68 @@ class TestAsyncServing:
         _install_zoo(srv.control_plane, rng, 8, 8)
         return srv
 
-    def test_async_results_match_sync(self):
-        rng = np.random.default_rng(11)
-        srv = self._server(max_inflight=3)
-        batches = []
-        for _ in range(7):
-            mids = rng.integers(100, 108, 64).astype(np.int32)
-            codes = rng.integers(-1000, 1000, (64, 8)).astype(np.int32)
-            batches.append(pk.encode_packets(jnp.asarray(mids),
-                                             jnp.int32(FRAC),
-                                             jnp.asarray(codes)))
-        futures = [srv.submit_async(p) for p in batches]
-        srv.drain()
-        for p, f in zip(batches, futures):
-            np.testing.assert_array_equal(np.asarray(f),
-                                          np.asarray(srv.process(p)))
+    @staticmethod
+    def _wire(rng, n, mid=None):
+        mids = (np.full(n, mid, np.int32) if mid is not None
+                else rng.integers(100, 108, n).astype(np.int32))
+        codes = rng.integers(-1000, 1000, (n, 8)).astype(np.int32)
+        return np.asarray(pk.encode_packets(jnp.asarray(mids),
+                                            jnp.int32(FRAC),
+                                            jnp.asarray(codes)))
 
-    def test_inflight_bounded_and_stats(self):
-        srv = self._server(max_inflight=2)
-        pkts = pk.encode_packets(jnp.int32(100), jnp.int32(FRAC),
-                                 jnp.zeros((32, 8), jnp.int32))
-        for _ in range(5):
-            srv.submit_async(pkts)
-        assert len(srv._inflight) <= 2
-        srv.drain()
-        assert not srv._inflight
-        st = srv.stats()
-        assert srv.engine.stats["packets"] == 5 * 32
-        assert st["recompiles"] == 1
+    @pytest.mark.parametrize("rejects", [False, True])
+    def test_inflight_bounded_and_stats(self, rejects):
+        """The device window never holds more than ``max_inflight``
+        batches, a malformed chunk takes no room in it and resolves as
+        per-packet error slots at its own positions, and the counters see
+        every served packet once."""
+        rng = np.random.default_rng(10)
+        srv = self._server(max_inflight=2, ingress_batch=32)
+        good, bad = [], []
+        n = 0
+        for i in range(10 if rejects else 5):
+            if rejects and i % 2:
+                srv.submit_packets(np.zeros((2, 1), np.uint8))
+                bad += [n, n + 1]
+                n += 2
+            else:
+                chunk = self._wire(rng, 32)
+                srv.submit_packets(chunk)
+                good.append(chunk)
+                n += 32
+            assert len(srv.ingress._inflight) <= 2
+        out = srv.drain_packets()
+        assert not srv.ingress._inflight
+        assert len(out) == n
+        assert [i for i, r in enumerate(out)
+                if isinstance(r, PacketError)] == bad
+        assert srv.engine.stats["packets"] == 32 * len(good)
+        assert srv.stats()["recompiles"] == 1
+        want = np.asarray(srv.process(np.concatenate(good)))
+        np.testing.assert_array_equal(
+            np.stack([r for i, r in enumerate(out) if i not in bad]),
+            want[:, : srv.ingress.out_bytes])
 
     def test_install_mid_flight_zero_retraces(self):
         """The acceptance property end-to-end: hot-swapping every model
-        between async submits never recompiles and next batches see the new
-        generation."""
+        while a batch is in flight never recompiles, the in-flight batch
+        keeps its generation and the next packets see the new one."""
         rng = np.random.default_rng(13)
-        srv = self._server()
-        pkts = pk.encode_packets(jnp.int32(100), jnp.int32(FRAC),
-                                 jnp.full((16, 8), 64, jnp.int32))
-        srv.submit_async(pkts)
+        srv = self._server(ingress_batch=16)
+        pkts = self._wire(rng, 16, mid=100)
+        want_old = np.asarray(srv.process(pkts))[:, : srv.ingress.out_bytes]
+        srv.submit_packets(pkts)          # a full batch: dispatched now
+        traces = srv.engine.trace_count
         gen = srv.control_plane.version
         _install_zoo(srv.control_plane, rng, 8, 8, scale=0.5)
-        srv.submit_async(pkts)
-        srv.drain()
-        assert srv.engine.trace_count == 1
+        srv.submit_packets(pkts)
+        got = srv.drain_packets()
+        want_new = np.asarray(srv.process(pkts))[:, : srv.ingress.out_bytes]
+        assert srv.engine.trace_count == traces
         assert srv.control_plane.version > gen
+        np.testing.assert_array_equal(np.stack(got[:16]), want_old)
+        np.testing.assert_array_equal(np.stack(got[16:]), want_new)
+        assert not np.array_equal(want_old, want_new)
 
 
 class _CopySpy:

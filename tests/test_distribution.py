@@ -141,7 +141,7 @@ class TestCompressedAllReduce:
 
 class TestLMServerControlPlane:
     def test_hot_swap_no_recompile(self):
-        from repro.launch.serve import LMServer
+        from repro.launch.lm_serve import LMServer
         cfg = reduced(get_config("qwen2-1.5b")).replace(remat=False)
         srv = LMServer(cfg, batch=2, max_seq=32)
         params_a = srv.model.init(jax.random.key(0))
@@ -163,7 +163,7 @@ class TestLMServerControlPlane:
             reg.install("m", {"b": jnp.zeros((2,))})
 
     def test_greedy_decode_deterministic(self):
-        from repro.launch.serve import LMServer
+        from repro.launch.lm_serve import LMServer
         cfg = reduced(get_config("qwen2-1.5b")).replace(remat=False)
         srv = LMServer(cfg, batch=2, max_seq=32)
         srv.install("m", srv.model.init(jax.random.key(0)))

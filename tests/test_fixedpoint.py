@@ -4,7 +4,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from hypothesis import given, settings
+from hypothesis import example, given, settings
 from hypothesis import strategies as st
 
 from repro.core import fixedpoint as fx
@@ -35,12 +35,19 @@ class TestEncodeDecode:
     @given(st.floats(-100.0, 100.0, allow_nan=False),
            st.integers(0, 12), st.integers(-8, 8))
     @settings(max_examples=200, deadline=None)
+    @example(w=64.57299497157666, s=11, b=0)
+    @example(w=64.18688811832067, s=12, b=0)
     def test_roundtrip_error_bound(self, w, s, b):
-        """Property: |decode(encode(w)) − w| ≤ 2^-(s+1) when in range."""
+        """Property: |decode(encode(w)) − w| ≤ 2^-(s+1) when in range.
+
+        ``encode`` works on ``float32(w)``; scaling it by 2^s and decoding
+        are exact, so the bound holds against that value with no slack
+        (against the float64 ``w`` the cast adds up to half a float32 ulp,
+        3.8e-6 near |w| = 64)."""
         wq = fx.encode(w, s, b, total_bits=32)
         w_back = float(fx.decode(wq, s, b))
         if abs(w * 2 ** s + b) < 2 ** 30:  # not saturated
-            assert abs(w_back - w) <= 2 ** (-s - 1) + 1e-6
+            assert abs(w_back - float(np.float32(w))) <= 2 ** (-s - 1)
 
     @given(st.integers(-2**14, 2**14), st.integers(0, 10), st.integers(-4, 4))
     @settings(max_examples=200, deadline=None)

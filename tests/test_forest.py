@@ -504,6 +504,26 @@ class TestForestServing:
         want = np.asarray(srv.process(base))[:, : srv.ingress.out_bytes]
         np.testing.assert_array_equal(new, want)
 
+    def test_forest_reinstall_mid_serving_zero_retraces(self):
+        """Hot-swapping retrained forests between windows of mixed
+        MLP+forest stream traffic recompiles no lane program, and each
+        window serves the forest installed before it."""
+        rng = np.random.default_rng(36)
+        srv = self._server(rng, ingress_batch=16, max_inflight=2)
+        wire, _ = _wire(rng, 64, rng.choice([1, 2], 64))
+        srv.submit_packets(wire)
+        srv.drain_packets()
+        np.asarray(srv.process(wire))    # the reference's own program
+        traces = srv.engine.trace_count
+        for seed in (1, 2):
+            f2, _, _ = _train_small(np.random.default_rng(seed), "classify")
+            srv.install_forest(2, f2)
+            srv.submit_packets(wire)
+            got = np.stack(srv.drain_packets())
+            want = np.asarray(srv.process(wire))[:, : srv.ingress.out_bytes]
+            np.testing.assert_array_equal(got, want)
+        assert srv.engine.trace_count == traces
+
     def test_remove_forest_drops_cached_rows(self):
         rng = np.random.default_rng(32)
         srv = self._server(rng)

@@ -14,9 +14,8 @@ import jax.numpy as jnp
 from repro.core import packet as pk
 from repro.core.control_plane import ControlPlane
 from repro.core.inference import DataPlaneEngine
-from repro.core.ingress import (BatchError, IngressPipeline, PacketError,
-                                ResultCache, _dedup_rows, hash_words,
-                                pack_rows)
+from repro.core.ingress import (IngressPipeline, PacketError, ResultCache,
+                                _dedup_rows, hash_words, pack_rows)
 
 FRAC = 8
 WIDTH = 8
@@ -513,6 +512,32 @@ class TestPipelineCorrectness:
             np.testing.assert_array_equal(np.stack(got[64 * k: 64 * (k + 1)]),
                                           want)
 
+    def test_half_duplicate_trace_short_circuits_every_repeat(self):
+        """A cold pass over a trace in which half of each chunk repeats an
+        earlier packet (of any earlier chunk or its own): every repeat
+        resolves without device work, by in-chunk dedup, pending-window
+        coalescing or a result-cache hit, so the short-circuit share is
+        the trace's duplicate share."""
+        rng = np.random.default_rng(16)
+        cp, eng, pipe = _pipeline(batch_size=64, max_inflight=2)
+        chunk, n_chunks = 64, 16
+        pool = _wire(rng, chunk // 2 * n_chunks, model_lo=10, model_hi=14)
+        idx = []
+        for c in range(n_chunks):
+            fresh = np.arange(c * chunk // 2, (c + 1) * chunk // 2)
+            repeats = rng.integers(0, fresh[-1] + 1, chunk // 2)
+            idx.append(rng.permutation(np.concatenate([fresh, repeats])))
+        trace = pool[np.concatenate(idx)]
+        for c in range(n_chunks):
+            pipe.submit(trace[c * chunk:(c + 1) * chunk])
+        got = pipe.drain()
+        short = (pipe.stats["ingress_coalesced_total"]
+                 + pipe.stats["ingress_cache_hits_total"])
+        assert short == trace.shape[0] // 2
+        assert short / trace.shape[0] >= 0.45
+        want = np.asarray(eng.process(trace))[:, : pipe.out_bytes]
+        np.testing.assert_array_equal(np.stack(got), want)
+
     def test_cache_serves_across_windows(self):
         rng = np.random.default_rng(14)
         cp, eng, pipe = _pipeline(batch_size=32)
@@ -747,15 +772,29 @@ class TestFlushAfter:
 
 
 class TestPipelineErrorSlots:
-    def test_malformed_chunks_occupy_ordered_slots(self):
+    @pytest.mark.parametrize("surface,bad_len", [
+        ("pipeline", pk.HEADER_BYTES + 4 * (WIDTH + 1)),  # a lane too long
+        ("server", 3),                                    # under a header
+    ], ids=["pipeline", "server"])
+    def test_malformed_chunks_occupy_ordered_slots(self, surface, bad_len):
+        """A chunk of the wrong wire length (too long, or shorter than the
+        header) resolves as per-packet error slots at its own submission
+        positions, through the pipeline and through the server, and the
+        packets after it do not shift."""
         rng = np.random.default_rng(17)
-        cp, eng, pipe = _pipeline(batch_size=32)
+        if surface == "pipeline":
+            cp, eng, pipe = _pipeline(batch_size=32)
+            submit, drain = pipe.submit, pipe.drain
+        else:
+            srv, rng = TestServerIntegration._server(ingress_batch=32,
+                                                     max_inflight=2)
+            eng, pipe = srv.engine, srv.ingress
+            submit, drain = srv.submit_packets, srv.drain_packets
         good1, good2 = _wire(rng, 5), _wire(rng, 6)
-        too_long = np.zeros((3, pipe.wire_bytes + 4), np.uint8)
-        pipe.submit(good1)
-        pipe.submit(too_long)
-        pipe.submit(good2)
-        got = pipe.drain()
+        submit(good1)
+        submit(np.zeros((3, bad_len), np.uint8))
+        submit(good2)
+        got = drain()
         assert len(got) == 14
         want = np.asarray(eng.process(np.concatenate([good1, good2])))
         for i in range(5):
@@ -854,7 +893,8 @@ class TestCacheStalenessEndToEnd:
 
 
 class TestServerIntegration:
-    def _server(self, **kw):
+    @staticmethod
+    def _server(**kw):
         from repro.launch.serve import PacketServer
         rng = np.random.default_rng(22)
         srv = PacketServer(max_models=4, max_layers=2, max_width=WIDTH,
@@ -862,46 +902,6 @@ class TestServerIntegration:
         for m in range(4):
             _install(srv.control_plane, rng, 10 + m)
         return srv, rng
-
-    def test_drain_preserves_order_with_rejected_batches(self):
-        """The satellite fix: a rejected batch occupies its submission-order
-        slot as a BatchError with per-packet error slots — results behind it
-        do not shift."""
-        srv, rng = self._server(max_inflight=2)
-        b1, b3 = _wire(rng, 16), _wire(rng, 16)
-        f1 = srv.submit_async(b1)
-        rej = srv.submit_async(np.zeros((5, 3), np.uint8))
-        f3 = srv.submit_async(b3)
-        outs = srv.drain()
-        assert len(outs) == 3
-        assert isinstance(outs[1], BatchError)
-        assert outs[1].n_packets == 5
-        assert len(outs[1].per_packet) == 5
-        assert all(isinstance(p, PacketError) for p in outs[1].per_packet)
-        np.testing.assert_array_equal(np.asarray(outs[0]),
-                                      np.asarray(srv.process(b1)))
-        np.testing.assert_array_equal(np.asarray(outs[2]),
-                                      np.asarray(srv.process(b3)))
-
-    def test_rejections_do_not_break_async_window(self):
-        """Error slots never count against the in-flight window, and drain
-        keeps relative submission order for everything still in flight
-        (the oldest valid future retires early once the window fills — the
-        pre-existing bounded-queue semantics)."""
-        srv, rng = self._server(max_inflight=2)
-        good = _wire(rng, 8)
-        entries = []
-        for i in range(6):
-            if i % 2:
-                entries.append(srv.submit_async(np.zeros((2, 1), np.uint8)))
-            else:
-                entries.append(srv.submit_async(good))
-        assert [isinstance(e, BatchError) for e in entries] \
-            == [False, True, False, True, False, True]
-        outs = srv.drain()
-        # submit #4 (valid) forced the retire of submit #0; error slots stay
-        assert [isinstance(o, BatchError) for o in outs] \
-            == [True, False, True, False, True]
 
     def test_remove_via_server_drops_cache(self):
         srv, rng = self._server()
@@ -913,9 +913,15 @@ class TestServerIntegration:
         assert not srv.ingress.cache.contains_model(10)
         assert srv.stats()["cache_entries"] == 0
 
-    def test_stream_results_match_sync(self):
-        srv, rng = self._server(ingress_batch=32)
-        chunks = [_wire(rng, n) for n in (5, 40, 17)]
+    @pytest.mark.parametrize("sizes,ingress_batch,max_inflight", [
+        ((5, 40, 17), 32, 8),       # ragged chunks across batch edges
+        ((64,) * 7, 64, 3),         # whole batches through a 3-deep window
+    ], ids=["ragged", "window"])
+    def test_stream_results_match_sync(self, sizes, ingress_batch,
+                                       max_inflight):
+        srv, rng = self._server(ingress_batch=ingress_batch,
+                                max_inflight=max_inflight)
+        chunks = [_wire(rng, n) for n in sizes]
         for ch in chunks:
             srv.submit_packets(ch)
         got = srv.drain_packets()

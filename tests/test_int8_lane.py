@@ -19,6 +19,7 @@ from repro.core.control_plane import ControlPlane
 from repro.core.inference import DataPlaneEngine
 from repro.core.taylor import scaled_constants
 from repro.kernels import KERNEL_VARIANTS
+from repro.kernels import ref
 from repro.kernels.ops import fused_mlp
 from repro.kernels.ref import lane_clamp
 
@@ -97,8 +98,6 @@ class TestInt8Lane:
             cp.install(50 + m, [(w, bias)], [])
             models[50 + m] = (w, bias)
         eng = DataPlaneEngine(cp, max_features=width, kernel_variant="int8")
-        eng_g = DataPlaneEngine(cp, max_features=width, dispatch="gather",
-                                kernel_variant="int8")
         b = 128
         mids = rng.integers(50, 54, b).astype(np.int32)
         x = (rng.normal(size=(b, width)) * 0.5).astype(np.float32)
@@ -106,8 +105,20 @@ class TestInt8Lane:
         pkts = pk.encode_packets(jnp.asarray(mids), jnp.int32(FRAC),
                                  jnp.asarray(xq))
         egress = eng.process(pkts)
-        np.testing.assert_array_equal(np.asarray(egress),
-                                      np.asarray(eng_g.process(pkts)))
+        # the int8 lane of the per-packet weight gather, applied directly
+        parsed_in = pk.parse_packets(pkts, width)
+        t = cp.tables()
+        slot = t.id_map[parsed_in.model_id]
+        lane_out = ref.fused_mlp_gather_ref(
+            parsed_in.features_q, slot, t.w, t.b, t.act, t.layer_on,
+            frac=FRAC, sig_coeffs=eng.lane_cfg.sig_coeffs,
+            leaky_alpha_q=eng.lane_cfg.leaky_alpha_q, lane_bits=8)
+        lane_out = jnp.where(
+            jnp.arange(width)[None, :] < t.out_dim[slot][:, None],
+            lane_out, 0)
+        np.testing.assert_array_equal(
+            np.asarray(egress),
+            np.asarray(pk.emit_results(parsed_in, lane_out, FRAC)))
         parsed = pk.parse_packets(egress, max_features=2)
         got = np.asarray(parsed.features_q[:, :2]) / 2.0 ** FRAC
         want = np.stack([x[i] @ models[int(mids[i])][0]

@@ -23,6 +23,9 @@ backpressure, reflex fallback lane, bounded drain, overload chaos).
     within budget
 """
 
+import time
+import types
+
 import numpy as np
 import pytest
 from hypothesis import given, settings
@@ -504,6 +507,89 @@ class TestOverloadChaos:
         p99 = [h.percentile(99.0) for h in fab._submit_hist]
         assert p99[1] < 0.05               # survivor within a 50ms budget
         assert p99[0] > p99[1]             # the overloaded shard is not
+
+    def test_burst_overload_drill(self, monkeypatch):
+        """One pipeline under overload: a per-model budget, a reflex
+        program on model 1 (15/16 of the traffic), none on model 2, and
+        the overload site holding every retire 10x the device cost.  Past
+        the high watermark model 1 answers on the reflex lane, model 2
+        sheds past hard capacity, and every slot resolves in submission
+        order to a bit-exact model-lane row, a bit-exact reflex row or a
+        typed shed.  Every answered packet meets the budget and nothing
+        retraces.  Time is the injected clock: a held retire advances it
+        instead of sleeping, arrivals 0.2 ms apart."""
+        import repro.core.ingress as ingress
+        budget_us, total, chunk, gap = 100_000.0, 4096, 64, 2e-4
+        rng = np.random.default_rng(2)
+        cp = _cp(mids=(), max_models=4)
+        for mid in (1, 2):
+            cp.install(mid, _layers(rng), ["relu"],
+                       final_activation="sigmoid", slo_budget_us=budget_us)
+        eng = DataPlaneEngine(cp, max_features=WIDTH)
+        mids = np.where(np.arange(total) % 16 == 15, 2, 1).astype(np.int32)
+        codes = rng.integers(-2000, 2000, (total, WIDTH)).astype(np.int32)
+        wire = np.asarray(pk.encode_packets(
+            jnp.asarray(mids), jnp.int32(FRAC), jnp.asarray(codes)))
+        chunks = [wire[i:i + chunk] for i in range(0, total, chunk)]
+        base = IngressPipeline(eng, batch_size=256, max_inflight=4,
+                               use_cache=False)
+        for ch in chunks:
+            base.submit(ch)
+        oracle = base.drain()               # unconstrained model lane
+
+        cp.install_reflex(1, _prog(on_true=(256, 0, 0, 0),
+                                   on_false=(0, 256, 0, 0)))
+        clk = FakeClock()
+        monkeypatch.setattr(ingress, "time", types.SimpleNamespace(
+            sleep=clk.advance, perf_counter=time.perf_counter))
+        pipe = IngressPipeline(eng, batch_size=256, max_inflight=4,
+                               use_cache=False, queue_capacity=320,
+                               queue_high_watermark=192, clock=clk)
+        for ch in chunks:                   # no-fault warm: every rung
+            pipe.submit(ch)
+            pipe.poll()
+        pipe.drain()
+        pipe.dispatch_cost_ewma = 1.2e-3    # a known device cost, pinned
+        pipe._COST_ALPHA = 0.0
+        pipe.fault_plan = FaultPlan([FaultSpec(
+            site="overload", slowdown=10.0, count=FOREVER)], seed=3)
+        traces = eng.trace_count
+        pipe.reset_tickets()
+        sub = np.empty(total)
+        rdy = np.full(total, np.nan)
+        for ch in chunks:
+            sub[pipe._n_tickets: pipe._n_tickets + len(ch)] = clk()
+            pipe.submit(ch)
+            pipe.poll()
+            pipe._resolve_ready_chunks()
+            k = pipe._n_tickets
+            done = np.isnan(rdy[:k]) & (pipe._status[:k] == 1)
+            rdy[:k][done] = clk()
+            clk.advance(gap)
+        out = pipe.drain()
+        rdy[np.isnan(rdy)] = clk()          # resolved during the drain
+        assert eng.trace_count == traces
+
+        assert len(out) == total
+        shed = [i for i, r in enumerate(out) if isinstance(r, PacketError)]
+        served = [i for i, r in enumerate(out)
+                  if not isinstance(r, PacketError)]
+        reflex = [i for i in served if int(out[i][6]) & pk.FLAG_REFLEX]
+        model = [i for i in served if not int(out[i][6]) & pk.FLAG_REFLEX]
+        assert shed and reflex and model    # all three outcomes happen
+        assert all(out[i].reason == DEADLINE_SHED for i in shed)
+        assert set(mids[shed]) == {2}       # only the uncovered model sheds
+        for i in model:
+            np.testing.assert_array_equal(out[i], oracle[i])
+        rs = np.asarray(reflex)
+        _, outw = cp.reflex_evaluate(mids[rs], codes[rs])
+        want = pk.emit_results_np(
+            mids[rs], np.array([int(out[i][6]) for i in reflex]),
+            outw[:, : pipe.out_feats], eng.frac)
+        for j, i in enumerate(reflex):
+            np.testing.assert_array_equal(out[i], want[j])
+        lat = (rdy - sub)[served]
+        assert np.percentile(lat, 99) * 1e6 <= budget_us
 
 
 # ---------------------------------------------------------------------------
