@@ -48,8 +48,9 @@ three pieces the paper's NIC gets for free from hardware:
     batches at light load for latency, the full batch under sustained
     load).  A **cold-traffic admission gate** (chunk-level EWMA of the
     observed duplication) turns the speculative cache/pending insert
-    sweeps off on unique/adversarial traffic — the cold path pays lookups
-    (which miss fast) but not inserts — and re-opens within a chunk or two
+    sweeps off on unique/adversarial traffic — the cold path pays
+    direct-mapped lookups (one slot per row) and a sampled insert, not
+    full sweeps — and re-opens within a chunk or two
     when the always-on intra-chunk dedup sees duplicates again.
   * per-packet **tickets** — every submitted packet gets a ticket; results
     (or :class:`PacketError` slots for malformed packets) are delivered in
@@ -230,6 +231,15 @@ class ResultCache:
     key words only where the tag matches: a cold row costs one tag
     compare per slot examined, not a key compare (``probes`` counts the
     slots examined, ``key_verifies`` the keys read).
+
+    With ``direct`` set (the ingress pipeline sets it while its admission
+    gate is closed) the table is **direct-mapped**: a lookup examines only
+    a row's home slot, and an insert takes the home slot in one round —
+    claiming it, or replacing the entry of another key (``direct_rows``
+    counts the rows, ``direct_replaced`` the entries replaced).  Entries
+    placed so sit where any lookup looks first; an entry placed off its
+    home slot is a safe miss until ``direct`` is cleared again.
+    Compaction always re-inserts with full probing.
     """
 
     def __init__(self, key_words: int, val_bytes: int, *,
@@ -266,14 +276,20 @@ class ResultCache:
         self.stale_inserts_dropped = 0
         self.probes = 0
         self.key_verifies = 0
+        self.direct = False
+        self.direct_rows = 0
+        self.direct_replaced = 0
         # layer span of maintenance (flush, compaction): the owning
         # pipeline binds its own
         self.span = no_span
 
     # -- internals --------------------------------------------------------
 
+    def _home(self, hashes: np.ndarray) -> np.ndarray:
+        return (hashes & np.uint64(self._mask)).astype(np.int64)
+
     def _slots_steps(self, hashes: np.ndarray) -> Tuple[np.ndarray, np.ndarray]:
-        slot = (hashes & np.uint64(self._mask)).astype(np.int64)
+        slot = self._home(hashes)
         # odd step → full-cycle double hashing over the power-of-two table
         step = ((((hashes >> np.uint64(32)) << np.uint64(1)) | np.uint64(1))
                 .astype(np.int64)) & self._mask
@@ -329,10 +345,11 @@ class ResultCache:
             self._tombstones = 0
             self.compactions += 1
             if keys.shape[0]:
-                # re-admissions are not new insertions
-                ins0 = self.insertions
-                self.insert(keys, vals, mids, self._gen, tags)
-                self.insertions = ins0
+                # probing whatever the mode; re-admissions are not new
+                # insertions
+                uidx, _ = _dedup_rows(keys, tags)
+                self._place_probing(keys[uidx], vals[uidx], mids[uidx],
+                                    tags[uidx])
 
     @property
     def tombstones(self) -> int:
@@ -362,15 +379,20 @@ class ResultCache:
             return np.zeros(n, bool), np.zeros((0, self.val_bytes), np.uint8)
         if hashes is None:
             hashes = hash_words(words)
-        slot, _ = self._slots_steps(hashes)
+        slot = self._home(hashes)
         # fast first round, no indirection: with load < load_limit almost
         # every probe resolves at its home slot
         st = self._state[slot]
         match = self._match(slot, st, hashes, words, np.arange(n))
         hit_slot = np.where(match, slot, np.int64(-1))
         # keep probing through tombstones and colliding keys; an empty slot
-        # terminates the probe chain → definitive miss
-        pending = np.nonzero(~match & (st != 0))[0]
+        # terminates the probe chain → definitive miss.  Direct-mapped:
+        # the home slot was the whole chain
+        if self.direct:
+            self.direct_rows += n
+            pending = np.zeros(0, np.int64)
+        else:
+            pending = np.nonzero(~match & (st != 0))[0]
         if pending.size:
             _, step = self._slots_steps(hashes[pending])
             cur = (slot[pending] + step) & self._mask
@@ -411,7 +433,8 @@ class ResultCache:
         (e.g. the best-effort pending window missed a row that then staged
         twice) stay safe either way: an arbitration loser whose slot was
         just claimed by its own key resolves as a value refresh instead of
-        claiming a second slot.
+        claiming a second slot.  In ``direct`` mode each row takes its home
+        slot in one round instead (:meth:`_place_direct`).
         """
         n = words.shape[0]
         if n == 0:
@@ -435,6 +458,51 @@ class ResultCache:
         if self._count + n > self._cap * self._load_limit:
             self.clear()
             self._gen = generation
+        place = self._place_direct if self.direct else self._place_probing
+        admitted = place(words, vals, model_ids, hashes)
+        self.insertions += admitted
+        return admitted
+
+    def _place_direct(self, words: np.ndarray, vals: np.ndarray,
+                      model_ids: np.ndarray, hashes: np.ndarray) -> int:
+        """Direct-mapped placement, one round at each row's home slot:
+        refresh the key's own entry, else claim the slot — empty, tombstone
+        (reclaimed) or another key's entry (replaced).  Rows of one call on
+        one slot: the last writer wins, the others are dropped.  Returns
+        the rows placed."""
+        n = words.shape[0]
+        self.direct_rows += n
+        s = self._home(hashes)
+        st = self._state[s]
+        rows = np.arange(n)
+        match = self._match(s, st, hashes, words, rows)
+        if match.any():
+            self._vals[s[match]] = vals[match]
+        ci = rows[~match]
+        if ci.size == 0:
+            return 0
+        cs = s[ci]
+        self._claim[cs] = ci  # scatter arbitration, as in probing
+        wi = ci[self._claim[cs] == ci]
+        ws = s[wi]
+        wst = st[wi]
+        replaced = int((wst == 1).sum())
+        self._tombstones -= int((wst == 2).sum())  # reclaimed
+        self._count += wi.size - replaced
+        self.direct_replaced += replaced
+        self._keys[ws] = words[wi]
+        self._tag[ws] = hashes[wi]
+        self._vals[ws] = vals[wi]
+        self._model[ws] = model_ids[wi]
+        self._state[ws] = 1
+        return wi.size
+
+    def _place_probing(self, words: np.ndarray, vals: np.ndarray,
+                       model_ids: np.ndarray, hashes: np.ndarray) -> int:
+        """Double-hashing placement: rows walk their probe chains until
+        they refresh their own key or claim an empty or tombstoned slot
+        (``max_probe`` slots at most).  Returns the rows claimed."""
+        n = words.shape[0]
         slot, step = self._slots_steps(hashes)
         admitted = 0
 
@@ -498,7 +566,6 @@ class ResultCache:
                 pending = pending[keep]
                 stepp = stepp[keep]
                 cur = (cur[keep] + stepp) & self._mask
-        self.insertions += admitted
         return admitted
 
     def drop_model(self, model_id: int) -> int:
@@ -652,8 +719,13 @@ class IngressPipeline:
     # shut forever despite the true rate being 4× the threshold.  Both
     # comparisons gate the *same* effective duplication: open-state closes
     # below 5% observed, closed-state re-opens at the stride-attenuated
-    # image of that same 5%.  Correctness never depends on the gate — a
-    # skipped insert can only cost a future hit.
+    # image of that same 5%.  While the gate is closed the result cache
+    # is also **direct-mapped** (``ResultCache.direct``): a sample takes
+    # its home slot in one round and a lookup reads only the home slot,
+    # where the samples sit, so the detector sees every sample it did
+    # before without paying probe chains.  Correctness never depends on
+    # the gate — a skipped insert or a direct-mapped miss can only cost a
+    # future hit.
     _ADMIT_THRESHOLD = 0.05
     _ADMIT_ALPHA = 0.5
     _PROBE_STRIDE = 8
@@ -898,6 +970,14 @@ class IngressPipeline:
                 "cache_key_verifies_total",
                 "result-cache slots whose hash tag matched (key read)",
                 shard=sid),
+            "cache_direct_rows_total": reg.counter(
+                "cache_direct_rows_total",
+                "result-cache rows looked up or inserted direct-mapped "
+                "(admission gate closed)", shard=sid),
+            "cache_direct_replaced_total": reg.counter(
+                "cache_direct_replaced_total",
+                "result-cache entries replaced by a direct-mapped insert",
+                shard=sid),
         }
         g_entries = reg.gauge("cache_entries", shard=sid)
         g_tomb = reg.gauge("cache_tombstones", shard=sid)
@@ -928,6 +1008,9 @@ class IngressPipeline:
                 cache_cells["cache_probes_total"].set(cache.probes)
                 cache_cells["cache_key_verifies_total"].set(
                     cache.key_verifies)
+                cache_cells["cache_direct_rows_total"].set(cache.direct_rows)
+                cache_cells["cache_direct_replaced_total"].set(
+                    cache.direct_replaced)
                 g_entries.set(len(cache))
                 g_tomb.set(cache.tombstones)
             g_gate.set(1.0 if self._gate_open else 0.0)
@@ -1474,6 +1557,8 @@ class IngressPipeline:
                 self._gate_open = (self._dup_ewma >= self._ADMIT_THRESHOLD
                                    / self._PROBE_STRIDE)
             if self._gate_open != was_open:
+                if self.cache is not None:
+                    self.cache.direct = not self._gate_open
                 self.obs.events.emit(
                     "gate_open" if self._gate_open else "gate_closed",
                     shard=self.shard_id, generation=self.cp.version,

@@ -268,6 +268,51 @@ class TestAdmissionGate:
         assert hits[-1] > hits[40]
         pipe.drain()
 
+    def test_closed_gate_runs_cache_direct_mapped(self):
+        """While unique traffic keeps the gate closed the result cache is
+        direct-mapped: every row looked up and every row sampled examines
+        one slot, samples replace resident entries, the answers stay
+        byte-equal to the wire path, and re-opening the gate returns the
+        cache to full probing."""
+        rng = np.random.default_rng(13)
+        # 256 slots: the open-gate fill leaves samples homes to replace
+        cp, eng, pipe = self._pipeline(rng, cache_capacity_pow2=8)
+        assert not pipe.cache.direct
+        for _ in range(8):
+            pipe.submit(_wire(rng, 32, model_lo=1, model_hi=3))
+            pipe.flush()
+        assert not pipe._admit() and pipe.cache.direct
+        pipe.reset_tickets()
+        reg = pipe.obs.registry
+
+        def counters():
+            snap = reg.snapshot()
+            return [snap[k]['shard="0"'] for k in (
+                "cache_probes_total", "cache_direct_rows_total",
+                "cache_direct_replaced_total", "cache_flushes_total",
+                "ingress_batches_total")]
+
+        probes0, rows0, repl0, flushes0, batches0 = counters()
+        chunks = [_wire(rng, 32, model_lo=1, model_hi=3) for _ in range(6)]
+        for ch in chunks:
+            pipe.submit(ch)
+            pipe.flush()
+        got = pipe.drain()
+        probes, rows, repl, flushes, batches = counters()
+        assert flushes == flushes0 and batches - batches0 == 6
+        looked_up = 6 * 32
+        sampled = 6 * len(range(0, 32, pipe._PROBE_STRIDE))
+        assert probes - probes0 == rows - rows0 == looked_up + sampled
+        assert repl > repl0
+        want = np.asarray(eng.process(np.concatenate(chunks)))
+        np.testing.assert_array_equal(np.stack(got), want[:, : pipe.out_bytes])
+        base = _wire(rng, 32, model_lo=1, model_hi=3)
+        for _ in range(3):
+            pipe.submit(np.concatenate([base, base]))
+            pipe.flush()
+        assert pipe._admit() and not pipe.cache.direct
+        pipe.drain()
+
 
 class _FakeClock:
     def __init__(self):

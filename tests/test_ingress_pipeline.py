@@ -406,6 +406,141 @@ class TestResultCache:
         assert probes == pipe.cache.probes > 0
         assert verifies == pipe.cache.key_verifies >= 96
 
+    # -- direct-mapped mode (the pipeline's closed admission gate) ---------
+
+    @staticmethod
+    def _homes(n, cap_pow2, tag_bit=0):
+        """Planted hashes with distinct home slots ``1..n`` and, for
+        ``tag_bit``, a tag that differs from the plain one's above the
+        slot mask (same home slot, foreign key)."""
+        h = np.arange(1, n + 1, dtype=np.uint64)
+        return h | np.uint64(tag_bit << cap_pow2) if tag_bit else h
+
+    def test_direct_examines_one_slot_per_row(self):
+        """Direct-mapped, a lookup and an insert examine exactly one slot
+        per row, in a table where full probing walks chains."""
+        rng = np.random.default_rng(60)
+        a, va, ma = self._kv(rng, 300)
+        b, vb, mb = self._kv(rng, 50)
+        c = ResultCache(3, 16, capacity_pow2=9)  # load 0.59: chains
+        c.insert(a, va, ma, 1)
+        p0 = c.probes
+        c.lookup(b, 1)
+        assert c.probes - p0 > 50  # full mode: cold rows walk chains
+        c.direct = True
+        for op in (lambda: c.lookup(a, 1), lambda: c.lookup(b, 1),
+                   lambda: c.insert(b, vb, mb, 1, assume_unique=True)):
+            p0, d0 = c.probes, c.direct_rows
+            op()
+            rows = c.direct_rows - d0
+            assert rows in (300, 50) and c.probes - p0 == rows
+
+    def test_direct_replaced_entry_misses_replacement_hits(self):
+        rng = np.random.default_rng(61)
+        a, va, ma = self._kv(rng, 40)
+        b, vb, mb = self._kv(rng, 40)
+        ha, hb = self._homes(40, 10), self._homes(40, 10, tag_bit=1)
+        c = ResultCache(3, 16, capacity_pow2=10)
+        c.insert(a, va, ma, 1, ha)
+        c.direct = True
+        assert c.insert(b[:25], vb[:25], mb[:25], 1, hb[:25]) == 25
+        assert c.direct_replaced == 25 and len(c) == 40
+        for direct in (True, False):
+            c.direct = direct
+            hm, _ = c.lookup(a, 1, ha)
+            np.testing.assert_array_equal(hm, np.arange(40) >= 25)
+            hm, got = c.lookup(b, 1, hb)
+            np.testing.assert_array_equal(hm, np.arange(40) < 25)
+            np.testing.assert_array_equal(got, vb[:25])
+
+    def test_direct_len_counts_full_slots(self):
+        """Claims of empty slots, tombstone reclaims, replacements and own
+        refreshes: ``len`` stays the number of full slots, ``tombstones``
+        the number of tombstoned ones."""
+        rng = np.random.default_rng(62)
+        a, va, _ = self._kv(rng, 60)
+        ma = np.arange(60, dtype=np.int64) % 3
+        b, vb, mb = self._kv(rng, 90)
+        ha, hb = self._homes(60, 10), self._homes(90, 10, tag_bit=1)
+        c = ResultCache(3, 16, capacity_pow2=10, tombstone_limit=0.5)
+        c.insert(a, va, ma, 1, ha)
+        c.drop_model(0)  # 20 tombstones at homes 1, 4, 7, ...
+        c.direct = True
+        # b on homes 1..90: 20 reclaims, 40 replacements, 30 empty claims
+        assert c.insert(b, vb, mb, 1, hb) == 90
+        assert c.direct_replaced == 40
+        # refresh of an own key (a row 1, home 2, was replaced by b row 1:
+        # put a row 2 back over b row 2 — a replacement — then refresh it)
+        c.insert(a[2:3], va[2:3], ma[2:3], 1, ha[2:3])
+        c.insert(a[2:3], vb[:1], ma[2:3], 1, ha[2:3])
+        assert c.direct_replaced == 41
+        assert len(c) == 90 == int((c._state == 1).sum())
+        assert c.tombstones == 0 == int((c._state == 2).sum())
+        hm, got = c.lookup(a[2:3], 1, ha[2:3])
+        assert hm.all()
+        np.testing.assert_array_equal(got, vb[:1])
+
+    def test_direct_entries_hit_under_full_lookup(self):
+        """Entries placed direct-mapped sit at their home slot, which a
+        full lookup examines first: every row placed hits with its own
+        value, and every earlier entry not replaced still hits."""
+        rng = np.random.default_rng(63)
+        a, va, ma = self._kv(rng, 250)
+        b, vb, mb = self._kv(rng, 100)
+        c = ResultCache(3, 16, capacity_pow2=9, load_limit=0.9)
+        c.insert(a, va, ma, 1)
+        c.direct = True
+        placed = c.insert(b, vb, mb, 1, assume_unique=True)
+        assert c.direct_replaced > 0
+        c.direct = False
+        hm, got = c.lookup(b, 1)
+        assert hm.sum() == placed
+        np.testing.assert_array_equal(got, vb[hm])
+        hm, got = c.lookup(a, 1)
+        assert hm.sum() == 250 - c.direct_replaced
+        np.testing.assert_array_equal(got, va[hm])
+        assert len(c) == int((c._state == 1).sum())
+
+    def test_direct_compaction_reprobes(self):
+        """Compaction re-inserts with full probing whatever the mode: live
+        entries sharing a home slot all survive, the rows re-homed are no
+        direct rows, and chains exist afterwards."""
+        rng = np.random.default_rng(64)
+        words, vals, _ = self._kv(rng, 120)
+        mids = np.arange(120, dtype=np.int64) % 4
+        # 120 keys on 64 homes (slot bits 6 and 7 cleared), steps intact
+        h = hash_words(words) & ~np.uint64(0xC0)
+        c = ResultCache(3, 16, capacity_pow2=8, tombstone_limit=0.05)
+        assert c.insert(words, vals, mids, 1, h) == 120
+        c.direct = True
+        c.drop_model(3)  # 30 tombstones > 5% of 256 → compact in place
+        assert c.compactions == 1 and c.tombstones == 0
+        assert c.direct_rows == 0 and c.direct_replaced == 0
+        keep = mids != 3
+        assert len(c) == int(keep.sum())
+        c.direct = False
+        hm, got = c.lookup(words, 1, h)
+        np.testing.assert_array_equal(hm, keep)
+        np.testing.assert_array_equal(got, vals[keep])
+        c.direct = True  # off-home entries are safe misses direct-mapped
+        hm, got = c.lookup(words, 1, h)
+        assert 0 < hm.sum() < keep.sum() and not (hm & ~keep).any()
+        np.testing.assert_array_equal(got, vals[hm])
+
+    def test_direct_stale_generation_dropped(self):
+        rng = np.random.default_rng(65)
+        words, vals, mids = self._kv(rng, 64)
+        c = ResultCache(3, 16)
+        c.direct = True
+        c.lookup(words, 7)
+        assert c.insert(words, vals, mids, 6) == 0
+        assert c.stale_inserts_dropped == 64 and c.direct_rows == 0
+        hm, _ = c.lookup(words, 7)
+        assert not hm.any()
+        assert c.insert(words, vals, mids, 7) == 64
+        hm, _ = c.lookup(words, 8)  # newer generation flushes
+        assert not hm.any() and len(c) == 0
+
 
 # ---------------------------------------------------------------------------
 # _dedup_rows
